@@ -9,7 +9,7 @@ three benchmark workloads (sheeprl/configs/exp/{ppo,a2c,sac}_benchmarks.yaml
 implemented in plain torch (lightning/hydra are not installed here, so the
 reference cannot run verbatim; this is a from-scratch reimplementation of
 its per-step work, not its code). The result is an apples-to-apples
-same-host column for BENCH_ALL.md next to bench.py's JAX numbers.
+same-host column next to bench.py's JAX numbers (BENCH_TORCH_SAMEHOST.jsonl).
 
 Workload fidelity notes (semantics from the reference, cited per workload):
 - PPO  (ppo_benchmarks.yaml): CartPole-v1, 1 sync env, Tanh MLP encoder

@@ -1,23 +1,13 @@
 #!/bin/sh
-# Refresh every bench number sequentially (each run owns the chip + the
-# single host core; concurrency would corrupt the measurements).
+# Refresh every bench number sequentially: one `python bench.py` child at a
+# time, because a chip belongs to one process (this shell never touches JAX)
+# and concurrent runs would corrupt the measurements. The accelerator legs
+# fail without an accelerator; there is no CPU fallback.
 # Usage: sh scripts/run_all_benches.sh [out_file]
 out="${1:-BENCH_ALL.jsonl}"
 errdir=$(mktemp -d)
 echo "bench stderr in $errdir" >&2
 : > "$out"
-# Probe accelerator reachability ONCE for the whole sweep (each bench run
-# would otherwise re-pay the 90 s subprocess probe: the runs outlast the
-# marker-file TTL). The exported verdict short-circuits bench.py's probe.
-if [ -z "$SHEEPRL_ACCEL_REACHABLE" ]; then
-    SHEEPRL_ACCEL_REACHABLE=$(python - <<'EOF'
-import bench
-print("1" if bench._accelerator_reachable() else "0")
-EOF
-    )
-    export SHEEPRL_ACCEL_REACHABLE
-    echo "accelerator reachable: $SHEEPRL_ACCEL_REACHABLE" >&2
-fi
 failed=0
 for w in ppo a2c sac dreamer_v1 dreamer_v2 dreamer_v3 dreamer_v3_S; do
     echo "=== $w ===" >&2

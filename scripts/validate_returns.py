@@ -40,19 +40,20 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+# The most CPU devices any validator asks for (ppo_dp, sac_decoupled).
+_MAX_VALIDATOR_DEVICES = 2
+
+
 def _setup_jax(num_cpu_devices: int = None) -> None:
     # CPU: learning validation must not depend on (or monopolize) a chip.
-    # force=True: in `all` mode the validators run sequentially in ONE
-    # process, so each _setup_jax clears the previous validator's backend —
-    # safe because no validator holds jax arrays across _setup_jax calls
-    # (each trains, checkpoints to disk, and evals within its own body).
-    # num_devices is a MINIMUM (force_cpu_platform semantics): a platform
-    # grown to 2 devices by ppo_dp/sac_decoupled stays at 2 for later
-    # validators — harmless, as every validator pins fabric.devices
-    # explicitly and trains on exactly the devices it requests.
+    # The CPU client is sized once, when the process first builds it, and in
+    # `all` mode the validators run sequentially in ONE process: size it for
+    # the largest of them whichever runs first. The spare device is harmless,
+    # as every validator pins fabric.devices explicitly and trains on exactly
+    # the devices it requests.
     from sheeprl_tpu.core.runtime import force_cpu_platform
 
-    force_cpu_platform(num_devices=int(num_cpu_devices or 1), force=True)
+    force_cpu_platform(num_devices=max(int(num_cpu_devices or 1), _MAX_VALIDATOR_DEVICES))
 
 
 def _compose(overrides):

@@ -137,8 +137,8 @@ def main(runtime, cfg: Dict[str, Any]):
 
     actions_dim, is_continuous = actions_metadata(envs.single_action_space)
 
-    # Eager flax/optax init runs host-side (each eager dispatch pays the
-    # device-link round trip); the finished trees then move to the mesh.
+    # Eager flax/optax init runs host-side (each eager dispatch pays a
+    # host-device round trip); the finished trees then move to the mesh.
     with runtime.host_init():
         agent, params = build_agent(
             runtime, actions_dim, is_continuous, cfg, observation_space,

@@ -332,8 +332,8 @@ def main(runtime, cfg: Dict[str, Any]):
         runtime.print("Encoder MLP keys:", cfg.algo.mlp_keys.encoder)
     mlp_keys = list(cfg.algo.mlp_keys.encoder)
 
-    # Eager flax/optax init runs host-side (each eager dispatch pays the
-    # device-link round trip); the finished trees then move to the mesh.
+    # Eager flax/optax init runs host-side (each eager dispatch pays a
+    # host-device round trip); the finished trees then move to the mesh.
     with runtime.host_init():
         agent, agent_state = build_agent(
             runtime, cfg, observation_space, action_space,

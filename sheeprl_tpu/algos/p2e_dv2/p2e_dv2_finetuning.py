@@ -99,7 +99,7 @@ def main(runtime, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any] = None):
         raise RuntimeError("The CNN keys or the MLP keys of the encoder and decoder must not be disjointed")
     obs_keys = list(cfg.algo.cnn_keys.encoder) + list(cfg.algo.mlp_keys.encoder)
 
-    # Eager flax/optax init runs host-side (each eager dispatch pays the device-link round trip); shard_params then moves the finished trees to the mesh.
+    # Eager flax/optax init runs host-side (each eager dispatch pays a host-device round trip); shard_params then moves the finished trees to the mesh.
     with runtime.host_init():
         agent, agent_state = dv2_build_agent(
             runtime,

@@ -104,7 +104,7 @@ def main(runtime, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any] = None):
     obs_keys = list(cfg.algo.cnn_keys.encoder) + list(cfg.algo.mlp_keys.encoder)
 
     # Task models drive the DV3 train step; the exploration actor only plays.
-    # Eager flax/optax init runs host-side (each eager dispatch pays the device-link round trip); shard_params then moves the finished trees to the mesh.
+    # Eager flax/optax init runs host-side (each eager dispatch pays a host-device round trip); shard_params then moves the finished trees to the mesh.
     with runtime.host_init():
         agent, agent_state = dv3_build_agent(
             runtime,

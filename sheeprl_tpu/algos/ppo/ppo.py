@@ -305,8 +305,8 @@ def main(runtime, cfg: Dict[str, Any]):
     clip_rewards_fn = (lambda r: np.tanh(r)) if cfg.env.clip_rewards else (lambda r: r)
 
     # ---------------------------------------------------------------- agent
-    # Eager flax/optax init runs host-side (each eager dispatch pays the
-    # device-link round trip); the finished trees then move to the mesh.
+    # Eager flax/optax init runs host-side (each eager dispatch pays a
+    # host-device round trip); the finished trees then move to the mesh.
     with runtime.host_init():
         agent, params = build_agent(
             runtime, actions_dim, is_continuous, cfg, observation_space,
@@ -522,8 +522,8 @@ def main(runtime, cfg: Dict[str, Any]):
         )
 
         with timer("Time/train_time"):
-            # PRNG split runs inside the jit (an eager split on a remote
-            # device blocks the host); coefs travel as numpy.
+            # PRNG split runs inside the jit (an eager split is one more
+            # dispatch the host waits on); coefs travel as numpy.
             clip_arr = np.asarray(cfg.algo.clip_coef, np.float32)
             ent_arr = np.asarray(cfg.algo.ent_coef, np.float32)
             # Goodput accounting BEFORE the dispatch: arg shape specs must

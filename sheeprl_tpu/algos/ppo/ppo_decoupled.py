@@ -112,8 +112,8 @@ def main(runtime, cfg: Dict[str, Any]):
         )
 
     # ---------------------------------------------------------------- agent
-    # Eager flax/optax init runs host-side (each eager dispatch pays the
-    # device-link round trip); replicate() then moves the trees to the mesh.
+    # Eager flax/optax init runs host-side (each eager dispatch pays a
+    # host-device round trip); replicate() then moves the trees to the mesh.
     with runtime.host_init():
         agent, params = build_agent(
             runtime, actions_dim, is_continuous, cfg, observation_space,

@@ -187,7 +187,7 @@ class RecurrentPPOAgent:
         logprobs[B,1], values[B,1], new_carry, next_key). Obs normalization
         and the PRNG split happen in-graph (cf. ppo/agent.py player_step) so
         one jitted call is the step's only dispatch — no per-step host
-        round trip when the player lives on a remote mesh device."""
+        round trip when the player lives on a mesh device."""
         obs = normalize_obs(obs, self.cnn_keys, list(obs.keys()))
         next_key, key = jax.random.split(key)
         obs = {k: v[None] for k, v in obs.items()}

@@ -126,8 +126,8 @@ def main(runtime, cfg: Dict[str, Any]):
         )
 
     # ------------------------------------------------------- agent + optimizers
-    # Eager flax/optax init runs host-side (each eager dispatch pays the
-    # device-link round trip); replicate() then moves the trees to the mesh.
+    # Eager flax/optax init runs host-side (each eager dispatch pays a
+    # host-device round trip); replicate() then moves the trees to the mesh.
     with runtime.host_init():
         agent, agent_state = build_agent(
             runtime, cfg, observation_space, action_space,

@@ -232,8 +232,8 @@ def main(runtime, cfg: Dict[str, Any]):
     mlp_keys = list(cfg.algo.mlp_keys.encoder)
     obs_keys = cnn_keys + mlp_keys
 
-    # Eager flax/optax init runs host-side (each eager dispatch pays the
-    # device-link round trip); the finished trees then move to the mesh.
+    # Eager flax/optax init runs host-side (each eager dispatch pays a
+    # host-device round trip); the finished trees then move to the mesh.
     with runtime.host_init():
         agent, agent_state = build_agent(
             runtime, cfg, observation_space, action_space,
@@ -326,7 +326,7 @@ def main(runtime, cfg: Dict[str, Any]):
 
     # Latency-aware player placement (core/player.py); off-policy: honors
     # fabric.player_sync=async. get_actions reads only encoder+actor, so
-    # only that sub-tree is mirrored (critics/decoder never cross the link).
+    # only that sub-tree is mirrored (critics/decoder never make the copy).
     def _player_view(state):
         return {"encoder": state["encoder"], "actor": state["actor"]}
 

@@ -293,6 +293,12 @@ def _replica_entry(
     that is what pipe-EOF death evidence is for)."""
     import sys
 
+    # Actors are host processes by design: the learner holds the chip, and a
+    # second process that initialised the accelerator would fail or hang.
+    # Select the CPU platform before the actor module's first JAX touch.
+    from sheeprl_tpu.core.runtime import force_cpu_platform
+
+    force_cpu_platform()
     for entry in sys_path:  # spawn children must see the test/driver modules
         if entry not in sys.path:
             sys.path.insert(0, entry)

@@ -47,7 +47,6 @@ import gymnasium as gym
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from sheeprl_tpu.config.instantiate import instantiate
@@ -364,17 +363,17 @@ def ppo_fused_main(runtime, cfg: Dict[str, Any]):
     if use_shard:
         # SPMD rollout: each shard steps its own envs and accumulates its own
         # [T, E/data] trajectory columns; GAE + the update pool downstream
-        # stay GSPMD over the `data`-sharded pool. check_rep=False because
+        # stay GSPMD over the `data`-sharded pool. check_vma=False because
         # the unmentioned `model` axis (size 1 here) defeats replication
         # inference; params and keys arrive replicated by construction.
         p_env = P(DATA_AXIS)
         p_traj = P(None, DATA_AXIS)
-        rollout_fn = shard_map(
+        rollout_fn = jax.shard_map(
             rollout_core,
             mesh=mesh,
             in_specs=(P(), p_env, p_env, p_env, p_env, P()),
             out_specs=(p_env, p_env, p_env, p_env, p_traj, p_traj),
-            check_rep=False,
+            check_vma=False,
         )
 
     def rollout_and_train(params, opt_state, env_state, obs, ep_ret, ep_len, key, clip_coef, ent_coef):
@@ -740,17 +739,17 @@ def sac_fused_main(runtime, cfg: Dict[str, Any]):
         core = rollout_core
         if use_shard:
             # SPMD superstep: each shard steps its own envs and writes its own
-            # ring rows; no cross-shard traffic inside the scan. check_rep is
+            # ring rows; no cross-shard traffic inside the scan. check_vma is
             # off because the unmentioned `model` axis (size 1 here) defeats
             # replication inference; params/keys arrive replicated.
             p_env = P(DATA_AXIS)
             ring_specs = jax.tree_util.tree_map(lambda s: s.spec, ring.state_shardings())
-            core = shard_map(
+            core = jax.shard_map(
                 rollout_core,
                 mesh=mesh,
                 in_specs=(P(), ring_specs, p_env, p_env, p_env, p_env, P()),
                 out_specs=(p_env, p_env, p_env, p_env, ring_specs, P(None, DATA_AXIS)),
-                check_rep=False,
+                check_vma=False,
             )
 
         def rollout(actor_params, ring_state, env_state, obs, ep_ret, ep_len, key):

@@ -5,7 +5,7 @@ iteration is ONE jitted call — final-obs value bootstrap, GAE, epoch/
 minibatch scans — so nothing round-trips the host between rollout and
 update (reference shape: separate ``estimate_returns_and_advantages`` +
 train loop, sheeprl/algos/ppo/ppo.py:345-420; here the fusion matters
-because every extra dispatch pays the device-link latency).
+because every extra dispatch pays the host-device dispatch latency).
 
 Layout: every rollout tensor travels in ``(T, E, ...)`` — T the rollout
 length, E the env columns — because the in-jit GAE scans T sequentially

@@ -1,7 +1,7 @@
 """Device-resident replay ring: the replay buffer as a pytree of HBM arrays.
 
-PROFILE.md's round-3 roofline left the host-side data path as the last
-measured overhead in the DV3 step: a memcpy-bound numpy gather plus a
+The round-3 roofline (CHANGES.md, "Round-3 profile") left the host-side
+data path as the last measured overhead in the DV3 step: a memcpy-bound numpy gather plus a
 ~12 MB host→device transfer *per gradient step*. The T5X-style answer is to
 keep the ring on-device and sample it inside the train jit, so the host
 never touches the hot path:

@@ -4,7 +4,7 @@ The off-policy loops (SAC, all Dreamers, P2E) alternate between env stepping
 (host-bound) and a train call whose batches must first be copied host→device.
 Synchronously, that copy serializes with everything else: for a Dreamer
 recipe the per-call batch is ~13 MB of uint8 pixels, tens of milliseconds of
-host time that the chip spends idle — and over a remote link it is worse.
+host time that the chip spends idle.
 
 `AsyncInfeed` overlaps the copy with env stepping (SURVEY §7.1 step 3,
 "sample on host threads → double-buffered device_put"):

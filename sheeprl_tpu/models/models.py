@@ -402,10 +402,11 @@ class LayerNormGRUCell(nn.Module):
     @nn.compact
     def __call__(self, h: jax.Array, x: jax.Array) -> jax.Array:
         inp = jnp.concatenate([h.astype(self.dtype), x.astype(self.dtype)], axis=-1)
-        # Auto default is OFF: the measured A/B (PROFILE.md) shows the fused
-        # kernel wins at the cell level forward (1.45x at B=1024) but is
-        # neutral-to-slightly-negative inside the full DV3 train step, where
-        # convs dominate and the custom-VJP boundary blocks XLA cross-fusion.
+        # Auto default is OFF: the round-3 A/B (CHANGES.md, "Round-3 profile";
+        # old code, not re-measured) showed the fused kernel winning at the
+        # cell level forward (1.45x at B=1024) but neutral-to-slightly-
+        # negative inside the full DV3 train step, where convs dominate and
+        # the custom-VJP boundary blocks XLA cross-fusion.
         # ONE knob: opt in per-module (fused=True) or globally via
         # SHEEPRL_TPU_FUSED_GRU=1 (read only here).
         use_fused = (

@@ -15,17 +15,23 @@ z -> out tail with plain jax and forms the three matmul gradients
 (dz @ W^T, inp^T @ dz, sum dz) directly. Same FLOPs as XLA's unfused
 backward, minus the fused forward's saved HBM traffic.
 
-Dispatch: the kernel runs on TPU when the shapes satisfy the tiling
-constraints (H multiple of 128, modest VMEM footprint); anything else —
-CPU tests, tiny dry-run models, XL configs whose W tiles exceed VMEM —
-falls back to the identical plain-jax computation. Whether the cell routes
-here at all is decided by ONE knob in models.LayerNormGRUCell: the `fused`
-flag, whose auto default reads SHEEPRL_TPU_FUSED_GRU (default off).
+Dispatch: the kernel runs on TPU when the shape is eligible — H a multiple
+of 128 and the per-grid-step VMEM footprint (:func:`_vmem_bytes`, the sum
+the compiler itself refuses past its scoped limit) inside
+:data:`_VMEM_LIMIT_BYTES`; "eligible" implies "compiles"
+(tests/test_utils/test_tpu_aot_compiles.py asks the TPU compiler). Off TPU (CPU tests)
+the identical plain-jax computation runs; on TPU an ineligible shape also
+takes the plain path, with a warning that names the shape and the bound.
+Whether the cell routes here at all is decided by ONE knob in
+models.LayerNormGRUCell: the `fused` flag, whose auto default reads
+SHEEPRL_TPU_FUSED_GRU (default off).
 """
 
 from __future__ import annotations
 
 import functools
+import warnings
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +43,48 @@ _B_TILE = 256
 _D_TILE = 512
 # Per-grid-step VMEM budget for the W tile (f32): D_TILE * 3H * 4 bytes
 _W_TILE_BUDGET = 8 * 1024 * 1024
+# Mosaic's default scoped-VMEM limit on a TPU v5e (the kernel sets no compiler
+# params): a kernel whose blocks sum past it is refused at compile time.
+_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
+
+def _tiles(batch: int, d: int, hidden: int) -> Tuple[int, int, int, int]:
+    """(padded batch, padded D, batch tile, D tile) the kernel runs with."""
+    bp = batch + (-batch) % 8
+    dp = d + (-d) % 128
+    b_tile = min(_B_TILE, bp)
+    # Adapt the D tile to the VMEM budget: wide hidden states (L/XL configs,
+    # 3H up to 12k) shrink the K-tile instead of losing the kernel.
+    d_tile = min(_D_TILE, dp)
+    while d_tile > 128 and d_tile * 3 * hidden * 4 > _W_TILE_BUDGET:
+        d_tile //= 2
+    return bp, dp, b_tile, d_tile
+
+
+def _vmem_bytes(batch: int, d: int, hidden: int, itemsize: int) -> int:
+    """Upper bound on the VMEM one grid step holds, counted as the compiler
+    counts it: every input and output block double-buffered (inp, W, the
+    three [1, 3H] vectors, h; out and the f32 z), plus the f32 accumulator.
+    Within a few percent above the compiler's own total at every Dreamer
+    size (S..XL, train and imagination batch), and never below it."""
+    _, _, b_tile, d_tile = _tiles(batch, d, hidden)
+    h3 = 3 * hidden
+    inputs = (b_tile * d_tile + d_tile * h3 + b_tile * hidden) * itemsize + h3 * (itemsize + 4 + 4)
+    outputs = b_tile * hidden * itemsize + b_tile * h3 * 4
+    return 2 * (inputs + outputs) + b_tile * h3 * 4
+
+
+def ineligible_reason(batch: int, d: int, hidden: int, itemsize: int) -> Optional[str]:
+    """Why the kernel cannot take this shape on a TPU, or None when it can."""
+    if hidden % 128 != 0:
+        return f"hidden size {hidden} is not a multiple of the 128-lane tile"
+    need = _vmem_bytes(batch, d, hidden, itemsize)
+    if need > _VMEM_LIMIT_BYTES:
+        return (
+            f"its blocks need {need / 2**20:.1f} MiB of VMEM per grid step, over the "
+            f"{_VMEM_LIMIT_BYTES / 2**20:.0f} MiB scoped limit"
+        )
+    return None
 
 
 def _gates_from_z(z, scale, ln_bias, h):
@@ -97,22 +145,13 @@ def _pallas_ln_gru(inp, w, b, scale, ln_bias, h, *, interpret: bool = False):
 
     # Pad batch to the f32 sublane tile and D to the lane tile; zero rows and
     # zero K-columns do not perturb the matmul.
-    pb = (-batch) % 8
-    pd = (-d) % 128
-    if pb:
-        inp = jnp.pad(inp, ((0, pb), (0, 0)))
-        h = jnp.pad(h, ((0, pb), (0, 0)))
-    if pd:
-        inp = jnp.pad(inp, ((0, 0), (0, pd)))
-        w = jnp.pad(w, ((0, pd), (0, 0)))
-    bp, dp = inp.shape
-
-    b_tile = min(_B_TILE, bp)
-    # Adapt the D tile to the VMEM budget: wide hidden states (L/XL configs,
-    # 3H up to 12k) shrink the K-tile instead of losing the kernel.
-    d_tile = min(_D_TILE, dp)
-    while d_tile > 128 and d_tile * h3 * 4 > _W_TILE_BUDGET:
-        d_tile //= 2
+    bp, dp, b_tile, d_tile = _tiles(batch, d, hidden)
+    if bp != batch:
+        inp = jnp.pad(inp, ((0, bp - batch), (0, 0)))
+        h = jnp.pad(h, ((0, bp - batch), (0, 0)))
+    if dp != d:
+        inp = jnp.pad(inp, ((0, 0), (0, dp - d)))
+        w = jnp.pad(w, ((0, dp - d), (0, 0)))
     grid = (pl.cdiv(bp, b_tile), pl.cdiv(dp, d_tile))
 
     out, z = pl.pallas_call(
@@ -141,14 +180,19 @@ def _pallas_ln_gru(inp, w, b, scale, ln_bias, h, *, interpret: bool = False):
 
 
 def _eligible(inp, w, h) -> bool:
-    hidden = h.shape[-1]
-    if hidden % 128 != 0:
+    if jax.default_backend() != "tpu":
         return False
-    # The adaptive D-tiling floors at 128 lanes; beyond that the W tile
-    # cannot fit the budget.
-    if 128 * 3 * hidden * 4 > _W_TILE_BUDGET:
-        return False
-    return jax.default_backend() == "tpu"
+    batch, d = inp.shape
+    reason = ineligible_reason(batch, d, h.shape[-1], jnp.dtype(inp.dtype).itemsize)
+    if reason is not None:
+        # The caller asked for the fused cell (fused=True or the env knob):
+        # say that it is not getting it. The warnings registry shows each
+        # distinct message, hence each shape, once.
+        warnings.warn(
+            f"fused LN-GRU kernel skipped for inp[{batch}, {d}] x W[{d}, {w.shape[-1]}] "
+            f"({jnp.dtype(inp.dtype).name}): {reason}; running the plain-JAX cell"
+        )
+    return reason is None
 
 
 @jax.custom_vjp

@@ -23,11 +23,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:
-    # Version-stable home on the pinned minimum jax (0.4.37).
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # newer jax graduated it to the top level
-    from jax import shard_map  # graftlint: disable=GL003
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -115,7 +110,7 @@ def ring_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_local,
             axis_name=axis_name,
@@ -147,7 +142,7 @@ def seq_all_to_all(
     by the axis size."""
     in_spec = P(None, axis_name, None, None) if to_heads else P(None, None, axis_name, None)
     out_spec = P(None, None, axis_name, None) if to_heads else P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_seq_all_to_all_local, axis_name=axis_name, to_heads=to_heads),
         mesh=mesh,
         in_specs=(in_spec,),

@@ -6,7 +6,7 @@ Parts (see each module's docstring for the design):
   JSONL exporters, the process-wide current tracer;
 - :mod:`~sheeprl_tpu.telemetry.step_timer` — async-dispatch-aware step
   timing with the coalesced per-interval metric fetch (the productized
-  donated-chain pattern from PROFILE.md);
+  donated-chain pattern: N chained dispatches bounded by one fetch);
 - :mod:`~sheeprl_tpu.telemetry.histogram` — streaming geometric-bucket
   latency histogram (p50/p95/p99) used by StepTimer and the serving engine;
 - :mod:`~sheeprl_tpu.telemetry.jax_events` — compile/retrace/cache

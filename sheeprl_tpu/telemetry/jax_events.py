@@ -18,7 +18,7 @@ telemetry stacks per process, so ONE module-level listener pair is
 registered lazily and fans out to the currently-attached monitors — attach/
 detach is list membership, not listener churn.
 
-Retrace detection: PROFILE.md had to hand-exclude the "hidden recompile"
+Retrace detection: hand-run profiling has to exclude the "hidden recompile"
 (the second call after compilation recompiles once for the donated-layout
 change). :meth:`JaxEventMonitor.advance` is called once per train
 iteration; compiles observed after ``warmup_iters`` iterations are counted

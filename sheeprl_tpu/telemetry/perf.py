@@ -37,6 +37,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+import warnings
 from contextlib import contextmanager
 from typing import Any, Dict, Optional, Tuple
 
@@ -44,6 +45,7 @@ __all__ = [
     "PerfAccountant",
     "jit_cost",
     "resolve_peaks",
+    "peaks_for_device_kind",
     "last_published",
     "GAUGE_PREFIX",
 ]
@@ -53,12 +55,13 @@ GAUGE_PREFIX = "perf"
 #: Peak dense-math FLOP/s and HBM bandwidth (bytes/s) per accelerator kind,
 #: matched by substring against ``device.device_kind.lower()``. Sources: the
 #: public TPU/GPU datasheets (bf16/fp16 peak for accelerators — the recipe
-#: precision on those backends). First match wins; order matters (v5p before
-#: v5, "v3" before "v2"-style prefixes is irrelevant here because kinds are
-#: distinct strings).
+#: precision on those backends). First match wins. A TPU v5e reports
+#: ``device_kind == "TPU v5 lite"``, so its peaks (Google Cloud "TPU v5e":
+#: 197 TFLOP/s bf16, 819 GB/s HBM) are listed under both spellings.
 PEAK_TABLE: Tuple[Tuple[str, float, float], ...] = (
     ("v5p", 459e12, 2.765e12),
-    ("v5e", 197e12, 0.82e12),
+    ("v5 lite", 197e12, 819e9),
+    ("v5e", 197e12, 819e9),
     ("v4", 275e12, 1.23e12),
     ("v3", 123e12, 0.90e12),
     ("v2", 45e12, 0.70e12),
@@ -67,6 +70,21 @@ PEAK_TABLE: Tuple[Tuple[str, float, float], ...] = (
     ("v100", 125e12, 0.90e12),
     ("rtx 3080", 59.5e12, 0.76e12),
 )
+
+
+def peaks_for_device_kind(device_kind: str) -> Tuple[float, float]:
+    """``(peak FLOP/s, peak bytes/s)`` of an accelerator from
+    :data:`PEAK_TABLE`. A kind the table does not hold is an error that
+    names it — a missing row must never read as a zero ceiling."""
+    kind = (device_kind or "").lower()
+    for needle, flops, bw in PEAK_TABLE:
+        if needle in kind:
+            return flops, bw
+    raise LookupError(
+        f"device kind {device_kind!r} is not in sheeprl_tpu.telemetry.perf.PEAK_TABLE: add its "
+        "datasheet peaks there (or set SHEEPRL_PERF_PEAK_FLOPS and SHEEPRL_PERF_PEAK_BW_GBPS)"
+    )
+
 
 # Module-level "most recent publish" readout, mirroring
 # core/interact.last_run_stats(): bench.py embeds the goodput snapshot of a
@@ -138,9 +156,11 @@ def resolve_peaks(
     """The hardware ceiling for roofline accounting, resolved in priority
     order: explicit/config values, ``SHEEPRL_PERF_PEAK_FLOPS`` /
     ``SHEEPRL_PERF_PEAK_BW_GBPS`` env overrides, the :data:`PEAK_TABLE`
-    device-kind match, then the CPU micro-kernel probe. Returns
-    ``{"flops", "bytes_per_s", "source"}`` with zeros when nothing resolves
-    (gauges depending on the ceiling are then omitted, never wrong)."""
+    device-kind match on an accelerator, the CPU micro-kernel probe on the
+    CPU backend. Returns ``{"flops", "bytes_per_s", "source"}``; an
+    accelerator kind the table does not hold is warned about by name and
+    resolves to zeros (gauges depending on the ceiling are then omitted,
+    never wrong)."""
     env_flops = os.environ.get("SHEEPRL_PERF_PEAK_FLOPS")
     env_bw = os.environ.get("SHEEPRL_PERF_PEAK_BW_GBPS")
     try:
@@ -164,31 +184,28 @@ def resolve_peaks(
             backend = backend or "unknown"
             device_kind = device_kind or ""
 
-    kind = (device_kind or "").lower()
-    for needle, flops, bw in PEAK_TABLE:
-        if needle in kind:
-            return {
-                "flops": float(peak_flops if peak_flops is not None else flops),
-                "bytes_per_s": float(peak_bytes_per_s if peak_bytes_per_s is not None else bw),
-                "source": "table",
-            }
-
-    if backend == "cpu" and probe:
+    flops, bw, source = 0.0, 0.0, "none"
+    if backend != "cpu":
+        try:
+            flops, bw = peaks_for_device_kind(device_kind or "")
+            source = "table"
+        except LookupError as err:
+            # A training run goes on without its utilization gauges, but
+            # says which device it could not account for; chip_smoke.py and
+            # bench.py call peaks_for_device_kind themselves and fail.
+            warnings.warn(f"{err}; perf/mfu and perf/hbm_bw_util are not published")
+    elif probe:
         with _probe_lock:
             cached = _probe_cache.get("cpu")
             if cached is None:
                 cached = _probe_cpu_peaks()
                 _probe_cache["cpu"] = cached
         flops, bw = cached
-        return {
-            "flops": float(peak_flops if peak_flops is not None else flops),
-            "bytes_per_s": float(peak_bytes_per_s if peak_bytes_per_s is not None else bw),
-            "source": "probe",
-        }
+        source = "probe"
     return {
-        "flops": float(peak_flops or 0.0),
-        "bytes_per_s": float(peak_bytes_per_s or 0.0),
-        "source": "none",
+        "flops": float(peak_flops if peak_flops is not None else flops),
+        "bytes_per_s": float(peak_bytes_per_s if peak_bytes_per_s is not None else bw),
+        "source": source,
     }
 
 

@@ -3,9 +3,10 @@
 XLA dispatch is asynchronous: the wall-clock around a jitted train call
 measures the *enqueue*, not the step — and the obvious fix (block every
 step) serializes the pipeline and is exactly the per-iteration host sync
-graftlint's GL002 exists to kill. PROFILE.md's hand-rolled answer was the
-donated-chain pattern: time N chained dispatches and bound the chain with a
-single host fetch at the end. :class:`StepTimer` productizes it:
+graftlint's GL002 exists to kill. The hand-rolled answer of the round-3
+profiling was the donated-chain pattern: time N chained dispatches and bound
+the chain with a single host fetch at the end. :class:`StepTimer`
+productizes it:
 
 - :meth:`step` wraps each dispatch and accumulates the enqueue wall-clock
   (cheap, async, never blocks);

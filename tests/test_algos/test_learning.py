@@ -37,24 +37,6 @@ from scripts.validate_returns import (  # noqa: E402
 _RUN_SLOW = os.environ.get("SHEEPRL_SLOW_TESTS", "") == "1"
 
 
-@pytest.fixture(autouse=True)
-def _restore_virtual_mesh():
-    """The validators force a fresh CPU platform sized for themselves
-    (1 or 2 devices); restore the suite's 8-device virtual mesh afterwards
-    so later-collected tests (test_core/test_mesh_runtime.py asserts 8,
-    ring attention needs 4+) see the conftest topology. Only when the
-    validator actually changed the topology: a force-clear invalidates any
-    jax arrays other fixtures hold, so a skipped test (slow gate) must not
-    pay it."""
-    yield
-    import jax
-
-    if len(jax.devices()) != 8:
-        from sheeprl_tpu.core.runtime import force_cpu_platform
-
-        force_cpu_platform(num_devices=8, force=True)
-
-
 def test_ppo_learns_cartpole():
     r = validate_ppo()
     assert r["mean_return"] >= r["threshold"], (

@@ -16,6 +16,7 @@ keep them within rtol=2e-4 / atol=1e-5 (howto/sharded_training.md).
 import glob
 import json
 import os
+import sys
 
 import jax
 import numpy as np
@@ -25,10 +26,16 @@ from sheeprl_tpu.cli import run
 from sheeprl_tpu.core import fused_loop
 from sheeprl_tpu.utils.checkpoint import load_checkpoint
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+
+# The recipes and the tolerance live with chip_smoke.py, which makes the same
+# 1 <-> N comparison on four real chips (`python chip_smoke.py --chips 4`).
+import chip_smoke  # noqa: E402
+
 NEEDS_8 = pytest.mark.skipif(jax.device_count() < 8, reason="needs the 8-device CPU platform")
 
-RTOL = 2e-4
-ATOL = 1e-5
+RTOL = chip_smoke.PARITY_RTOL
+ATOL = chip_smoke.PARITY_ATOL
 
 
 @pytest.fixture(autouse=True)
@@ -46,55 +53,11 @@ def find_checkpoints(root):
 
 
 def sac_shard_overrides(devices, **extra):
-    args = [
-        "exp=sac_anakin",
-        "metric.log_level=0",
-        "env.num_envs=8",
-        "env.sync_env=True",
-        "algo.fused_superstep_steps=4",
-        "algo.fused_train_steps=4",
-        "algo.total_steps=96",
-        "algo.learning_starts=32",
-        "algo.per_rank_batch_size=8",
-        "algo.hidden_size=8",
-        "algo.run_test=False",
-        "algo.fused_rollout=True",
-        "buffer.size=256",
-        "buffer.memmap=False",
-        "checkpoint.every=0",
-        "checkpoint.save_last=True",
-        "fabric.accelerator=cpu",
-        f"fabric.devices={devices}",
-    ]
-    for k, v in extra.items():
-        args.append(f"{k}={v}")
-    return args
+    return chip_smoke.sac_shard_overrides(devices, "cpu", **extra)
 
 
 def ppo_shard_overrides(devices, **extra):
-    args = [
-        "exp=ppo_anakin",
-        "metric.log_level=0",
-        "env.num_envs=8",
-        "env.sync_env=True",
-        "algo.rollout_steps=4",
-        "algo.total_steps=64",
-        "algo.per_rank_batch_size=8",
-        "algo.update_epochs=1",
-        "algo.dense_units=8",
-        "algo.mlp_layers=1",
-        "algo.encoder.mlp_features_dim=8",
-        "algo.run_test=False",
-        "algo.fused_rollout=True",
-        "buffer.memmap=False",
-        "checkpoint.every=0",
-        "checkpoint.save_last=True",
-        "fabric.accelerator=cpu",
-        f"fabric.devices={devices}",
-    ]
-    for k, v in extra.items():
-        args.append(f"{k}={v}")
-    return args
+    return chip_smoke.ppo_shard_overrides(devices, "cpu", **extra)
 
 
 def _assert_tree_close(a, b, rtol=RTOL, atol=ATOL):

@@ -1,18 +1,29 @@
 """Toy actor loops for FleetSupervisor unit tests.
 
 Importable by spawn children (the supervisor forwards the parent's sys.path,
-which includes this directory), deliberately JAX-free so each replica process
-starts in well under a second. Every shipped row is tagged with the replica's
-identity triple (replica, restart, seed) so the learner-side assertions can
-reconstruct exactly which process generation produced it.
+which includes this directory), and never touching a JAX backend so each
+replica process starts in well under a second. Every shipped row is tagged
+with the replica's identity triple (replica, restart, seed) so the
+learner-side assertions can reconstruct exactly which process generation
+produced it, and with the JAX platform selection the replica entry left in
+force before the actor ran.
 """
 
 import os
+import sys
 import time
 
 
 def _tagged(ctx, i):
-    return {"replica": ctx.replica, "restart": ctx.restart, "seed": ctx.seed, "i": i}
+    # jax is imported (not initialised) in every replica: unpickling the
+    # spawn arguments imports sheeprl_tpu.core.
+    return {
+        "replica": ctx.replica,
+        "restart": ctx.restart,
+        "seed": ctx.seed,
+        "i": i,
+        "jax_platforms": sys.modules["jax"].config.jax_platforms,
+    }
 
 
 def steady(ctx):

@@ -72,12 +72,17 @@ def test_supervisor_rejects_bad_quorum():
 
 
 # ------------------------------------------------------- steady-state fleet
-def test_steady_fleet_ships_everything_then_finishes_clean():
+def test_steady_fleet_ships_everything_then_finishes_clean(monkeypatch):
+    # The learner's environment names the accelerator first, as on a TPU
+    # host; a replica must still select the CPU platform before its actor
+    # runs — the chip belongs to the learner process.
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
     sup = make_sup("steady", replicas=2)
     sup.start()
     try:
         shipments = collect(sup)
         assert len(shipments) == 10  # 2 replicas x toy_total rows
+        assert {s.rows["jax_platforms"] for s in shipments} == {"cpu"}
         by_replica = {r: [s for s in shipments if s.replica == r] for r in (0, 1)}
         for r, group in by_replica.items():
             assert [s.rows["i"] for s in group] == list(range(5))
@@ -130,7 +135,9 @@ def test_quorum_breaker_trips_when_fleet_cannot_recover():
 
 
 def test_heartbeat_timeout_reaps_hung_replica():
-    sup = make_sup("hang", replicas=1, heartbeat_timeout_s=1.0)
+    # The deadline must outlast a replica's start-up (a spawn child imports
+    # jax before its hello: 2-3 s), or the restarted generation is reaped too.
+    sup = make_sup("hang", replicas=1, heartbeat_timeout_s=4.0)
     sup.start()
     try:
         shipments = collect(sup)

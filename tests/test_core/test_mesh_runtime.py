@@ -116,7 +116,7 @@ def test_split_player_trainer_model_axis_needs_two_data_rows():
 
 
 def test_split_player_trainer_auto_with_params():
-    """auto + params threads the size guard (ADVICE r2): on the CPU test
+    """auto + params threads the size guard (a round-2 review item): on the CPU test
     platform host==mesh silicon, so the split stays on-mesh regardless."""
     import jax.numpy as jnp
 

@@ -21,6 +21,7 @@ from sheeprl_tpu.telemetry.perf import (
     PerfAccountant,
     jit_cost,
     last_published,
+    peaks_for_device_kind,
     resolve_peaks,
 )
 from sheeprl_tpu.telemetry.registry import MetricsRegistry, default_registry
@@ -61,8 +62,23 @@ class TestResolvePeaks:
         assert time.perf_counter() - t0 < 0.05
         assert again["flops"] == peaks["flops"]
 
-    def test_unknown_backend_without_probe_resolves_nothing(self):
-        peaks = resolve_peaks(backend="rocm", device_kind="mystery", probe=False)
+    def test_cpu_without_probe_resolves_nothing(self):
+        peaks = resolve_peaks(backend="cpu", device_kind="generic-cpu", probe=False)
+        assert peaks == {"flops": 0.0, "bytes_per_s": 0.0, "source": "none"}
+
+    def test_v5e_row_matches_the_kind_the_chip_reports(self):
+        # A TPU v5e's device_kind is "TPU v5 lite" (chip_smoke.py prints it).
+        peaks = resolve_peaks(backend="tpu", device_kind="TPU v5 lite", probe=False)
+        assert peaks == {"flops": 197e12, "bytes_per_s": 819e9, "source": "table"}
+        assert peaks_for_device_kind("TPU v5 lite") == peaks_for_device_kind("TPU v5e") == (197e12, 819e9)
+
+    def test_unknown_accelerator_kind_is_reported_by_name(self):
+        # Strict callers (chip_smoke.py, bench.py) get an error naming the
+        # kind; a training run gets a warning naming it — never silent zeros.
+        with pytest.raises(LookupError, match="mystery-9000"):
+            peaks_for_device_kind("mystery-9000")
+        with pytest.warns(UserWarning, match="mystery-9000"):
+            peaks = resolve_peaks(backend="rocm", device_kind="mystery-9000", probe=False)
         assert peaks == {"flops": 0.0, "bytes_per_s": 0.0, "source": "none"}
 
 
