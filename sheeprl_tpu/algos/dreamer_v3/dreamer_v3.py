@@ -50,6 +50,7 @@ from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialRepla
 from sheeprl_tpu.data.device_buffer import DeviceReplayRing
 from sheeprl_tpu.core.runtime import DispatchThrottle
 from sheeprl_tpu.registry import register_algorithm
+from sheeprl_tpu.telemetry import scopes
 from sheeprl_tpu.telemetry.health import health_probe, probes_enabled
 from sheeprl_tpu.utils.checkpoint import load_checkpoint, restore_opt_state, save_checkpoint
 from sheeprl_tpu.utils.distribution import (
@@ -135,86 +136,89 @@ def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation]
 
     def world_loss_fn(wm_params, data, batch_obs, keys):
         T, B = data["rewards"].shape[:2]
-        embedded = agent.wm(wm_params, batch_obs, method="embed_obs")  # [T, B, E]
+        with scopes.scope(scopes.DV3_ENCODER):
+            embedded = agent.wm(wm_params, batch_obs, method="embed_obs")  # [T, B, E]
 
-        batch_actions = jnp.concatenate(
-            [jnp.zeros_like(data["actions"][:1]), data["actions"][:-1]], axis=0
-        )
-        is_first = data["is_first"].at[0].set(1.0)
-
-        h0 = jnp.zeros((B, recurrent_state_size), embedded.dtype)
-        z0 = jnp.zeros((B, stoch_state_size), embedded.dtype)
-        step_keys, post_key = keys[:T], keys[T]
-
-        if decoupled:
-            # Decoupled RSSM (reference: dreamer_v3.py:115-130): posteriors are
-            # obs-only, computed for the WHOLE sequence in one batched matmul;
-            # the scan then only threads the recurrent state, feeding each step
-            # the previous step's posterior.
-            posteriors_logits, posteriors = agent.world_model.apply(
-                wm_params, embedded, post_key, method=WorldModel.posterior_obs_only
+        with scopes.scope(scopes.DV3_RSSM):
+            batch_actions = jnp.concatenate(
+                [jnp.zeros_like(data["actions"][:1]), data["actions"][:-1]], axis=0
             )
-            prev_posteriors = jnp.concatenate([jnp.zeros_like(posteriors[:1]), posteriors[:-1]], 0)
+            is_first = data["is_first"].at[0].set(1.0)
 
-            def dstep(h, x):
-                z_prev, action, first, key = x
-                h, _, prior_logits = agent.world_model.apply(
-                    wm_params, z_prev, h, action, first, key, method=WorldModel.dynamic_decoupled
+            h0 = jnp.zeros((B, recurrent_state_size), embedded.dtype)
+            z0 = jnp.zeros((B, stoch_state_size), embedded.dtype)
+            step_keys, post_key = keys[:T], keys[T]
+
+            if decoupled:
+                # Decoupled RSSM (reference: dreamer_v3.py:115-130): posteriors are
+                # obs-only, computed for the WHOLE sequence in one batched matmul;
+                # the scan then only threads the recurrent state, feeding each step
+                # the previous step's posterior.
+                posteriors_logits, posteriors = agent.world_model.apply(
+                    wm_params, embedded, post_key, method=WorldModel.posterior_obs_only
                 )
-                return h, (h, prior_logits)
+                prev_posteriors = jnp.concatenate([jnp.zeros_like(posteriors[:1]), posteriors[:-1]], 0)
 
-            _, (recurrent_states, priors_logits) = jax.lax.scan(
-                dstep, h0, (prev_posteriors, batch_actions, is_first, step_keys)
-            )
-        else:
+                def dstep(h, x):
+                    z_prev, action, first, key = x
+                    h, _, prior_logits = agent.world_model.apply(
+                        wm_params, z_prev, h, action, first, key, method=WorldModel.dynamic_decoupled
+                    )
+                    return h, (h, prior_logits)
 
-            def step(carry, x):
-                h, z = carry
-                action, emb, first, key = x
-                h, post, prior, post_logits, prior_logits = agent.world_model.apply(
-                    wm_params, z, h, action, emb, first, key, method=WorldModel.dynamic
+                _, (recurrent_states, priors_logits) = jax.lax.scan(
+                    dstep, h0, (prev_posteriors, batch_actions, is_first, step_keys)
                 )
-                return (h, post), (h, post, post_logits, prior_logits)
+            else:
 
-            (_, _), (recurrent_states, posteriors, posteriors_logits, priors_logits) = jax.lax.scan(
-                step, (h0, z0), (batch_actions, embedded, is_first, step_keys)
-            )
-        latent_states = jnp.concatenate([posteriors, recurrent_states], -1)
+                def step(carry, x):
+                    h, z = carry
+                    action, emb, first, key = x
+                    h, post, prior, post_logits, prior_logits = agent.world_model.apply(
+                        wm_params, z, h, action, emb, first, key, method=WorldModel.dynamic
+                    )
+                    return (h, post), (h, post, post_logits, prior_logits)
 
-        reconstructed_obs = agent.wm(wm_params, latent_states, method="decode")
-        po = {
-            k: MSEDistribution(reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:]))
-            for k in cnn_dec_keys
-        }
-        po.update(
-            {
-                k: SymlogDistribution(reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:]))
-                for k in mlp_dec_keys
+                (_, _), (recurrent_states, posteriors, posteriors_logits, priors_logits) = jax.lax.scan(
+                    step, (h0, z0), (batch_actions, embedded, is_first, step_keys)
+                )
+        with scopes.scope(scopes.DV3_HEADS):
+            latent_states = jnp.concatenate([posteriors, recurrent_states], -1)
+
+            reconstructed_obs = agent.wm(wm_params, latent_states, method="decode")
+            po = {
+                k: MSEDistribution(reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:]))
+                for k in cnn_dec_keys
             }
-        )
-        pr = TwoHotEncodingDistribution(agent.wm(wm_params, latent_states, method="reward_logits"), dims=1)
-        pc = Independent(
-            BernoulliSafeMode(logits=agent.wm(wm_params, latent_states, method="continue_logits")), 1
-        )
-        continues_targets = 1 - data["terminated"]
+            po.update(
+                {
+                    k: SymlogDistribution(reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:]))
+                    for k in mlp_dec_keys
+                }
+            )
+            pr = TwoHotEncodingDistribution(agent.wm(wm_params, latent_states, method="reward_logits"), dims=1)
+            pc = Independent(
+                BernoulliSafeMode(logits=agent.wm(wm_params, latent_states, method="continue_logits")), 1
+            )
+            continues_targets = 1 - data["terminated"]
 
-        pl = priors_logits.reshape(*priors_logits.shape[:-1], stochastic_size, discrete_size)
-        pol = posteriors_logits.reshape(*posteriors_logits.shape[:-1], stochastic_size, discrete_size)
-        rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
-            po,
-            batch_obs,
-            pr,
-            data["rewards"],
-            pl,
-            pol,
-            wm_cfg.kl_dynamic,
-            wm_cfg.kl_representation,
-            wm_cfg.kl_free_nats,
-            wm_cfg.kl_regularizer,
-            pc,
-            continues_targets,
-            wm_cfg.continue_scale_factor,
-        )
+            pl = priors_logits.reshape(*priors_logits.shape[:-1], stochastic_size, discrete_size)
+            pol = posteriors_logits.reshape(*posteriors_logits.shape[:-1], stochastic_size, discrete_size)
+            rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
+                po,
+                batch_obs,
+                pr,
+                data["rewards"],
+                pl,
+                pol,
+                wm_cfg.kl_dynamic,
+                wm_cfg.kl_representation,
+                wm_cfg.kl_free_nats,
+                wm_cfg.kl_regularizer,
+                pc,
+                continues_targets,
+                wm_cfg.continue_scale_factor,
+            )
         aux = {
             "posteriors": posteriors,
             "recurrent_states": recurrent_states,
@@ -231,8 +235,9 @@ def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation]
     def step_core(state, opt_states, moments_state, data, key, tau):
         T, B = data["rewards"].shape[:2]
         data = jax.lax.with_sharding_constraint(data, {k: batch_sharding for k in data})
-        batch_obs = {k: data[k] / 255.0 - 0.5 for k in cnn_keys}
-        batch_obs.update({k: data[k] for k in mlp_keys})
+        with scopes.scope(scopes.DV3_ENCODER):
+            batch_obs = {k: data[k] / 255.0 - 0.5 for k in cnn_keys}
+            batch_obs.update({k: data[k] for k in mlp_keys})
 
         k_dyn, k_img0, k_img, k_actor = jax.random.split(key, 4)
         # T per-step keys + one extra for the decoupled whole-sequence posterior
@@ -242,10 +247,11 @@ def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation]
         (rec_loss, aux), wm_grads = jax.value_and_grad(world_loss_fn, has_aux=True)(
             state["world_model"], data, batch_obs, dyn_keys
         )
-        wm_updates, wm_opt = txs["world_model"].update(
-            wm_grads, opt_states["world_model"], state["world_model"]
-        )
-        state["world_model"] = optax.apply_updates(state["world_model"], wm_updates)
+        with scopes.scope(scopes.DV3_OPTIM):
+            wm_updates, wm_opt = txs["world_model"].update(
+                wm_grads, opt_states["world_model"], state["world_model"]
+            )
+            state["world_model"] = optax.apply_updates(state["world_model"], wm_updates)
 
         # --------------------------------------------- behaviour learning
         sg = jax.lax.stop_gradient
@@ -262,75 +268,77 @@ def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation]
             # Imagination rollout (actions re-sampled from the CURRENT actor
             # params so the pathwise gradient flows; reference does the same
             # through in-place module weights, dreamer_v3.py:219-241).
-            a0 = actor_sample(actor_params, latent0, k_img0)
+            with scopes.scope(scopes.DV3_IMAGINE):
+                a0 = actor_sample(actor_params, latent0, k_img0)
 
-            def img_step(carry, k):
-                prior, h, actions = carry
-                k_wm, k_act = jax.random.split(k)
-                prior, h = agent.world_model.apply(
-                    state["world_model"], prior, h, actions, k_wm, method=WorldModel.imagination
+                def img_step(carry, k):
+                    prior, h, actions = carry
+                    k_wm, k_act = jax.random.split(k)
+                    prior, h = agent.world_model.apply(
+                        state["world_model"], prior, h, actions, k_wm, method=WorldModel.imagination
+                    )
+                    latent = jnp.concatenate([prior, h], -1)
+                    next_actions = actor_sample(actor_params, latent, k_act)
+                    return (prior, h, next_actions), (latent, next_actions)
+
+                img_keys = jax.random.split(k_img, horizon)
+                _, (latents, img_actions) = jax.lax.scan(
+                    img_step, (imagined_prior, recurrent_state, a0), img_keys
                 )
-                latent = jnp.concatenate([prior, h], -1)
-                next_actions = actor_sample(actor_params, latent, k_act)
-                return (prior, h, next_actions), (latent, next_actions)
+                imagined_trajectories = jnp.concatenate([latent0[None], latents], 0)  # [H+1, TB, L]
+                imagined_actions = jnp.concatenate([a0[None], img_actions], 0)
 
-            img_keys = jax.random.split(k_img, horizon)
-            _, (latents, img_actions) = jax.lax.scan(
-                img_step, (imagined_prior, recurrent_state, a0), img_keys
-            )
-            imagined_trajectories = jnp.concatenate([latent0[None], latents], 0)  # [H+1, TB, L]
-            imagined_actions = jnp.concatenate([a0[None], img_actions], 0)
+            with scopes.scope(scopes.DV3_ACTOR_CRITIC):
+                # Predict values / rewards / continues on the imagined rollout
+                predicted_values = TwoHotEncodingDistribution(
+                    agent.critic_logits(state["critic"], imagined_trajectories), dims=1
+                ).mean
+                predicted_rewards = TwoHotEncodingDistribution(
+                    agent.wm(state["world_model"], imagined_trajectories, method="reward_logits"), dims=1
+                ).mean
+                continues = Independent(
+                    BernoulliSafeMode(
+                        logits=agent.wm(state["world_model"], imagined_trajectories, method="continue_logits")
+                    ),
+                    1,
+                ).mode
+                true_continue = (1 - data["terminated"]).reshape(1, -1, 1)
+                continues = jnp.concatenate([true_continue, continues[1:]], 0)
 
-            # Predict values / rewards / continues on the imagined rollout
-            predicted_values = TwoHotEncodingDistribution(
-                agent.critic_logits(state["critic"], imagined_trajectories), dims=1
-            ).mean
-            predicted_rewards = TwoHotEncodingDistribution(
-                agent.wm(state["world_model"], imagined_trajectories, method="reward_logits"), dims=1
-            ).mean
-            continues = Independent(
-                BernoulliSafeMode(
-                    logits=agent.wm(state["world_model"], imagined_trajectories, method="continue_logits")
-                ),
-                1,
-            ).mode
-            true_continue = (1 - data["terminated"]).reshape(1, -1, 1)
-            continues = jnp.concatenate([true_continue, continues[1:]], 0)
+                lambda_values = compute_lambda_values(
+                    predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda
+                )
+                discount = sg(jnp.cumprod(continues * gamma, 0) / gamma)
 
-            lambda_values = compute_lambda_values(
-                predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda
-            )
-            discount = sg(jnp.cumprod(continues * gamma, 0) / gamma)
+                # Actor objective (reference: dreamer_v3.py:262-297)
+                new_moments, (offset, invscale) = update_moments(
+                    moments_state,
+                    lambda_values,
+                    decay=moments_cfg.decay,
+                    max_=moments_cfg.max,
+                    percentile_low=moments_cfg.percentile.low,
+                    percentile_high=moments_cfg.percentile.high,
+                )
+                baseline = predicted_values[:-1]
+                normed_lambda_values = (lambda_values - offset) / invscale
+                normed_baseline = (baseline - offset) / invscale
+                advantage = normed_lambda_values - normed_baseline
 
-            # Actor objective (reference: dreamer_v3.py:262-297)
-            new_moments, (offset, invscale) = update_moments(
-                moments_state,
-                lambda_values,
-                decay=moments_cfg.decay,
-                max_=moments_cfg.max,
-                percentile_low=moments_cfg.percentile.low,
-                percentile_high=moments_cfg.percentile.high,
-            )
-            baseline = predicted_values[:-1]
-            normed_lambda_values = (lambda_values - offset) / invscale
-            normed_baseline = (baseline - offset) / invscale
-            advantage = normed_lambda_values - normed_baseline
-
-            pre = agent.actor.apply(actor_params, sg(imagined_trajectories))
-            _, policies = actor_forward(pre, spec, k_actor, greedy=False)
-            if spec.is_continuous:
-                objective = advantage
-                _, entropy = continuous_log_prob_and_entropy(policies[0], imagined_actions, spec)
-                entropy = ent_coef * entropy if entropy is not None else jnp.zeros(advantage.shape[:-1])
-            else:
-                splits = np.cumsum(actions_dim)[:-1]
-                per_dim = jnp.split(imagined_actions, splits, -1)
-                logp = jnp.stack(
-                    [p.log_prob(sg(a))[..., None][:-1] for p, a in zip(policies, per_dim)], -1
-                ).sum(-1)
-                objective = logp * sg(advantage)
-                entropy = ent_coef * jnp.stack([p.entropy() for p in policies], -1).sum(-1)
-            policy_loss = -jnp.mean(sg(discount[:-1]) * (objective + entropy[..., None][:-1]))
+                pre = agent.actor.apply(actor_params, sg(imagined_trajectories))
+                _, policies = actor_forward(pre, spec, k_actor, greedy=False)
+                if spec.is_continuous:
+                    objective = advantage
+                    _, entropy = continuous_log_prob_and_entropy(policies[0], imagined_actions, spec)
+                    entropy = ent_coef * entropy if entropy is not None else jnp.zeros(advantage.shape[:-1])
+                else:
+                    splits = np.cumsum(actions_dim)[:-1]
+                    per_dim = jnp.split(imagined_actions, splits, -1)
+                    logp = jnp.stack(
+                        [p.log_prob(sg(a))[..., None][:-1] for p, a in zip(policies, per_dim)], -1
+                    ).sum(-1)
+                    objective = logp * sg(advantage)
+                    entropy = ent_coef * jnp.stack([p.entropy() for p in policies], -1).sum(-1)
+                policy_loss = -jnp.mean(sg(discount[:-1]) * (objective + entropy[..., None][:-1]))
             img_aux = {
                 "imagined_trajectories": sg(imagined_trajectories),
                 "lambda_values": sg(lambda_values),
@@ -342,35 +350,48 @@ def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation]
         (policy_loss, img_aux), actor_grads = jax.value_and_grad(imagine_loss_fn, has_aux=True)(
             state["actor"]
         )
-        actor_updates, actor_opt = txs["actor"].update(actor_grads, opt_states["actor"], state["actor"])
-        state["actor"] = optax.apply_updates(state["actor"], actor_updates)
+        with scopes.scope(scopes.DV3_OPTIM):
+            actor_updates, actor_opt = txs["actor"].update(actor_grads, opt_states["actor"], state["actor"])
+            state["actor"] = optax.apply_updates(state["actor"], actor_updates)
 
         # ------------------------------------------------- critic update
         traj = img_aux["imagined_trajectories"][:-1]
         lambda_values = img_aux["lambda_values"]
         discount = img_aux["discount"]
-        predicted_target_values = TwoHotEncodingDistribution(
-            agent.critic_logits(state["target_critic"], traj), dims=1
-        ).mean
+        with scopes.scope(scopes.DV3_ACTOR_CRITIC):
+            predicted_target_values = TwoHotEncodingDistribution(
+                agent.critic_logits(state["target_critic"], traj), dims=1
+            ).mean
 
         def critic_loss_fn(critic_params):
-            qv = TwoHotEncodingDistribution(agent.critic_logits(critic_params, traj), dims=1)
-            value_loss = -qv.log_prob(lambda_values)
-            value_loss = value_loss - qv.log_prob(sg(predicted_target_values))
-            return jnp.mean(value_loss * discount[:-1].squeeze(-1))
+            with scopes.scope(scopes.DV3_ACTOR_CRITIC):
+                qv = TwoHotEncodingDistribution(agent.critic_logits(critic_params, traj), dims=1)
+                value_loss = -qv.log_prob(lambda_values)
+                value_loss = value_loss - qv.log_prob(sg(predicted_target_values))
+                return jnp.mean(value_loss * discount[:-1].squeeze(-1))
 
         value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(state["critic"])
-        critic_updates, critic_opt = txs["critic"].update(
-            critic_grads, opt_states["critic"], state["critic"]
-        )
-        state["critic"] = optax.apply_updates(state["critic"], critic_updates)
+        with scopes.scope(scopes.DV3_OPTIM):
+            critic_updates, critic_opt = txs["critic"].update(
+                critic_grads, opt_states["critic"], state["critic"]
+            )
+            state["critic"] = optax.apply_updates(state["critic"], critic_updates)
 
-        # target critic EMA (host decides tau; 0 = frozen)
-        state["target_critic"] = jax.tree_util.tree_map(
-            lambda p, tp: tau * p + (1 - tau) * tp, state["critic"], state["target_critic"]
-        )
+            # target critic EMA (host decides tau; 0 = frozen)
+            state["target_critic"] = jax.tree_util.tree_map(
+                lambda p, tp: tau * p + (1 - tau) * tp, state["critic"], state["target_critic"]
+            )
 
         opt_states = {"world_model": wm_opt, "actor": actor_opt, "critic": critic_opt}
+        with scopes.scope(scopes.DV3_HEADS):
+            post_entropy = Independent(OneHotCategorical(logits=aux["posteriors_logits"]), 1).entropy().mean()
+            prior_entropy = Independent(OneHotCategorical(logits=aux["priors_logits"]), 1).entropy().mean()
+        with scopes.scope(scopes.DV3_OPTIM):
+            grad_norms = {
+                "Grads/world_model": optax.global_norm(wm_grads),
+                "Grads/actor": optax.global_norm(actor_grads),
+                "Grads/critic": optax.global_norm(critic_grads),
+            }
         metrics = {
             "Loss/world_model_loss": rec_loss,
             "Loss/observation_loss": aux["observation_loss"],
@@ -378,17 +399,11 @@ def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation]
             "Loss/state_loss": aux["state_loss"],
             "Loss/continue_loss": aux["continue_loss"],
             "State/kl": aux["kl"],
-            "State/post_entropy": Independent(
-                OneHotCategorical(logits=aux["posteriors_logits"]), 1
-            ).entropy().mean(),
-            "State/prior_entropy": Independent(
-                OneHotCategorical(logits=aux["priors_logits"]), 1
-            ).entropy().mean(),
+            "State/post_entropy": post_entropy,
+            "State/prior_entropy": prior_entropy,
             "Loss/policy_loss": policy_loss,
             "Loss/value_loss": value_loss,
-            "Grads/world_model": optax.global_norm(wm_grads),
-            "Grads/actor": optax.global_norm(actor_grads),
-            "Grads/critic": optax.global_norm(critic_grads),
+            **grad_norms,
         }
         if probes_enabled(cfg):
             # In-jit health probe: pure reductions over the already-live grad
@@ -722,10 +737,11 @@ def main(runtime, cfg: Dict[str, Any]):
 
     def _player_step(wm, a, s, o, k):
         # PRNG split + obs normalization in-graph: ONE dispatch per env step.
-        next_k, sub = jax.random.split(k)
-        out = agent.player_step(
-            wm, a, s, normalize_player_obs(o, player_cnn_keys), sub, greedy=False
-        )
+        with scopes.scope(scopes.DV3_ACT):
+            next_k, sub = jax.random.split(k)
+            out = agent.player_step(
+                wm, a, s, normalize_player_obs(o, player_cnn_keys), sub, greedy=False
+            )
         return (*out, next_k)
 
     player_step_fn = jax.jit(_player_step)
@@ -842,7 +858,7 @@ def main(runtime, cfg: Dict[str, Any]):
                             (agent_state, opt_states, moments_state, ring.state, train_key, taus),
                             steps=k,
                         )
-                        with train_timer.step(), watch(watchdog, "train_dispatch"):
+                        with train_timer.step(k), watch(watchdog, "train_dispatch"):
                             agent_state, opt_states, moments_state, train_metrics, train_key = fused_train_fn(
                                 agent_state, opt_states, moments_state, ring.state,
                                 train_key, taus,
@@ -906,6 +922,12 @@ def main(runtime, cfg: Dict[str, Any]):
                 # copies to overlap the next env-step phase.
                 infeed.stage(per_rank_gradient_steps)
 
+    def add_rows(data, env_idxes=None) -> None:
+        with telemetry.span("replay/add", "replay"):
+            rb.add(data, env_idxes, validate_args=cfg.buffer.validate_args)
+            if ring is not None:
+                ring.add(data, env_idxes)
+
     for iter_num in range(start_iter, total_iters + 1):
         policy_step += policy_steps_per_iter
         telemetry.advance(policy_step)
@@ -924,9 +946,7 @@ def main(runtime, cfg: Dict[str, Any]):
                         axis=-1,
                     )
                 step_data["actions"] = actions.reshape((1, cfg.env.num_envs, -1))
-                rb.add(step_data, validate_args=cfg.buffer.validate_args)
-                if ring is not None:
-                    ring.add(step_data)
+                add_rows(step_data)
                 next_obs, rewards, terminated, truncated, infos = envs.step(
                     real_actions.reshape(envs.action_space.shape)
                 )
@@ -950,9 +970,7 @@ def main(runtime, cfg: Dict[str, Any]):
                 # it depends on the step's results, so the contents match the
                 # serial order exactly.
                 step_data["actions"] = actions.reshape((1, cfg.env.num_envs, -1))
-                rb.add(step_data, validate_args=cfg.buffer.validate_args)
-                if ring is not None:
-                    ring.add(step_data)
+                add_rows(step_data)
                 next_obs, rewards, terminated, truncated, infos = (
                     res.obs,
                     res.rewards,
@@ -1028,9 +1046,7 @@ def main(runtime, cfg: Dict[str, Any]):
             reset_data["actions"] = np.zeros((1, reset_envs, int(np.sum(actions_dim))), np.float32)
             reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
             reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
-            if ring is not None:
-                ring.add(reset_data, dones_idxes)
+            add_rows(reset_data, dones_idxes)
 
             step_data["rewards"][:, dones_idxes] = np.zeros_like(reset_data["rewards"])
             step_data["terminated"][:, dones_idxes] = np.zeros_like(step_data["terminated"][:, dones_idxes])

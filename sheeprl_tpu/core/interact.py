@@ -370,38 +370,32 @@ class PendingFetch:
 
         from sheeprl_tpu.core import chaos
 
+        tracer = tracer_mod.current()
         t0 = time.perf_counter()
         watchdog = self._pipeline.watchdog
-        if watchdog is not None:
-            with watchdog.guard(f"fetch/{self._label}"):
-                # Inside the armed window: a delayed_fetch drill must look
-                # exactly like a hung device fetch to the watchdog.
+        with tracer.span(f"fetch/{self._label}", "fetch", ctx=self._ctx) as span:
+            if watchdog is not None:
+                with watchdog.guard(f"fetch/{self._label}"):
+                    # Inside the armed window: a delayed_fetch drill must look
+                    # exactly like a hung device fetch to the watchdog.
+                    chaos.maybe_delay("fetch.harvest")
+                    out = jax.device_get(self._tree)
+            else:
                 chaos.maybe_delay("fetch.harvest")
                 out = jax.device_get(self._tree)
-        else:
-            chaos.maybe_delay("fetch.harvest")
-            out = jax.device_get(self._tree)
-        t1 = time.perf_counter()
+            t1 = time.perf_counter()
+            if tracer.enabled:
+                nbytes = tracer_mod.tree_bytes(out)
+                span.set(bytes=nbytes, **{"async": self._async})
+                tracer.count("device_get_calls", 1)
+                tracer.count("device_get_bytes", nbytes)
         stats = self._pipeline.stats
         stats.fetch_blocked_s += t1 - t0
-        tracer = tracer_mod.current()
         if self._async:
             stats.fetch_ride_s += t0 - self._submit_t
         else:
             stats.blocking_fetches += 1
             tracer.count(BLOCKING_CALLS_COUNTER, 1)
-        if tracer.enabled:
-            nbytes = tracer_mod.tree_bytes(out)
-            tracer.add_span(
-                f"fetch/{self._label}",
-                "fetch",
-                t0,
-                t1 - t0,
-                {"bytes": nbytes, "async": self._async},
-                ctx=self._ctx,
-            )
-            tracer.count("device_get_calls", 1)
-            tracer.count("device_get_bytes", nbytes)
         self._result = out
         self._done = True
         self._tree = None

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from sheeprl_tpu.core import mesh as mesh_lib
+from sheeprl_tpu.telemetry import scopes
 from sheeprl_tpu.telemetry import tracer as tracer_mod
 
 __all__ = ["DeviceReplayRing", "next_power_of_two"]
@@ -301,6 +302,7 @@ class DeviceReplayRing:
         env_ids = jnp.arange(n_envs)
 
         @partial(jax.jit, donate_argnums=(0,))
+        @scopes.scope(scopes.RING_WRITE)
         def write(data, pos, added, rows, mask, shift):
             # mask: [S, E] bool; rows: {k: [S, E, *f]}. Per-env cumulative
             # write count turns the staged order into ring targets; masked-out
@@ -425,6 +427,7 @@ class DeviceReplayRing:
         """
         capacity = self.capacity
 
+        @scopes.scope(scopes.RING_WRITE)
         def write(state: Dict[str, Any], row: Dict[str, jax.Array], mask: jax.Array) -> Dict[str, Any]:
             pos = state["pos"]
             added = state["added"]
@@ -522,6 +525,7 @@ class DeviceReplayRing:
                 return jnp.swapaxes(value, 0, 1)
             return value
 
+        @scopes.scope(scopes.RING_SAMPLE)
         def sample(state: Dict[str, Any], key: jax.Array) -> Dict[str, jax.Array]:
             pos = state["pos"]
             added = state["added"]

@@ -130,11 +130,21 @@ class ReplayInfeed:
         return [{k: np.asarray(v[i]) for k, v in data.items()} for i in range(n)]
 
     def take_or_sample(self, n: int) -> List[Any]:
-        """Staged device batches if available, else sample+copy synchronously."""
-        batches = self._infeed.take(n) if self._infeed is not None else None
+        """Staged device batches if available, else sample+copy synchronously.
+
+        ``infeed/take`` holds the wait on the worker's future (what the loop
+        waited for, where ``transfer/h2d_stage`` is what the worker hid) and
+        says whether the batches were staged: never, with the infeed off. On
+        a miss ``replay/sample`` and then ``transfer/h2d_sync``, the copy
+        alone, follow on the caller's thread."""
+        tracer = _current_tracer()
+        with tracer.span("infeed/take", "transfer") as take:
+            batches = self._infeed.take(n) if self._infeed is not None else None
+            take.set(hit=batches is not None)
         if batches is None:
-            with _current_tracer().span("transfer/h2d_sync", "transfer", batches=n):
-                batches = [self._device_batch(b) for b in self._sample_host(n)]
+            host_batches = self._sample_host(n)
+            with tracer.span("transfer/h2d_sync", "transfer", batches=n):
+                batches = [self._device_batch(b) for b in host_batches]
         return batches
 
     def stage(self, n: int) -> None:

@@ -1,7 +1,6 @@
 """Config-driven ``jax.profiler`` integration: step-window traces + server.
 
-The one-off profiling recipe (scripts/profile_dreamer_v3.py used to inline
-it) becomes a run feature: configure ``telemetry.profiler.start_step`` /
+Profiling is a run feature: configure ``telemetry.profiler.start_step`` /
 ``stop_step`` and the run traces exactly that policy-step window
 ``[start, stop)`` into an XLA/xplane trace directory, viewable with
 Perfetto / TensorBoard's profile plugin. Optionally a live profiler server
@@ -76,7 +75,15 @@ class ProfilerWindow:
         assert self.trace_dir, "ProfilerWindow needs trace_dir before starting"
         try:
             os.makedirs(self.trace_dir, exist_ok=True)
-            jax.profiler.start_trace(self.trace_dir)
+            # Host tracer at level 1: the lowest that records TraceAnnotations
+            # (the program's own spans, tracer.py); Python tracer off. The TPU
+            # runtime's own level-1 annotations come with it and slow a host
+            # loop that copies pixel batches several times over (PERF.md, PR
+            # 25): device operation times stay true, idle gaps do not.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+            jax.profiler.start_trace(self.trace_dir, profiler_options=options)
         except Exception as e:  # pragma: no cover - backend-dependent
             warnings.warn(f"jax.profiler.start_trace({self.trace_dir}) failed: {e}")
             self._done = True

@@ -1,0 +1,34 @@
+"""The one table of device scope names, and the only caller of
+``jax.named_scope`` in the program.
+
+A scope only writes into the ``op_name`` metadata of the instructions traced
+under it (``jit(train_step)/jvp(dv3/rssm)/while/body/...``); the compiled
+program, its memory and its compilation-cache key are unchanged. JAX itself
+tells the directions apart: the backward pass of a scope entered inside
+``value_and_grad`` arrives as ``transpose(jvp(<scope>))``. A device profile
+(``telemetry=profile``, or the benchmark's traced run) is reduced to per-phase
+self time by these names (``benchmarks/layer_metrics/_scopes.py``), so a name
+here is part of that yardstick: rename one only with its reader.
+"""
+
+from __future__ import annotations
+
+import jax
+
+# The DreamerV3 gradient step (algos/dreamer_v3/dreamer_v3.py:make_step_core)
+DV3_ENCODER = "dv3/encoder"  # observation normalisation and encoders (CNN + MLP)
+DV3_RSSM = "dv3/rssm"  # the dynamics-learning scan over the sequence
+DV3_HEADS = "dv3/heads"  # decoders, reward and continue heads, world-model loss and KL
+DV3_IMAGINE = "dv3/imagine"  # the imagination scan over the horizon (actor sample + world model)
+DV3_ACTOR_CRITIC = "dv3/actor_critic"  # lambda returns, moments, actor and critic losses, target critic
+DV3_OPTIM = "dv3/optim"  # clipping, the three optimizer updates, the target EMA, gradient norms
+DV3_STEP = (DV3_ENCODER, DV3_RSSM, DV3_HEADS, DV3_IMAGINE, DV3_ACTOR_CRITIC, DV3_OPTIM)
+# Outside the gradient step
+DV3_ACT = "dv3/act"  # the player's acting step
+RING_SAMPLE = "replay/ring_sample"  # the in-jit sampler of the device replay ring
+RING_WRITE = "replay/ring_write"  # the ring's donated write program
+
+
+def scope(name: str):
+    """``with scope(DV3_RSSM): ...`` inside traced code."""
+    return jax.named_scope(name)

@@ -54,6 +54,7 @@ class StepTimer:
         self._pending: deque = deque(maxlen=int(max_pending))
         self._token: Any = None
         self.steps = 0
+        self.gradient_steps = 0
         self.dispatch_s = 0.0
         self.bound_s = 0.0
         self.flushes = 0
@@ -66,17 +67,19 @@ class StepTimer:
 
     # ------------------------------------------------------------- dispatch
     @contextmanager
-    def step(self):
+    def step(self, gradient_steps: int = 1):
         """Wrap ONE jitted dispatch; accumulates enqueue wall-clock and emits
-        a dispatch span."""
+        a dispatch span. ``gradient_steps`` is what the dispatch holds (K for
+        a fused K-step call): ``loop/iteration`` reports it."""
+        trc = tracer_mod.current()
         start = time.perf_counter()
-        yield
+        with trc.span(f"{self.name}/dispatch", "dispatch"):
+            yield
         elapsed = time.perf_counter() - start
         self.steps += 1
+        self.gradient_steps += int(gradient_steps)
         self.dispatch_s += elapsed
         self.dispatch_hist.record(elapsed)
-        trc = tracer_mod.current()
-        trc.add_span(f"{self.name}/dispatch", "dispatch", start, elapsed)
         # Dispatch-count counter: fused K-step trains show up as one
         # dispatch, which is the whole point — the counter is how the A/B
         # proves it.

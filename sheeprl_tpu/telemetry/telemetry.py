@@ -113,6 +113,7 @@ class Telemetry:
         self._tracing_open = False
         self._trace_root: Optional[trace_context.TraceContext] = None
         self._trace_token: Any = None
+        self._iteration: Optional[tuple] = None  # the open loop/iteration: (ctx, start, step, gradient steps before)
         self._carrier_prev: Optional[tuple] = None
         self._flight: Optional[flight_mod.FlightRecorder] = None
         self._flight_tracer: Optional[Tracer] = None
@@ -208,6 +209,9 @@ class Telemetry:
                     "profiler_window": [self._profiler.start_step, self._profiler.stop_step],
                     "trace_id": self._trace_root.trace_id if self._trace_root else None,
                     "pid": os.getpid(),
+                    # span timestamps (ts_us) count from these two readings of one instant
+                    "perf_epoch_s": self._tracer.perf_epoch_s,
+                    "wall_epoch_s": self._tracer.wall_epoch_s,
                     # Provenance stamps: which code on which hardware produced
                     # this run — the same identity bench history records carry.
                     # Stamp the PACKAGE checkout, not the run cwd: runs launch
@@ -298,6 +302,7 @@ class Telemetry:
     def close(self) -> None:
         """Stop profiling, detach counters, export trace.json/telemetry.jsonl
         (rank zero), and restore the previously-installed tracer."""
+        self._end_iteration(time.perf_counter())
         for st in self._step_timers.values():
             st.flush()
         if self._opened:
@@ -348,20 +353,42 @@ class Telemetry:
 
     def advance(self, step: int) -> None:
         """Once per train iteration: drives the profiler window and the
-        recompile-after-warmup watchdog, and rolls the active trace context
-        to a fresh per-iteration child of the run root (so every span this
-        iteration emits — dispatch, fetch, ship, env restarts — parents to
-        one iteration marker)."""
+        recompile-after-warmup watchdog, closes the previous ``loop/iteration``
+        span and rolls the active trace context to a fresh per-iteration child
+        of the run root (so every span this iteration emits — dispatch, fetch,
+        ship, env restarts — parents to one iteration span)."""
         if self._trace_root is not None:
+            now = time.perf_counter()
+            self._end_iteration(now)
             ctx = self._trace_root.child()
             trace_context.set_current(ctx)
-            tracer_mod.current().add_span(
-                "loop/iteration", "loop", time.perf_counter(), 0.0, {"step": int(step)}, ctx=ctx
-            )
+            self._iteration = (ctx, now, int(step), self._gradient_steps())
         if not self.enabled:
             return
         self._profiler.advance(step)
         self._monitor.advance()
+
+    def _gradient_steps(self) -> int:
+        train = self._step_timers.get("train")
+        return train.gradient_steps if train is not None else 0
+
+    def _end_iteration(self, now: float) -> None:
+        """``loop/iteration`` is the loop's request: it runs from one
+        :meth:`advance` to the next (or to :meth:`close`), every span the
+        iteration emits carries its trace context, and its args say how many
+        gradient steps it dispatched."""
+        if self._iteration is None:
+            return
+        ctx, start, step, steps_before = self._iteration
+        self._iteration = None
+        tracer_mod.current().add_span(
+            "loop/iteration",
+            "loop",
+            start,
+            now - start,
+            {"step": step, "gradient_steps": self._gradient_steps() - steps_before},
+            ctx=ctx,
+        )
 
     # ------------------------------------------------------------ counters
     def counters(self) -> Dict[str, float]:

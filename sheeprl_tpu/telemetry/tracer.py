@@ -1,12 +1,19 @@
 """Span tracer: bounded ring buffer + Chrome-trace / JSONL exporters.
 
 The trace model is deliberately tiny — a span is (name, category, start,
-duration, args) — because everything downstream is a projection of it:
+duration, args, thread) — because everything downstream is a projection of it:
 
 - the Chrome trace-event JSON (``chrome://tracing`` / Perfetto "load legacy
   trace") renders spans as complete ("ph": "X") events on one process
   timeline, one track per category;
 - ``telemetry.jsonl`` gets one line per span for grep/pandas consumption.
+
+Timestamps are ``time.perf_counter()`` seconds, exported relative to the
+tracer's birth; both exports state that birth on both clocks (``perf_epoch_s``,
+``wall_epoch_s``), so anything else stamped on ``perf_counter`` in the process
+(a device profile's clock bridge) can place a span on its own timeline. A live
+tracer's ``span()`` also enters a ``jax.profiler.TraceAnnotation`` of the same
+name, so a device profile taken meanwhile shows the span on the host plane.
 
 The buffer is a ring (``collections.deque`` with ``maxlen``): a week-long
 run records forever and exports the trailing window instead of growing
@@ -47,9 +54,10 @@ _US = 1e6  # seconds -> microseconds (the trace-event timestamp unit)
 
 
 class Span:
-    """One completed region: host wall-clock, perf_counter timebase."""
+    """One completed region: host wall-clock, perf_counter timebase, and the
+    name of the thread that recorded it."""
 
-    __slots__ = ("name", "category", "start_s", "duration_s", "args", "trace_id", "span_id", "parent_id")
+    __slots__ = ("name", "category", "start_s", "duration_s", "args", "trace_id", "span_id", "parent_id", "thread")
 
     def __init__(
         self,
@@ -61,6 +69,7 @@ class Span:
         trace_id: Optional[str] = None,
         span_id: Optional[str] = None,
         parent_id: Optional[str] = None,
+        thread: Optional[str] = None,
     ) -> None:
         self.name = name
         self.category = category
@@ -70,6 +79,7 @@ class Span:
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
+        self.thread = thread
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return f"Span({self.name!r}, cat={self.category!r}, dur={self.duration_s * 1e3:.3f}ms)"
@@ -80,31 +90,58 @@ class _SpanContext:
     instance per ``span()`` call, so nesting the same name is fine.
 
     On entry it derives a child of the active :class:`TraceContext` (when one
-    is installed) and makes it current, so spans opened inside this block
-    parent to this span; the token restores the parent context on exit even
-    when the body raises."""
+    is installed and no ``ctx`` was handed over) and makes it current, so
+    spans opened inside this block parent to this span; the token restores the
+    parent context on exit even when the body raises. It also enters a
+    ``jax.profiler.TraceAnnotation`` of the same name — the one emission site
+    for both clocks — so a device profile taken while the span runs shows it
+    on the host plane beside the device's operations (a few nanoseconds when
+    no profiler session is active)."""
 
-    __slots__ = ("_tracer", "_name", "_category", "_args", "_start", "_ctx", "_token")
+    __slots__ = ("_tracer", "_name", "_category", "_args", "_start", "_ctx", "_token", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, category: str, args: Optional[Dict[str, Any]]):
+    def __init__(
+        self,
+        tracer: "Tracer",
+        name: str,
+        category: str,
+        args: Optional[Dict[str, Any]],
+        ctx: Optional[trace_context.TraceContext] = None,
+    ):
         self._tracer = tracer
         self._name = name
         self._category = category
         self._args = args
         self._start = 0.0
-        self._ctx: Optional[trace_context.TraceContext] = None
+        self._ctx = ctx
         self._token = None
+        self._annotation = None
+
+    def set(self, **args: Any) -> None:
+        """Add args learned inside the block (a cache hit, a byte count)."""
+        if self._args is None:
+            self._args = args
+        else:
+            self._args.update(args)
 
     def __enter__(self) -> "_SpanContext":
-        parent = trace_context.current()
-        if parent is not None:
-            self._ctx = parent.child()
+        if self._ctx is None:
+            parent = trace_context.current()
+            if parent is not None:
+                self._ctx = parent.child()
+        if self._ctx is not None:
             self._token = trace_context.set_current(self._ctx)
+        annotate = self._tracer._annotate
+        if annotate is not None:
+            self._annotation = annotate(self._name)
+            self._annotation.__enter__()
         self._start = time.perf_counter()
         return self
 
     def __exit__(self, *exc_info: Any) -> None:
         duration = time.perf_counter() - self._start
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc_info)
         if self._token is not None:
             trace_context.reset(self._token)
             self._token = None
@@ -122,6 +159,9 @@ class _NoopContext:
         return self
 
     def __exit__(self, *exc_info: Any) -> None:
+        return None
+
+    def set(self, **args: Any) -> None:
         return None
 
 
@@ -143,14 +183,38 @@ class Tracer:
         # real time so the cross-process aggregator can align timelines.
         self._epoch = time.perf_counter()
         self._epoch_wall = time.time()
+        # Resolved once and only by a live tracer: a disabled one (and the
+        # stdlib-only inspectors that import this module) never touches jax.
+        self._annotate: Optional[Callable[[str], Any]] = None
+        if self.enabled:
+            from jax.profiler import TraceAnnotation
+
+            self._annotate = TraceAnnotation
+
+    @property
+    def perf_epoch_s(self) -> float:
+        """The ``time.perf_counter()`` second exported timestamps count from.
+        Anything else stamped on ``perf_counter`` in this process (the
+        benchmark's device-clock bridge) carries a span onto its own clock
+        with it."""
+        return self._epoch
+
+    @property
+    def wall_epoch_s(self) -> float:
+        """The wall-clock twin of :attr:`perf_epoch_s`."""
+        return self._epoch_wall
 
     # ------------------------------------------------------------ recording
-    def span(self, name: str, category: str = "host", **args: Any):
+    def span(
+        self, name: str, category: str = "host", ctx: Optional[trace_context.TraceContext] = None, **args: Any
+    ):
         """Context manager recording one complete span. Cheap no-op when
-        disabled."""
+        disabled. ``ctx`` hands over a trace identity minted earlier (a fetch
+        harvested after its dispatch); by default the span is a child of the
+        caller's active context."""
         if not self.enabled:
             return _NOOP_CTX
-        return _SpanContext(self, name, category, args or None)
+        return _SpanContext(self, name, category, args or None, ctx)
 
     def add_span(
         self,
@@ -183,6 +247,7 @@ class Tracer:
             trace_id=ctx.trace_id if ctx is not None else None,
             span_id=ctx.span_id if ctx is not None else None,
             parent_id=ctx.parent_id if ctx is not None else None,
+            thread=threading.current_thread().name,
         )
         with self._lock:
             if len(self._spans) == self._spans.maxlen:
@@ -262,6 +327,7 @@ class Tracer:
                 "tid": tid,
             }
             args = dict(s.args) if s.args else {}
+            args["thread"] = s.thread
             if s.trace_id is not None:
                 args["trace_id"] = s.trace_id
                 args["span_id"] = s.span_id
@@ -298,7 +364,7 @@ class Tracer:
         return {
             "traceEvents": events,
             "displayTimeUnit": "ms",
-            "metadata": {"pid": pid, "wall_epoch_s": self._epoch_wall},
+            "metadata": {"pid": pid, "wall_epoch_s": self._epoch_wall, "perf_epoch_s": self._epoch},
         }
 
     def export_chrome(self, path: str) -> str:
@@ -316,6 +382,7 @@ class Tracer:
                 "cat": s.category,
                 "ts_us": round(self._ts_us(s.start_s), 3),
                 "dur_us": round(s.duration_s * _US, 3),
+                "thread": s.thread,
             }
             if s.trace_id is not None:
                 rec["trace_id"] = s.trace_id

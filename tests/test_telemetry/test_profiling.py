@@ -41,3 +41,16 @@ def test_window_traces_the_configured_steps(tmp_path):
     w.close()
     # The xplane trace directory was created by the start.
     assert os.path.isdir(trace_dir)
+
+
+def test_window_records_annotations_and_not_python_calls(tmp_path, monkeypatch):
+    """Host tracer at level 1 (the program's spans enter TraceAnnotations),
+    Python tracer off."""
+    seen = {}
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda path, profiler_options=None: seen.update(
+        path=path, host=profiler_options.host_tracer_level, python=profiler_options.python_tracer_level))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    w = ProfilerWindow(trace_dir=str(tmp_path / "xla_trace"), start_step=0, stop_step=2)
+    w.advance(0)
+    w.close()
+    assert seen == {"path": str(tmp_path / "xla_trace"), "host": 1, "python": 0}

@@ -122,9 +122,22 @@ def test_dreamer_v3_smoke_writes_telemetry(tmp_path):
     _check_exports(str(tmp_path))
     # The Dreamer loop also exercises the replay/transfer spans.
     trace_path, _ = _find_exports(str(tmp_path))
-    names = {e["name"] for e in json.load(open(trace_path))["traceEvents"]}
+    events = [e for e in json.load(open(trace_path))["traceEvents"] if e["ph"] == "X"]
+    names = {e["name"] for e in events}
     assert "replay/sample" in names
     assert "fetch/player_actions" in names
+    assert {"replay/add", "interaction/dispatch/slice0"} <= names
+    # loop/iteration spans tile the loop: each span the loop thread emitted
+    # while the loop ran lies inside exactly one of them.
+    iterations = [e for e in events if e["name"] == "loop/iteration"]
+    assert iterations and all(e["dur"] > 0 and "gradient_steps" in e["args"] for e in iterations)
+    first, last = min(e["ts"] for e in iterations), max(e["ts"] + e["dur"] for e in iterations)
+    loop_thread = iterations[0]["args"]["thread"]
+    inside = [e for e in events if e["name"] != "loop/iteration" and e["args"]["thread"] == loop_thread
+              and first <= e["ts"] and e["ts"] + e["dur"] <= last]
+    assert {"fetch/player_actions", "train/dispatch", "replay/add"} <= {e["name"] for e in inside}
+    for child in inside:
+        assert sum(i["ts"] <= child["ts"] and child["ts"] + child["dur"] <= i["ts"] + i["dur"] for i in iterations) == 1
 
 
 def test_from_config_maps_the_telemetry_group():
