@@ -458,15 +458,19 @@ class WorldModel(nn.Module):
         embedded_obs: jax.Array,
         is_first: jax.Array,
         key: jax.Array,
+        initial_states: Optional[Tuple[jax.Array, jax.Array]] = None,
     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
         """One step of dynamic learning (reference: agent.py:396-435):
         is_first reset-mix (zeroed action, learned initial h/z), GRU step,
         prior from transition, posterior from representation.
         All states are FLAT; batch leading dim only (the time loop is the
-        caller's lax.scan)."""
+        caller's lax.scan, which passes `initial_states` so that
+        `get_initial_states` runs once per sequence, not once per step)."""
         k1, k2 = jax.random.split(key)
         action = (1 - is_first) * action
-        h0, z0 = self.get_initial_states(recurrent_state.shape[:-1])
+        if initial_states is None:
+            initial_states = self.get_initial_states(recurrent_state.shape[:-1])
+        h0, z0 = initial_states
         recurrent_state = (1 - is_first) * recurrent_state + is_first * h0
         posterior = (1 - is_first) * posterior + is_first * z0
         recurrent_state = self.recurrent_model(
@@ -493,12 +497,15 @@ class WorldModel(nn.Module):
         action: jax.Array,
         is_first: jax.Array,
         key: jax.Array,
+        initial_states: Optional[Tuple[jax.Array, jax.Array]] = None,
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """One decoupled dynamic step (reference: DecoupledRSSM.dynamic,
         agent.py:542-581): the posterior arrives precomputed (obs-only), so
         only the recurrent state and the prior are produced here."""
         action = (1 - is_first) * action
-        h0, z0 = self.get_initial_states(recurrent_state.shape[:-1])
+        if initial_states is None:
+            initial_states = self.get_initial_states(recurrent_state.shape[:-1])
+        h0, z0 = initial_states
         recurrent_state = (1 - is_first) * recurrent_state + is_first * h0
         posterior = (1 - is_first) * posterior + is_first * z0
         recurrent_state = self.recurrent_model(

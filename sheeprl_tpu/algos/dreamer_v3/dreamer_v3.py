@@ -3,7 +3,13 @@
 TPU-first structure (SURVEY §3.3 / §7.2):
 - Dynamic learning: the RSSM runs as ONE `lax.scan` over the sequence axis
   (the reference python-loops per-step GRU cells, dreamer_v3.py:134-145) —
-  carry = (h, z), stacked outputs (h_t, z_t, logits).
+  carry = (h, z), stacked outputs (h_t, z_t, logits). Its backward pass is
+  its own (models/deferred_wgrad.py): the backward scan carries the
+  cotangents of (h, z) and of the small leaves only, stacks every Dense's
+  output cotangent over time, and each Dense kernel's gradient is one
+  contraction over time and batch after the scan — not a kernel-sized
+  float32 carry read and written T times. The learned initial state and its
+  prior mode are computed once per sequence, outside the scan.
 - Behaviour learning: imagination is a second `lax.scan` over the horizon
   starting from every (t, b) posterior flattened to one batch, with per-step
   PRNG keys for actor sampling.
@@ -49,8 +55,10 @@ from sheeprl_tpu.data.infeed import ReplayInfeed
 from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
 from sheeprl_tpu.data.device_buffer import DeviceReplayRing
 from sheeprl_tpu.core.runtime import DispatchThrottle
+from sheeprl_tpu.models.deferred_wgrad import scan_deferred_wgrad
 from sheeprl_tpu.registry import register_algorithm
 from sheeprl_tpu.telemetry import scopes
+from sheeprl_tpu.telemetry import tracer as tracer_mod
 from sheeprl_tpu.telemetry.health import health_probe, probes_enabled
 from sheeprl_tpu.utils.checkpoint import load_checkpoint, restore_opt_state, save_checkpoint
 from sheeprl_tpu.utils.distribution import (
@@ -76,6 +84,15 @@ def _make_optimizer(optim_cfg: Dict[str, Any], clip: float) -> optax.GradientTra
     if clip is not None and clip > 0:
         return optax.chain(optax.clip_by_global_norm(clip), inner)
     return inner
+
+
+def _report_deferred_wgrad(n_kernels: int, float32_bytes: int) -> None:
+    """Gauges of what the dynamics scan leaves to the contractions after its
+    backward pass (models/deferred_wgrad.py). A fact of the trace, stated
+    once per trace: no per-step counter exists to read."""
+    tracer = tracer_mod.current()
+    tracer.set_gauge("train/deferred_wgrad_leaves", float(n_kernels))
+    tracer.set_gauge("train/deferred_wgrad_bytes", float(float32_bytes))
 
 
 def partition_specs(mesh) -> mesh_lib.PartitionPlan:
@@ -104,35 +121,18 @@ def _explicit_shardings(plan, state, opt_states, data_sharding):
     )
 
 
-def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation], cfg: Dict[str, Any], mesh):
-    """Build the PURE single-gradient-step function over a [T, B] batch.
-
-    Not jitted and no internal key split: :func:`make_train_step` wraps it
-    into the classic one-dispatch-per-step jit, and
-    :func:`make_fused_train_step` scans it over K on-device-sampled batches
-    inside one jitted call. Both share this trace so they optimise the same
-    math."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
+def make_world_loss_fn(agent: DV3Agent, cfg: Dict[str, Any]):
+    """Build ``world_loss_fn(wm_params, data, batch_obs, keys) -> (loss, aux)``:
+    the world model's loss over a [T, B] batch (encoder, the dynamics-learning
+    scan, heads and KL), the function :func:`make_step_core` differentiates."""
     wm_cfg = cfg.algo.world_model
-    cnn_keys = list(cfg.algo.cnn_keys.encoder)
-    mlp_keys = list(cfg.algo.mlp_keys.encoder)
     cnn_dec_keys = list(cfg.algo.cnn_keys.decoder)
     mlp_dec_keys = list(cfg.algo.mlp_keys.decoder)
     stochastic_size = int(wm_cfg.stochastic_size)
     discrete_size = int(wm_cfg.discrete_size)
     stoch_state_size = stochastic_size * discrete_size
     recurrent_state_size = int(wm_cfg.recurrent_model.recurrent_state_size)
-    horizon = int(cfg.algo.horizon)
-    gamma = float(cfg.algo.gamma)
-    lmbda = float(cfg.algo.lmbda)
-    ent_coef = float(cfg.algo.actor.ent_coef)
-    moments_cfg = cfg.algo.actor.moments
     decoupled = bool(wm_cfg.decoupled_rssm)
-    spec = agent.actor_spec
-    actions_dim = agent.actions_dim
-
-    batch_sharding = NamedSharding(mesh, P(None, DATA_AXIS))
 
     def world_loss_fn(wm_params, data, batch_obs, keys):
         T, B = data["rewards"].shape[:2]
@@ -148,6 +148,9 @@ def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation]
             h0 = jnp.zeros((B, recurrent_state_size), embedded.dtype)
             z0 = jnp.zeros((B, stoch_state_size), embedded.dtype)
             step_keys, post_key = keys[:T], keys[T]
+            # The learned initial state and its prior mode do not depend on the
+            # carry: computed once here, not in every step of the scan.
+            initial_states = agent.world_model.apply(wm_params, (B,), method=WorldModel.get_initial_states)
 
             if decoupled:
                 # Decoupled RSSM (reference: dreamer_v3.py:115-130): posteriors are
@@ -159,28 +162,48 @@ def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation]
                 )
                 prev_posteriors = jnp.concatenate([jnp.zeros_like(posteriors[:1]), posteriors[:-1]], 0)
 
-                def dstep(h, x):
+                def dstep(variables, h, x):
+                    params, initial_states = variables
                     z_prev, action, first, key = x
                     h, _, prior_logits = agent.world_model.apply(
-                        wm_params, z_prev, h, action, first, key, method=WorldModel.dynamic_decoupled
+                        params, z_prev, h, action, first, key, initial_states, method=WorldModel.dynamic_decoupled
                     )
                     return h, (h, prior_logits)
 
-                _, (recurrent_states, priors_logits) = jax.lax.scan(
-                    dstep, h0, (prev_posteriors, batch_actions, is_first, step_keys)
+                _, (recurrent_states, priors_logits) = scan_deferred_wgrad(
+                    dstep,
+                    (wm_params, initial_states),
+                    h0,
+                    (prev_posteriors, batch_actions, is_first, step_keys),
+                    given={"0/params/transition_model/dense_0/kernel": lambda ys, xs: ys[0]},
+                    report=_report_deferred_wgrad,
                 )
             else:
 
-                def step(carry, x):
+                def step(variables, carry, x):
+                    params, initial_states = variables
                     h, z = carry
                     action, emb, first, key = x
                     h, post, prior, post_logits, prior_logits = agent.world_model.apply(
-                        wm_params, z, h, action, emb, first, key, method=WorldModel.dynamic
+                        params, z, h, action, emb, first, key, initial_states, method=WorldModel.dynamic
                     )
                     return (h, post), (h, post, post_logits, prior_logits)
 
-                (_, _), (recurrent_states, posteriors, posteriors_logits, priors_logits) = jax.lax.scan(
-                    step, (h0, z0), (batch_actions, embedded, is_first, step_keys)
+                (_, _), (recurrent_states, posteriors, posteriors_logits, priors_logits) = scan_deferred_wgrad(
+                    step,
+                    (wm_params, initial_states),
+                    (h0, z0),
+                    (batch_actions, embedded, is_first, step_keys),
+                    # The transition model reads h_t and the representation model
+                    # [h_t | embedded_t]: the scan returns the one and is given the
+                    # other, so neither is stacked a second time.
+                    given={
+                        "0/params/transition_model/dense_0/kernel": lambda ys, xs: ys[0],
+                        "0/params/representation_model/dense_0/kernel": lambda ys, xs: jnp.concatenate(
+                            [ys[0], xs[1]], -1
+                        ),
+                    },
+                    report=_report_deferred_wgrad,
                 )
         with scopes.scope(scopes.DV3_HEADS):
             latent_states = jnp.concatenate([posteriors, recurrent_states], -1)
@@ -231,6 +254,35 @@ def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation]
             "continue_loss": continue_loss,
         }
         return rec_loss, aux
+
+    return world_loss_fn
+
+
+def make_step_core(agent: DV3Agent, txs: Dict[str, optax.GradientTransformation], cfg: Dict[str, Any], mesh):
+    """Build the PURE single-gradient-step function over a [T, B] batch.
+
+    Not jitted and no internal key split: :func:`make_train_step` wraps it
+    into the classic one-dispatch-per-step jit, and
+    :func:`make_fused_train_step` scans it over K on-device-sampled batches
+    inside one jitted call. Both share this trace so they optimise the same
+    math."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    wm_cfg = cfg.algo.world_model
+    cnn_keys = list(cfg.algo.cnn_keys.encoder)
+    mlp_keys = list(cfg.algo.mlp_keys.encoder)
+    stoch_state_size = int(wm_cfg.stochastic_size) * int(wm_cfg.discrete_size)
+    recurrent_state_size = int(wm_cfg.recurrent_model.recurrent_state_size)
+    horizon = int(cfg.algo.horizon)
+    gamma = float(cfg.algo.gamma)
+    lmbda = float(cfg.algo.lmbda)
+    ent_coef = float(cfg.algo.actor.ent_coef)
+    moments_cfg = cfg.algo.actor.moments
+    spec = agent.actor_spec
+    actions_dim = agent.actions_dim
+
+    batch_sharding = NamedSharding(mesh, P(None, DATA_AXIS))
+    world_loss_fn = make_world_loss_fn(agent, cfg)
 
     def step_core(state, opt_states, moments_state, data, key, tau):
         T, B = data["rewards"].shape[:2]
