@@ -34,22 +34,6 @@ if ROOT not in sys.path:
 BELOW = {"float32": "bf16", "bfloat16": "fp8"}
 
 
-def half_of_the_batch(model):
-    T, B = model["sequence"], model["batch"]
-
-    def mutate(batch, noise):
-        half = B // 2
-        batch = {k: v[:, :half] for k, v in batch.items()}
-
-        def rows(x):  # [H, T*B, ...] -> the rows of the kept batch columns
-            return x.reshape((x.shape[0], T, B) + x.shape[2:])[:, :, :half].reshape((x.shape[0], T * half) + x.shape[2:])
-
-        noise = {"post": noise["post"][:, :half], "img_prior": rows(noise["img_prior"]), "actor": rows(noise["actor"])}
-        return batch, noise
-
-    return mutate
-
-
 def sides(cell, run):
     """(name, what stands in the program's place) for the control and the faults."""
     from benchmarks.harness import compare
@@ -58,13 +42,14 @@ def sides(cell, run):
     captured, seed = run["record"].captured, run["record"].seed
     below = BELOW[config["model"]["compute_dtype"]]
     control = compare.reference_run(config, captured, seed, precision=below)
-    control["player_h"] = compare.acting_steps(config, reference["initial"], run["acted"], below)
+    control["acting"] = compare.acting_steps(config, reference["initial"], run["acted"], below)
     yield "control_" + below, control
-    fault = compare.reference_run(config, captured, seed, mutate=half_of_the_batch(config["model"]))
-    fault["player_h"] = reference["player_h"]  # the fault is in the train step: the player acts as it did
+    half = compare.load_adapter(config).half_of_the_batch(config["model"])
+    fault = compare.reference_run(config, captured, seed, mutate=half)
+    fault["acting"] = reference["acting"]  # the fault is in the train step: the player acts as it did
     yield "half_batch", fault
     fault = compare.reference_run(config, captured, seed, frozen=True)
-    fault["player_h"] = reference["player_h"]
+    fault["acting"] = reference["acting"]
     yield "state_unchanged", fault
 
 
@@ -88,8 +73,9 @@ def main(argv=None) -> int:
            "reference": {"losses": run["reference"]["losses"]}}
     steps_owed = run["compared"]["ratio_steps"]["value"]
     say("numbers program: " + json.dumps(out["program"]["numbers"]))
+    adapter = compare.load_adapter(cell.config)
     for name, other in sides(cell, run):
-        values = compare.numbers(other, run["reference"])
+        values = compare.numbers(adapter, other, run["reference"])
         values["ratio_steps"] = steps_owed
         correct, shown = compare.judge(values, cell.limits)
         out[name] = {"correct": correct, "numbers": values, "losses": other["losses"],

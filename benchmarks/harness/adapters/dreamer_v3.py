@@ -5,6 +5,10 @@ From the program it takes only the system itself: the normal entry point
 (`PreemptionGuard.advance`), its jitted train steps (`make_train_step`,
 `make_fused_train_step`), its agent builder (whose weights are replaced with
 the benchmark's) and its replay ring. Nothing in the program is edited.
+
+What the harness asks of an adapter is listed in `benchmarks/README.md`
+("The adapter's protocol"); everything that names this family is here, in
+`reference/dreamer_v3.py`, `flops/dreamer_v3.py` and the configuration's file.
 """
 
 from __future__ import annotations
@@ -19,6 +23,17 @@ import numpy as np
 from benchmarks.harness import weights as weights_mod
 
 # --------------------------------------------------------------- names
+#: The parameter groups the comparison reads, {number's suffix: leaf prefix in
+#: the reference's naming}: one optimizer each.
+GROUPS = {"world_model": "wm/", "actor": "actor/", "critic": "critic/"}
+#: The number that `asked_again` gives, and the one that `acting_reference` gives.
+MOVED = "moved.world_model"
+ACTING = "player.recurrent"
+#: {loss.<name>: the program's own name of that loss}
+LOSSES = {"world_model": "Loss/world_model_loss", "policy": "Loss/policy_loss", "value": "Loss/value_loss",
+          "observation": "Loss/observation_loss", "reward": "Loss/reward_loss", "continue": "Loss/continue_loss",
+          "state": "Loss/state_loss", "kl": "State/kl"}
+
 _RENAMES: List[Tuple[str, str]] = [
     (r"^world_model/params/", "wm/"),
     (r"^(actor|critic|target_critic)/params/", r"\1/"),
@@ -99,6 +114,55 @@ def _fmt(value: Any) -> str:
     if isinstance(value, (list, tuple)):
         return "[" + ",".join(_fmt(v) for v in value) + "]"
     return str(value)
+
+
+# --------------------------------------------------------------- the traffic and the recipe
+def warm_policy_steps(traffic: Dict[str, Any]) -> int:
+    """The policy step from which the window may open: the prefill, then as
+    many steps again as the shorter of the env's two fixed first episodes, by
+    when both have ended and their reset programs are warm."""
+    prefill, lengths = int(traffic["overrides"]["algo.learning_starts"]), traffic["env"]["warm_lengths"]
+    if sum(lengths) >= prefill + min(lengths):
+        raise SystemExit("benchmark: the traffic's warm_lengths do not end inside set-up")
+    return prefill + min(lengths)
+
+
+def gradient_steps_owed(traffic: Dict[str, Any], policy_steps: int) -> float:
+    """Gradient steps a window of ``policy_steps`` owes: the recipe's replay ratio times them."""
+    return float(traffic["overrides"]["algo.replay_ratio"]) * policy_steps
+
+
+def recipe_sizes(cfg: Any) -> Dict[str, Any]:
+    """The sizes of the composed recipe ``cfg`` under the keys of the
+    configuration file's ``model`` (a list: the keys of the file's dict)."""
+    wm = cfg.algo.world_model
+
+    def one(*values):  # what the recipe states in several places, once
+        if len(set(values)) != 1:
+            raise ValueError(f"the recipe disagrees with itself: {values}")
+        return values[0]
+
+    return {
+        "recurrent": wm.recurrent_model.recurrent_state_size,
+        "dense": cfg.algo.dense_units,
+        "mlp_layers": cfg.algo.mlp_layers,
+        "hidden": one(wm.transition_model.hidden_size, wm.representation_model.hidden_size),
+        "cnn_mult": wm.encoder.cnn_channels_multiplier,
+        "stoch": wm.stochastic_size,
+        "discrete": wm.discrete_size,
+        "batch": cfg.algo.per_rank_batch_size,
+        "sequence": cfg.algo.per_rank_sequence_length,
+        "horizon": cfg.algo.horizon,
+        "bins": one(wm.reward_model.bins, cfg.algo.critic.bins),
+        "gamma": float(cfg.algo.gamma),
+        "lmbda": cfg.algo.lmbda,
+        "compute_dtype": {"bf16-mixed": "bfloat16", "32-true": "float32"}[str(cfg.fabric.precision)],
+        "optim": {
+            name: {"lr": opt.optimizer.lr, "eps": opt.optimizer.eps, "clip": opt.clip_gradients}
+            for name, opt in (("world_model", cfg.algo.world_model), ("actor", cfg.algo.actor), ("critic", cfg.algo.critic))
+        },
+        "mlp_keys": sorted(cfg.algo.mlp_keys.encoder),
+    }
 
 
 # --------------------------------------------------------------- the step's noise
@@ -232,9 +296,9 @@ def first_moments(opt_states: Any) -> Dict[str, Any]:
 class Record:
     """What the harness learns about one run of the program, from outside."""
 
-    def __init__(self, seed: int, ring_expected: bool) -> None:
+    def __init__(self, seed: int, traffic: Dict[str, Any]) -> None:
         self.seed = seed
-        self.ring_expected = ring_expected
+        self.ring_expected = bool(traffic.get("ring", False))
         self.calls = 0
         self.steps = 0
         self.fused_calls = 0
@@ -318,7 +382,9 @@ class Record:
             for before, after in jax.device_get(self.player_steps)
         ]
 
-    def ring_fell_back(self) -> Optional[str]:
+    def fell_back(self) -> Optional[str]:
+        """Why the run did not take the path its traffic asks for (the device
+        ring), or None: the runner fails the run on anything said here."""
         if not self.ring_expected:
             return None
         if not self.rings:
@@ -433,6 +499,55 @@ def run_program(args: List[str]) -> None:
 
 
 # --------------------------------------------------------------- the comparison
+def reference_initial(weights: Any) -> Any:
+    """The benchmark's weights as the program starts from them: the target critic begins as the critic."""
+    weights["target_critic"] = weights["critic"]
+    return weights
+
+
+def reference_step(ref: Any, state: Any, batch: Dict[str, Any], noise: Dict[str, Any], captured: Dict[str, Any]):
+    """One step of the reference on what one captured step of the program was given."""
+    return ref.step(state, batch, noise, captured["tau"])
+
+
+def asked_again(ref: Any, state: Any, batch: Dict[str, Any], noise: Dict[str, Any], captured: Dict[str, Any]):
+    """The world model's first gradient on ``batch`` (the first batch with one
+    column altered), for `MOVED`; None where the program's step samples its
+    own batch and cannot be asked again."""
+    return None if captured["fused"] else ref.world_model_gradient(state, batch, noise)
+
+
+def acting_reference(ref: Any, params: Dict[str, Any], acted: List[Dict[str, Any]]) -> List[np.ndarray]:
+    """For `ACTING`: the recurrent state of each kept acting step, from the
+    reference's equations on the program's previous state and the benchmark's
+    weights (``params``: the reference run's ``initial``)."""
+    import jax.numpy as jnp
+
+    params = {k: jnp.asarray(v) for k, v in params.items() if k.startswith(GROUPS["world_model"])}
+    return [
+        np.asarray(ref.player_recurrent(params, *(jnp.asarray(step[k], jnp.float32) for k in ("z", "a", "h"))))
+        for step in acted
+    ]
+
+
+def half_of_the_batch(model: Dict[str, Any]) -> Callable:
+    """`calibrate.py`'s fault: ``mutate(batch, noise)`` that leaves half of the
+    batch's columns out, with the noise of the kept rows."""
+    T, B = model["sequence"], model["batch"]
+
+    def mutate(batch, noise):
+        half = B // 2
+        batch = {k: v[:, :half] for k, v in batch.items()}
+
+        def rows(x):  # [H, T*B, ...] -> the rows of the kept batch columns
+            return x.reshape((x.shape[0], T, B) + x.shape[2:])[:, :, :half].reshape((x.shape[0], T * half) + x.shape[2:])
+
+        noise = {"post": noise["post"][:, :half], "img_prior": rows(noise["img_prior"]), "actor": rows(noise["actor"])}
+        return batch, noise
+
+    return mutate
+
+
 def reference_inputs(config: Dict[str, Any], captured: Dict[str, Any]) -> Tuple[Dict[str, Any], Dict[str, Any]]:
     """(batch, noise) of one captured step, for the reference."""
     import jax.numpy as jnp
@@ -455,15 +570,12 @@ def program_numbers(
     import jax
 
     losses = jax.device_get([c["losses"] for c in captured])
-    names = {"world_model": "Loss/world_model_loss", "policy": "Loss/policy_loss", "value": "Loss/value_loss",
-             "observation": "Loss/observation_loss", "reward": "Loss/reward_loss", "continue": "Loss/continue_loss",
-             "state": "Loss/state_loss", "kl": "State/kl"}
     mu = captured[0]["mu"]
-    first = to_reference({"world_model": mu["world_model"], "actor": mu["actor"], "critic": mu["critic"]})
+    first = to_reference({name: mu[name] for name in GROUPS})
     return {
-        "losses": [{k: float(np.asarray(step[v]).reshape(-1)[0]) for k, v in names.items()} for step in losses],
+        "losses": [{k: float(np.asarray(step[v]).reshape(-1)[0]) for k, v in LOSSES.items()} for step in losses],
         "first_grads": {k: np.asarray(v) / 0.1 for k, v in first.items()},  # mu_1 = (1 - b1) g_1, b1 = 0.9
         "params": {k: np.asarray(v) for k, v in to_reference(captured[-1]["params"]).items()},
-        "player_h": [step["h_new"] for step in acted or []],
+        "acting": [step["h_new"] for step in acted or []],
         "moved": moved,
     }

@@ -7,7 +7,7 @@ The window drives the program's normal training entry point in this process.
 The harness observes iteration boundaries from outside. Set-up ends, and the
 window begins, at the first boundary at which the program has made its first
 gradient steps (first call, donated-layout recompile, one steady) and the
-traffic's warm-up has passed; both edges wait for the device (everything the
+traffic's warm-up has passed (the adapter's `warm_policy_steps`); both edges wait for the device (everything the
 program has enqueued) before the clock is read. The run is ended the way a
 preemption ends one: SIGTERM to this process, the loop leaves at its next
 iteration boundary and saves nothing.
@@ -16,7 +16,6 @@ iteration boundary and saves nothing.
 from __future__ import annotations
 
 import contextlib
-import importlib
 import json
 import os
 import shutil
@@ -29,16 +28,6 @@ from typing import Any, Callable, Dict, List, Optional
 #: A traced run measures this much: enough iterations to read every layer,
 #: and a trace the reduction gets through inside the run's time limit.
 TRACE_SECONDS = 5.0
-
-
-def warm_policy_steps(traffic: Dict[str, Any]) -> int:
-    """The policy step from which the window may open: the prefill, then as
-    many steps again as the shorter of the env's two fixed first episodes, by
-    when both have ended and their reset programs are warm."""
-    prefill, lengths = int(traffic["overrides"]["algo.learning_starts"]), traffic["env"]["warm_lengths"]
-    if sum(lengths) >= prefill + min(lengths):
-        raise SystemExit("benchmark: the traffic's warm_lengths do not end inside set-up")
-    return prefill + min(lengths)
 
 
 class Window:
@@ -147,20 +136,20 @@ def run_cell(cell: Any, seed: int, seconds: float, trace: bool, started: float, 
     """Everything between the chip check and the result line."""
     from benchmarks.harness import compare, device, tracing
 
-    adapter = importlib.import_module("benchmarks.harness.adapters." + cell.config["adapter"])
+    adapter = compare.load_adapter(cell.config)
     traffic = cell.traffic
     program_seed = int(seed) % (2**31 - 1)
     shutil.rmtree(run_dir, ignore_errors=True)
     os.makedirs(run_dir, exist_ok=True)
     compiles = CompileCounter()
     compiles.install()
-    record = adapter.Record(program_seed, ring_expected=bool(traffic.get("ring", False)))
+    record = adapter.Record(program_seed, traffic)
     tracer = None
     if trace:
         tracer = tracing.Tracer(os.path.join(run_dir, "xla_trace"), TRACE_SECONDS, record)
         seconds = min(seconds, tracer.seconds)  # a traced run measures the traced window and no more
     # set-up lasts at least through the gradient steps that the comparison follows
-    window = Window(seconds, warm_policy_steps(traffic), adapter.StepProbe.CAPTURED, record, self_sigterm, tracer)
+    window = Window(seconds, adapter.warm_policy_steps(traffic), adapter.StepProbe.CAPTURED, record, self_sigterm, tracer)
     args = adapter.overrides(cell.config, traffic, program_seed, run_dir, trace)
     say(f"program: python -m sheeprl_tpu {' '.join(args)}")
     record.mark("program called")
@@ -169,7 +158,7 @@ def run_cell(cell: Any, seed: int, seconds: float, trace: bool, started: float, 
         adapter.run_program(args)
     if window.phase != "closed":
         raise SystemExit(f"benchmark: the program ended before the window closed (phase {window.phase})")
-    fell_back = record.ring_fell_back()
+    fell_back = record.fell_back() if hasattr(record, "fell_back") else None
     if fell_back:
         raise SystemExit(f"benchmark: {fell_back}")
     for root, _, files in os.walk(run_dir):
@@ -185,7 +174,7 @@ def run_cell(cell: Any, seed: int, seconds: float, trace: bool, started: float, 
     say(
         f"window: {window.elapsed:.3f} s, {len(iters)} iterations, {window.env_steps()} policy steps, "
         f"{window.gradient_steps()} gradient steps, {in_window} compile(s) inside; "
-        f"iteration ms median {statistics.median(iters):.3f} p95 {percentile(iters, 95):.3f} "
+        f"iteration ms median {statistics.median(iters):.3f} p95 {percentile(iters, 95):.3f} max {max(iters):.3f} "
         f"(n={len(iters)}); set-up {setup_s:.2f} s of which {compile_s:.1f} s compiling"
     )
     marks = [*record.marks, ("window open", opened)]
@@ -219,11 +208,10 @@ def run_cell(cell: Any, seed: int, seconds: float, trace: bool, started: float, 
     t_ref = time.perf_counter()
     program = adapter.program_numbers(captured, acted, record.sensitivity())
     reference = compare.reference_run(cell.config, captured, program_seed)
-    reference["player_h"] = compare.acting_steps(cell.config, reference["initial"], acted)
+    reference["acting"] = compare.acting_steps(cell.config, reference["initial"], acted)
     worst: Dict[str, str] = {}
-    values = compare.numbers(program, reference, worst)
-    ratio = float(traffic["overrides"]["algo.replay_ratio"])
-    values["ratio_steps"] = abs(window.gradient_steps() - ratio * window.env_steps())
+    values = compare.numbers(adapter, program, reference, worst)
+    values["ratio_steps"] = abs(window.gradient_steps() - adapter.gradient_steps_owed(traffic, window.env_steps()))
     correct, shown = compare.judge(values, cell.limits)
     say(f"reference: the step asked again and three steps in {time.perf_counter() - t_ref:.1f} s")
     say("leaves read (worst, or median for direction and moved): " + json.dumps(worst))

@@ -5,8 +5,11 @@ way `_spans.py` is, so one run's trace is parsed once for all of them.
 
 **Scopes.** The program wraps the phases of its jitted steps in
 `jax.named_scope` (`sheeprl_tpu/telemetry/scopes.py`), which puts the scope
-into every instruction's `op_name`: `jit(train_step)/jvp(dv3/rssm)/while/body/...`
-forward, `.../transpose(jvp(dv3/rssm))/...` backward. On the TPU every event
+into every instruction's `op_name`: `jit(train_step)/jvp(<scope>)/while/body/...`
+forward, `.../transpose(jvp(<scope>))/...` backward. The phases of a
+configuration's gradient step are the scopes its file lists
+(`program.step_scopes`, `<family>/<phase>` each; a test holds the list to the
+program's own table). On the TPU every event
 of a device plane's "XLA Ops" line names its HLO instruction, and the `op_name`
 is a stat (`tf_op`) of the event's metadata, which `jax.profiler.ProfileData`
 does not show: `op_names` reads the metadata tables from the file's wire format
@@ -36,14 +39,24 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 from benchmarks.harness import tracing
 
-#: The phases of the DreamerV3 gradient step, as the program names them
-#: (`sheeprl_tpu.telemetry.scopes.DV3_STEP`; a test holds the two together).
-STEP_SCOPES = ("dv3/encoder", "dv3/rssm", "dv3/heads", "dv3/imagine", "dv3/actor_critic", "dv3/optim")
 UNSCOPED = "unscoped"
-_SCOPE_RE = re.compile(r"(?:dv3|replay)/[a-z_]+")
+#: Scope families every configuration's programs may pass through beside its
+#: own (the in-jit replay ring's sampler and writer, `replay/*`).
+SHARED_FAMILIES = ("replay",)
 OP_NAME_STAT = "tf_op"  # the stat of an event's metadata that holds its `op_name`
 
-_parsed: Dict[str, Optional[Dict[str, Any]]] = {}  # xplane path -> reduce_scopes(...)
+_parsed: Dict[Tuple[str, Tuple[str, ...]], Optional[Dict[str, Any]]] = {}  # (xplane path, step scopes) -> reduce_scopes(...)
+
+
+def step_scopes(run: Dict[str, Any]) -> Tuple[str, ...]:
+    """The phases of the gradient step, as the configuration's file lists them."""
+    return tuple(run["cell"].config["program"].get("step_scopes", ()))
+
+
+def scope_pattern(scopes: Tuple[str, ...]) -> "re.Pattern[str]":
+    """Matches every scope of the listed scopes' families and of the shared ones."""
+    families = sorted({s.split("/", 1)[0] for s in scopes} | set(SHARED_FAMILIES))
+    return re.compile("(?:" + "|".join(map(re.escape, families)) + ")/[a-z_]+")
 
 
 # ------------------------------------------------------------------ the file's wire format
@@ -148,11 +161,12 @@ def self_times(events: List[Tuple[float, float, Any]]) -> Dict[Any, float]:
     return out
 
 
-def scope_of(op_name: str) -> Tuple[str, str]:
-    """(scope, direction) of one instruction: the innermost scope its
-    `op_name` passes through, backward where a `transpose(` precedes it."""
+def scope_of(op_name: str, pattern: "re.Pattern[str]") -> Tuple[str, str]:
+    """(scope, direction) of one instruction: the innermost scope (a match of
+    ``pattern``, as `scope_pattern` builds it) its `op_name` passes through,
+    backward where a `transpose(` precedes it."""
     found = None
-    for found in _SCOPE_RE.finditer(op_name):
+    for found in pattern.finditer(op_name):
         pass
     if found is None:
         return UNSCOPED, "fwd"
@@ -160,16 +174,17 @@ def scope_of(op_name: str) -> Tuple[str, str]:
 
 
 def reduce_scopes(
-    planes: List[Dict[str, Any]], names: Dict[str, Dict[str, str]], train_modules: List[str]
+    planes: List[Dict[str, Any]], names: Dict[str, Dict[str, str]], train_modules: List[str], scopes: Tuple[str, ...]
 ) -> Optional[Dict[str, Any]]:
     """``planes`` as `tracing.load_planes` gives them, ``names`` as `op_names`
-    does. Per device, inside the marker window: self time by (scope, direction)
+    does, ``scopes`` the configuration's step scopes. Per device, inside the marker window: self time by (scope, direction)
     of the operations that ran inside whole executions of the train modules,
     the number of those executions, and the device's idle gaps; times and
     counts averaged over the devices, the gaps those of the first."""
     devices = [p for p in planes if tracing.is_device_plane(p["name"])]
     by_scope: Dict[Tuple[str, str], float] = {}
     calls, idle = 0.0, None
+    pattern = scope_pattern(scopes)
     for plane in devices:
         lines = {ln["name"]: ln["events"] for ln in plane["lines"]}
         modules = lines.get("XLA Modules", [])
@@ -188,7 +203,7 @@ def reduce_scopes(
             if at < len(runs) and runs[at][0] <= start:
                 inside.append((start, end, name))
         for name, seconds in self_times(inside).items():
-            key = scope_of(table.get(name, ""))
+            key = scope_of(table.get(name, ""), pattern)
             by_scope[key] = by_scope.get(key, 0.0) + seconds / len(devices)
         calls += len(runs) / len(devices)
         if idle is None:
@@ -205,28 +220,41 @@ def scopes_of(run: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     path = tracing.newest_xplane(trace_dir) if run.get("trace") else None
     if path is None:
         return None
-    if path not in _parsed:
+    scopes = step_scopes(run)
+    if (path, scopes) not in _parsed:
         modules = run["cell"].config["program"]["train_modules"]
-        _parsed[path] = reduce_scopes(tracing.load_planes(path), op_names(path), modules)
-    return _parsed[path]
+        _parsed[path, scopes] = reduce_scopes(tracing.load_planes(path), op_names(path), modules, scopes)
+    return _parsed[path, scopes]
+
+
+def _step_times(run: Dict[str, Any]) -> Optional[Tuple[Dict[str, Any], Tuple[str, ...]]]:
+    """`scopes_of` the run with its step scopes; None where the trace names none of them."""
+    got = scopes_of(run)
+    if not got:
+        return None
+    scopes = step_scopes(run)
+    return (got, scopes) if any(s in scopes for s, _ in got["by_scope"]) else None
 
 
 def phase_ms(run: Dict[str, Any], scope: str, direction: Optional[str] = None) -> Optional[float]:
     """Self time under ``scope`` per execution of the train module, in ms;
-    None where the trace names no scope at all."""
-    got = scopes_of(run)
-    if not got or not got["calls"] or not any(s in STEP_SCOPES for s, _ in got["by_scope"]):
+    None where the trace names no scope of the step at all."""
+    found = _step_times(run)
+    if not found or not found[0]["calls"]:
         return None
+    got = found[0]
     seconds = sum(v for (s, d), v in got["by_scope"].items() if s == scope and direction in (None, d))
     return seconds * 1e3 / got["calls"]
 
 
 def unscoped_share(run: Dict[str, Any]) -> Optional[float]:
-    got = scopes_of(run)
-    if not got or not any(s in STEP_SCOPES for s, _ in got["by_scope"]):
+    """Share of the train modules' self time under none of the step's scopes."""
+    found = _step_times(run)
+    if not found:
         return None
+    got, scopes = found
     total = sum(got["by_scope"].values())
-    scoped = sum(v for (s, _), v in got["by_scope"].items() if s in STEP_SCOPES)
+    scoped = sum(v for (s, _), v in got["by_scope"].items() if s in scopes)
     return 100.0 * (total - scoped) / total if total else None
 
 

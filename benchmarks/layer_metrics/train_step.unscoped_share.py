@@ -1,4 +1,4 @@
-"""Share of the train modules' device self time under none of the step's six scopes."""
+"""Share of the train modules' device self time under none of the scopes the configuration lists for its step."""
 import os
 import sys
 

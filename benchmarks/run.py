@@ -29,6 +29,23 @@ def say(line: str) -> None:
     print(line, file=sys.stderr, flush=True)
 
 
+def metrics_of(cell, run, trace: bool, root: str = ROOT) -> dict:
+    """The cell's metrics of one run: end to end from the runner's readings,
+    or per layer, each from its own reader; a reader that finds nothing to
+    read returns None and its metric is left out."""
+    from benchmarks.harness import manifest
+
+    metrics = {}
+    for metric in cell.per_layer() if trace else cell.end_to_end():
+        if trace:
+            value = manifest.load_reader(metric["name"], root)(run)
+        else:
+            value = run["readings"].get(metric["name"])
+        if value is not None:
+            metrics[metric["name"]] = {"value": value, "unit": metric["unit"]}
+    return metrics
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
@@ -47,20 +64,11 @@ def main(argv=None) -> int:
     run_dir = os.path.join(ROOT, "benchmarks", ".runs", cell.name)
     run = runner.run_cell(cell, args.seed, args.seconds, bool(args.trace), STARTED, run_dir, say)
 
-    names = cell.per_layer() if args.trace else cell.end_to_end()
-    metrics = {}
-    for metric in names:
-        if args.trace:
-            value = manifest.load_reader(metric["name"], ROOT)(run)
-        else:
-            value = run["readings"].get(metric["name"])
-        if value is not None:
-            metrics[metric["name"]] = {"value": value, "unit": metric["unit"]}
     result = {
         "correct": bool(run["correct"]),
         "attempted": run["window"].env_steps(),
         "failed": 0,
-        "metrics": metrics,
+        "metrics": metrics_of(cell, run, bool(args.trace)),
         "device": dict(report, memory_peak_bytes=int(run["readings"]["peak_hbm_gib"] * 2**30)),
     }
     if args.trace and run["trace"]:

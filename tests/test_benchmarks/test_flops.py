@@ -1,7 +1,7 @@
-"""`flops.py` against a hand count at micro widths, every scan step counted."""
+"""`flops/dreamer_v3.py` against a hand count at micro widths, every scan step counted."""
 
 import conftest  # noqa: F401
-from benchmarks.harness.flops import step_flops
+from benchmarks.flops.dreamer_v3 import step_flops
 
 MICRO = dict(sequence=2, batch=3, horizon=2, stoch=2, discrete=2, recurrent=4, dense=3, hidden=5, mlp_layers=1,
              actions=[2], cnn_mult=1, cnn_stages=4, screen=64, bins=7, cnn_channels=[3], mlp_keys={})

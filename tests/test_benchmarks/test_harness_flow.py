@@ -135,8 +135,8 @@ def test_a_lower_precision_reference_fails(host_run, precision):
     seed = host_run["record"].seed
     reference = dict(host_run["reference"])
     control = compare.reference_run(cell.config, captured, seed, precision=precision)
-    control["player_h"] = compare.acting_steps(cell.config, reference["initial"], host_run["acted"], precision)
-    values = compare.numbers(control, reference)
+    control["acting"] = compare.acting_steps(cell.config, reference["initial"], host_run["acted"], precision)
+    values = compare.numbers(compare.load_adapter(cell.config), control, reference)
     correct, _ = compare.judge(values, MICRO_LIMITS)
     assert correct is False
     assert max(values.values()) > 10 * max(host_run["compared"][k]["value"] for k in LOSSES_AND_NORMS)
@@ -187,7 +187,7 @@ def test_calibrate_judges_the_control_and_the_faults(host_run):
     cell = micro_cell("dreamer_v3_XL", "crafter_host")
     verdicts = {}
     for name, other in calibrate.sides(cell, host_run):
-        values = compare.numbers(other, host_run["reference"])
+        values = compare.numbers(compare.load_adapter(cell.config), other, host_run["reference"])
         verdicts[name] = compare.judge(values, {k: v for k, v in cell.limits.items() if k != "ratio_steps"})
     assert set(verdicts) == {"control_bf16", "half_batch", "state_unchanged"}
     assert not any(correct for correct, _ in verdicts.values())

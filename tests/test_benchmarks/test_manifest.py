@@ -41,14 +41,17 @@ def test_cell_files(name):
     assert {"model", "program", "adapter", "reference", "reduced", "assumed", "source"} <= set(cell.config)
     assert "setup_s" in {m["name"] for m in cell.end_to_end()}
     assert len(cell.end_to_end()) >= 2 and len(cell.per_layer()) >= 1
-    # every limit of the cell is a number the comparison produces
+    # every limit of the cell is a number the comparison produces, by the names the adapter gives them
     from benchmarks.harness import compare
 
-    known = {f"{k}.{o}" for k in ("grad", "change", "direction") for o in compare.OPTIMIZERS}
-    known |= {"ratio_steps", "player.recurrent", "moved.world_model"} | {
-        f"loss.{name}" for name in ("world_model", "policy", "value", "observation", "reward", "continue", "state", "kl")
-    }
+    adapter = compare.load_adapter(cell.config)
+    known = {f"{k}.{group}" for k in ("grad", "change", "direction") for group in adapter.GROUPS}
+    known |= {"ratio_steps"} | {f"loss.{name}" for name in adapter.LOSSES}
+    known |= {getattr(adapter, name) for name in ("MOVED", "ACTING") if hasattr(adapter, name)}
     assert set(cell.limits) <= known
+    # the files the configuration names are there
+    for kind in ("reference", "flops"):
+        assert os.path.exists(os.path.join(ROOT, "benchmarks", kind, cell.config[kind] + ".py"))
     assert len(cell.entry["why"]) <= 200
 
 
@@ -64,25 +67,23 @@ def test_reader_exists(name):
 
 @pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
 def test_configuration_is_the_recipe(config):
-    """The sizes in the configuration's file are the composed recipe's own."""
+    """The sizes in the configuration's file are the composed recipe's own, as
+    the configuration's adapter reads them from the recipe (`recipe_sizes`)."""
     import sheeprl_tpu
+    from benchmarks.harness import compare
     from sheeprl_tpu.config.loader import compose
 
     entry = next(c for c in BENCH["configs"] if c["name"] == config)
     body = manifest.load_json(os.path.join(ROOT, entry["file"]))
     sheeprl_tpu.register_all()
     cfg = compose("config", [f"exp={body['program']['exp']}", "env=dummy"])
-    m, wm = body["model"], cfg.algo.world_model
-    assert m["recurrent"] == wm.recurrent_model.recurrent_state_size
-    assert m["dense"] == cfg.algo.dense_units and m["mlp_layers"] == cfg.algo.mlp_layers
-    assert m["hidden"] == wm.transition_model.hidden_size == wm.representation_model.hidden_size
-    assert m["cnn_mult"] == wm.encoder.cnn_channels_multiplier
-    assert (m["stoch"], m["discrete"]) == (wm.stochastic_size, wm.discrete_size)
-    assert (m["batch"], m["sequence"], m["horizon"]) == (
-        cfg.algo.per_rank_batch_size, cfg.algo.per_rank_sequence_length, cfg.algo.horizon)
-    assert m["bins"] == wm.reward_model.bins == cfg.algo.critic.bins
-    assert abs(m["gamma"] - cfg.algo.gamma) < 1e-12 and m["lmbda"] == cfg.algo.lmbda
-    assert str(cfg.fabric.precision) == "bf16-mixed" and m["compute_dtype"] == "bfloat16"
-    for name, opt in (("world_model", cfg.algo.world_model), ("actor", cfg.algo.actor), ("critic", cfg.algo.critic)):
-        assert m["optim"][name] == {"lr": opt.optimizer.lr, "eps": opt.optimizer.eps, "clip": opt.clip_gradients}
-    assert sorted(m["mlp_keys"]) == sorted(cfg.algo.mlp_keys.encoder)
+    sizes = compare.load_adapter(body).recipe_sizes(cfg)
+    assert sizes, "the adapter compares no size"
+    for key, recipe in sizes.items():
+        stated = body["model"][key]
+        if isinstance(recipe, list):  # names only: the file's dict gives each a width the recipe does not state
+            assert sorted(stated) == recipe, key
+        elif isinstance(recipe, float):
+            assert abs(stated - recipe) < 1e-12, key
+        else:
+            assert stated == recipe, key

@@ -19,6 +19,8 @@ import _scopes  # noqa: E402
 import make_scoped_xplane as made  # noqa: E402
 
 US = 1e-6
+CONFIG = manifest.load_json(os.path.join(ROOT, "benchmarks", "configs", "dreamer_v3_XL.json"))
+SCOPES = tuple(CONFIG["program"]["step_scopes"])
 
 
 @pytest.fixture(scope="module")
@@ -31,7 +33,7 @@ def run(tmp_path_factory):
     reduced = tracing.reduce_planes(tracing.load_planes(str(where / "host.xplane.pb")))
     tracer = types.SimpleNamespace(first_marker_done=made.HOST_CLOCK_AT_FIRST_MARKER)
     return {
-        "cell": types.SimpleNamespace(config={"program": {"train_modules": ["train_step"]}}),
+        "cell": types.SimpleNamespace(config={"program": {"train_modules": ["train_step"], "step_scopes": list(SCOPES)}}),
         "run_dir": str(run_dir),
         "trace": dict(reduced, gradient_steps=2),
         "window": types.SimpleNamespace(tracer=tracer, gradient_steps=lambda: 2),
@@ -62,7 +64,7 @@ def test_the_wire_format_gives_each_operation_its_op_name():
     ("", ("unscoped", "fwd")),
 ])
 def test_scope_and_direction_of_an_op_name(op_name, scope):
-    assert _scopes.scope_of(op_name) == scope
+    assert _scopes.scope_of(op_name, _scopes.scope_pattern(SCOPES)) == scope
 
 
 def test_self_time_goes_to_the_innermost_operation():
@@ -129,13 +131,14 @@ def test_a_run_of_a_program_without_scopes_or_clock_reads_nothing(run):
                    "replay.infeed_hit_share"):
         assert read(metric, bare) is None
     planes = tracing.load_planes(tracing.newest_xplane(os.path.join(run["run_dir"], "xla_trace")))
-    got = _scopes.reduce_scopes(planes, {}, ["no_such_module"])
+    got = _scopes.reduce_scopes(planes, {}, ["no_such_module"], SCOPES)
     assert got["calls"] == 0 and got["by_scope"] == {}
-    unscoped = _scopes.reduce_scopes(planes, {"/device:TPU:0": {}}, ["train_step"])
+    unscoped = _scopes.reduce_scopes(planes, {"/device:TPU:0": {}}, ["train_step"], SCOPES)
     assert set(unscoped["by_scope"]) == {("unscoped", "fwd")}
 
 
 def test_the_readers_scopes_are_the_programs():
     from sheeprl_tpu.telemetry import scopes
 
-    assert _scopes.STEP_SCOPES == scopes.DV3_STEP
+    assert SCOPES == scopes.DV3_STEP
+    assert _scopes.scope_pattern(SCOPES).pattern == "(?:dv3|replay)/[a-z_]+"
