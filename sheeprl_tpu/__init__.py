@@ -20,6 +20,7 @@ _ALGO_MODULES = [
     "sheeprl_tpu.algos.ppo.ppo",
     "sheeprl_tpu.algos.ppo.ppo_decoupled",
     "sheeprl_tpu.algos.ppo_recurrent.ppo_recurrent",
+    "sheeprl_tpu.algos.ppo_lm.ppo_lm",
     "sheeprl_tpu.algos.a2c.a2c",
     "sheeprl_tpu.algos.sac.sac",
     "sheeprl_tpu.algos.sac.sac_decoupled",

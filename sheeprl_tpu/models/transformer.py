@@ -1,0 +1,435 @@
+"""Decoder blocks of the DeepSeek-V3 family (flax.linen), TPU-first.
+
+What `model_type: deepseek_v3` configurations are made of, as published:
+
+- **RMSNorm** with float32 statistics.
+- **Interleaved RoPE**: the weights pair channels (2i, 2i+1); the pair is
+  rotated and the halves are laid out de-interleaved (evens, then odds), on
+  queries and keys alike, so every dot product is the published one.
+- **Latent attention (MLA)**, no query compression, in two forms over the
+  same parameters. :meth:`MLA.__call__` runs whole sequences: keys and values
+  are expanded from the latent, and the causal softmax goes query block by
+  query block over the keys up to the block's end, each block under
+  ``jax.checkpoint`` so that no ``[heads, S, S]`` tensor outlives its block.
+  :meth:`MLA.decode` is the absorbed single-token form over a cache of
+  ``(c, k_rope)``, ``kv_lora_rank + qk_rope_head_dim`` numbers a token:
+  ``q' = q_nope W_kb^T``, scores ``q'.c + q_rope.k_rope``, ``(P c) W_vb``.
+- **SwiGLU**, dense or as the shared experts (one SwiGLU of their summed width).
+- **The expert layer** (:class:`MoE`): a float32 sigmoid router over all
+  ``n_routed_experts``, top-k of ``score + bias`` with the bias used for the
+  selection only, the selected scores normalised and scaled. The layer is
+  *told which experts it holds* (``experts_held = (first, count)``): it routes
+  over all of them and computes the part of the result its own experts give,
+  as one rank of an expert-parallel deployment does before the exchange. The
+  slots that chose a held expert are sorted by expert and go through grouped
+  matrix products (``jax.lax.ragged_dot``); there is no capacity and no token
+  is dropped. Dispatch and combine are each other's transposes, so both
+  directions are gathers.
+
+Compute dtype and parameter dtype come from the precision policy
+(``bf16-mixed``: float32 parameters, bfloat16 products, float32 softmax, norm
+statistics and router). Device scopes (`telemetry/scopes.py`) name the phases.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from functools import partial
+from typing import Any, Dict, Mapping, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from sheeprl_tpu.telemetry import scopes
+
+Dtype = Any
+MASKED = -1e30  # a masked score: finite, so a row with no valid key stays finite
+ATTN_BLOCK = 512  # most queries of one block of the whole-sequence attention (a short sequence still goes in four)
+
+
+@dataclasses.dataclass(frozen=True)
+class TransformerConfig:
+    """The published keys of a `deepseek_v3` config.json, plus what this chip holds (``experts_held``)."""
+
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    num_attention_heads: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    kv_lora_rank: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    n_shared_experts: int
+    num_experts_per_tok: int
+    first_k_dense_replace: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-6
+    initializer_range: float = 0.02
+    experts_held: Optional[Tuple[int, int]] = None  # (first, count) of the routed experts held here; None = all of them
+
+    def __post_init__(self) -> None:
+        held = self.experts_held or (0, 0)
+        first, count = int(held[0]), int(held[1])
+        if count <= 0:
+            first, count = 0, int(self.n_routed_experts)
+        if first < 0 or first + count > int(self.n_routed_experts):
+            raise ValueError(f"experts_held {tuple(held)} does not lie inside the {self.n_routed_experts} routed experts")
+        object.__setattr__(self, "experts_held", (first, count))
+
+    @classmethod
+    def from_config(cls, model_cfg: Mapping[str, Any]) -> "TransformerConfig":
+        known = {f.name for f in dataclasses.fields(cls)}
+        return cls(**{k: v for k, v in dict(model_cfg).items() if k in known and v is not None})
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+# --------------------------------------------------------------------- pieces
+class RMSNorm(nn.Module):
+    epsilon: float = 1e-6
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+        return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope_tables(positions: jax.Array, dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
+    """cos and sin ``[..., dim // 2]`` of the positions' angles, float32."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate the channel pairs (2i, 2i+1) of the last axis; the result holds
+    the first members of the pairs, then the second (`rope_interleave: true`:
+    de-interleave to halves, then rotate-half)."""
+    x32 = x.astype(jnp.float32)
+    even, odd = x32[..., 0::2], x32[..., 1::2]
+    return jnp.concatenate([even * cos - odd * sin, odd * cos + even * sin], axis=-1).astype(x.dtype)
+
+
+def _init(cfg: TransformerConfig):
+    return nn.initializers.normal(cfg.initializer_range)
+
+
+class SwiGLU(nn.Module):
+    cfg: TransformerConfig
+    width: int
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        hidden = x.shape[-1]
+        gate = self.param("w_gate", _init(self.cfg), (hidden, self.width), self.param_dtype).astype(self.dtype)
+        up = self.param("w_up", _init(self.cfg), (hidden, self.width), self.param_dtype).astype(self.dtype)
+        down = self.param("w_down", _init(self.cfg), (self.width, hidden), self.param_dtype).astype(self.dtype)
+        return (nn.silu(x @ gate) * (x @ up)) @ down
+
+
+# ------------------------------------------------------------ latent attention
+class MLA(nn.Module):
+    cfg: TransformerConfig
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    def setup(self) -> None:
+        c = self.cfg
+        heads = c.num_attention_heads
+        self.norm = RMSNorm(c.rms_norm_eps, self.param_dtype)
+        self.wq = self.param("wq", _init(c), (c.hidden_size, heads * c.qk_head_dim), self.param_dtype)
+        self.wkv_a = self.param("wkv_a", _init(c), (c.hidden_size, c.kv_lora_rank + c.qk_rope_head_dim), self.param_dtype)
+        self.kv_norm = RMSNorm(c.rms_norm_eps, self.param_dtype)
+        self.wkv_b = self.param(
+            "wkv_b", _init(c), (c.kv_lora_rank, heads * (c.qk_nope_head_dim + c.v_head_dim)), self.param_dtype
+        )
+        self.wo = self.param("wo", _init(c), (heads * c.v_head_dim, c.hidden_size), self.param_dtype)
+
+    def _project(self, x: jax.Array, positions: jax.Array):
+        """``x`` [..., H] at ``positions`` [...]: q_nope [..., h, dn], q_rope
+        [..., h, dr] (rotated), the normed latent c [..., r], k_rope [..., dr] (rotated)."""
+        c = self.cfg
+        heads = c.num_attention_heads
+        xn = self.norm(x)
+        q = (xn @ self.wq.astype(self.dtype)).reshape(*x.shape[:-1], heads, c.qk_head_dim)
+        q_nope, q_rope = q[..., : c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
+        kv = xn @ self.wkv_a.astype(self.dtype)
+        latent = self.kv_norm(kv[..., : c.kv_lora_rank])
+        cos, sin = rope_tables(positions, c.qk_rope_head_dim, c.rope_theta)
+        k_rope = apply_rope_interleaved(kv[..., c.kv_lora_rank:], cos, sin)
+        q_rope = apply_rope_interleaved(q_rope, cos[..., None, :], sin[..., None, :])
+        return q_nope, q_rope, latent, k_rope
+
+    def _wkv_b(self):
+        c = self.cfg
+        w = self.wkv_b.astype(self.dtype).reshape(c.kv_lora_rank, c.num_attention_heads, c.qk_nope_head_dim + c.v_head_dim)
+        return w[..., : c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]  # W_kb, W_vb: [r, h, d]
+
+    def __call__(self, x: jax.Array, positions: jax.Array, start: jax.Array):
+        """Whole sequences: ``x`` [B, S, H], ``positions`` [B, S]; the keys of
+        row b are valid from index ``start[b]`` on (left padding). Returns
+        the attention output and ``(c, k_rope)``, what a cache keeps."""
+        c = self.cfg
+        B, S, _ = x.shape
+        q_nope, q_rope, latent, k_rope = self._project(x, positions)
+        w_kb, w_vb = self._wkv_b()
+        k_nope = jnp.einsum("bsr,rhd->bshd", latent, w_kb)
+        value = jnp.einsum("bsr,rhd->bshd", latent, w_vb)
+        scale = c.qk_head_dim ** -0.5
+
+        def block(qn, qr, kn, kr, v, first, start):
+            # queries [first, first + len) against the keys [0, first + len)
+            scores = jnp.einsum("bqhd,bkhd->bhqk", qn, kn, preferred_element_type=jnp.float32)
+            scores = scores + jnp.einsum("bqhd,bkd->bhqk", qr, kr, preferred_element_type=jnp.float32)
+            key_at = jnp.arange(kn.shape[1])
+            causal = key_at[None, :] <= (first + jnp.arange(qn.shape[1]))[:, None]
+            valid = causal[None, None] & (key_at[None, :] >= start[:, None])[:, None, None, :]
+            probs = jax.nn.softmax(jnp.where(valid, scores * scale, MASKED), axis=-1)
+            return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+
+        block = jax.checkpoint(block, static_argnums=(5,))
+        size = min(ATTN_BLOCK, -(-S // 4))
+        outs = []
+        for first in range(0, S, size):
+            end = min(first + size, S)
+            outs.append(block(q_nope[:, first:end], q_rope[:, first:end], k_nope[:, :end], k_rope[:, :end],
+                              value[:, :end], first, start))
+        out = jnp.concatenate(outs, axis=1).reshape(B, S, c.num_attention_heads * c.v_head_dim)
+        return out @ self.wo.astype(self.dtype), (latent, k_rope)
+
+    def decode(self, x: jax.Array, cache_c: jax.Array, cache_kr: jax.Array, pos: jax.Array, start: jax.Array):
+        """One token per env through the absorbed form: ``x`` [E, H] is the
+        token at index ``pos`` [E] of its env's context; the cache rows
+        [E, T, r] / [E, T, dr] get it written there, and the env attends to
+        its indices ``start..pos``. Returns the output and the two caches."""
+        c = self.cfg
+        q_nope, q_rope, latent, k_rope = self._project(x, pos - start)
+        write = jax.vmap(lambda row, new, at: jax.lax.dynamic_update_slice(row, new[None], (at, 0)))
+        cache_c = write(cache_c, latent.astype(cache_c.dtype), pos)
+        cache_kr = write(cache_kr, k_rope.astype(cache_kr.dtype), pos)
+        w_kb, w_vb = self._wkv_b()
+        q_latent = jnp.einsum("ehd,rhd->ehr", q_nope, w_kb)
+        scores = jnp.einsum("ehr,etr->eht", q_latent, cache_c, preferred_element_type=jnp.float32)
+        scores = scores + jnp.einsum("ehd,etd->eht", q_rope, cache_kr, preferred_element_type=jnp.float32)
+        key_at = jnp.arange(cache_c.shape[1])
+        valid = (key_at[None, :] >= start[:, None]) & (key_at[None, :] <= pos[:, None])
+        probs = jax.nn.softmax(jnp.where(valid[:, None, :], scores * c.qk_head_dim ** -0.5, MASKED), axis=-1)
+        context = jnp.einsum("eht,etr->ehr", probs.astype(cache_c.dtype), cache_c)
+        out = jnp.einsum("ehr,rhd->ehd", context, w_vb).reshape(x.shape[0], -1)
+        return out @ self.wo.astype(self.dtype), cache_c, cache_kr
+
+
+# ------------------------------------------------------------ the expert layer
+def route(scores: jax.Array, bias: jax.Array, k: int, normalise: bool, scaling: float):
+    """``scores`` [N, E] (sigmoid, float32): the top ``k`` of ``scores + bias``
+    and their weights. The bias takes part in the selection only."""
+    _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalise:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return chosen, weights * scaling
+
+
+@jax.custom_vjp
+def _dispatch(x: jax.Array, src: jax.Array, rank: jax.Array, held: jax.Array, n_held: jax.Array) -> jax.Array:
+    """Rows of ``x`` [N, D] in sorted-slot order: row m < n_held is the token of
+    the m-th held slot, the rest are zero."""
+    live = jnp.arange(src.shape[0]) < n_held
+    return jnp.where(live[:, None], x[src], 0)
+
+
+@jax.custom_vjp
+def _combine(y: jax.Array, src: jax.Array, rank: jax.Array, held: jax.Array, n_held: jax.Array) -> jax.Array:
+    """Its transpose: token n gets the sum of the rows of ``y`` [M, D] that its held slots stand at."""
+    rows = y[jnp.where(held, rank, 0)]  # [N, K, D]
+    return jnp.sum(jnp.where(held[..., None], rows, 0), axis=1)
+
+
+def _dispatch_fwd(x, src, rank, held, n_held):
+    return _dispatch(x, src, rank, held, n_held), (src, rank, held, n_held)
+
+
+def _dispatch_bwd(res, g):
+    return (_combine(g, *res), None, None, None, None)
+
+
+def _combine_fwd(y, src, rank, held, n_held):
+    return _combine(y, src, rank, held, n_held), (src, rank, held, n_held)
+
+
+def _combine_bwd(res, g):
+    return (_dispatch(g, *res), None, None, None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+class MoE(nn.Module):
+    """Router over all experts, grouped products over the held ones, plus the
+    shared experts. Returns ``(y, stats)``; ``stats`` are small device arrays
+    (expert counts of the held experts, slots routed and held, the selection)."""
+
+    cfg: TransformerConfig
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    def setup(self) -> None:
+        c = self.cfg
+        first, count = c.experts_held
+        width = c.moe_intermediate_size
+        self.norm = RMSNorm(c.rms_norm_eps, self.param_dtype)
+        self.router = self.param("router", _init(c), (c.hidden_size, c.n_routed_experts), jnp.float32)
+        self.router_bias = self.param("router_bias", nn.initializers.zeros, (c.n_routed_experts,), jnp.float32)
+        self.w_gate = self.param("w_gate", _init(c), (count, c.hidden_size, width), self.param_dtype)
+        self.w_up = self.param("w_up", _init(c), (count, c.hidden_size, width), self.param_dtype)
+        self.w_down = self.param("w_down", _init(c), (count, width, c.hidden_size), self.param_dtype)
+        self.shared = SwiGLU(c, c.moe_intermediate_size * c.n_shared_experts, self.dtype, self.param_dtype)
+
+    def _experts(self, x, weights, order, rank, held, sizes, n_held):
+        """The held experts' part: the sorted slots, of which the first ``n_held`` chose a held expert."""
+        src = order // self.cfg.num_experts_per_tok
+        live = jnp.arange(order.shape[0]) < n_held
+        slot_weight = jnp.where(live, weights.reshape(-1)[order], 0.0)
+        xs = _dispatch(x, src, rank, held, n_held)
+        grouped = partial(jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=self.dtype)
+        hidden = nn.silu(grouped(xs, self.w_gate.astype(self.dtype))) * grouped(xs, self.w_up.astype(self.dtype))
+        ys = grouped(hidden, self.w_down.astype(self.dtype))
+        # rows past the last group are not written by the grouped product
+        ys = jnp.where(live[:, None], ys.astype(jnp.float32) * slot_weight[:, None], 0).astype(self.dtype)
+        return _combine(ys, src, rank, held, n_held)
+
+    def __call__(self, x: jax.Array, real: Optional[jax.Array] = None):
+        """``real`` (the shape of ``x`` less its last axis) is false at the
+        padding of a left-padded batch: a position that belongs to no context
+        is routed (the selection is reported for every position) and sent to
+        no expert, so the experts' load is that of the real tokens."""
+        c = self.cfg
+        first, count = c.experts_held
+        k = c.num_experts_per_tok
+        shape = x.shape
+        with scopes.scope(scopes.LM_MOE_ROUTE):
+            xn = self.norm(x).reshape(-1, shape[-1])
+            logits = jnp.dot(xn.astype(jnp.float32), self.router, precision=jax.lax.Precision.HIGHEST)
+            chosen, weights = route(jax.nn.sigmoid(logits), self.router_bias, k, c.norm_topk_prob, c.routed_scaling_factor)
+            local = chosen - first
+            held = (local >= 0) & (local < count)  # [N, K]
+            routed_slots = jnp.asarray(held.size, jnp.int32)
+            if real is not None:
+                held = held & real.reshape(-1, 1)
+                routed_slots = jnp.sum(real).astype(jnp.int32) * k
+            group = jnp.where(held, local, count).reshape(-1)  # the other slots sort last
+            order = jnp.argsort(group, stable=True)
+            one_hot = (group[:, None] == jnp.arange(count)[None, :]).astype(jnp.int32)
+            sizes = jnp.sum(one_hot, axis=0)
+            offsets = jnp.cumsum(sizes) - sizes
+            within = jnp.cumsum(one_hot, axis=0) - one_hot  # slots of the same expert before this one
+            rank = jnp.sum(one_hot * (within + offsets[None, :]), axis=1).reshape(held.shape)
+            n_held = jnp.sum(sizes)
+        with scopes.scope(scopes.LM_MOE_EXPERTS):
+            routed = self._experts(xn, weights, order, rank, held, sizes, n_held)
+        with scopes.scope(scopes.LM_MOE_SHARED):
+            shared = self.shared(xn)
+        stats = {"expert_tokens": sizes, "held_slots": n_held, "routed_slots": routed_slots, "chosen": chosen}
+        return (routed + shared).reshape(shape), stats
+
+
+# ------------------------------------------------------------------ the blocks
+class DecoderLayer(nn.Module):
+    cfg: TransformerConfig
+    dense: bool
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    def setup(self) -> None:
+        self.attn = MLA(self.cfg, self.dtype, self.param_dtype)
+        if self.dense:
+            self.mlp_norm = RMSNorm(self.cfg.rms_norm_eps, self.param_dtype)
+            self.mlp = SwiGLU(self.cfg, self.cfg.intermediate_size, self.dtype, self.param_dtype)
+        else:
+            self.moe = MoE(self.cfg, self.dtype, self.param_dtype)
+
+    def _feed_forward(self, x: jax.Array, real: Optional[jax.Array] = None):
+        if self.dense:
+            with scopes.scope(scopes.LM_DENSE_MLP):
+                return x + self.mlp(self.mlp_norm(x)), None
+        y, stats = self.moe(x, real)
+        return x + y, stats
+
+    def __call__(self, x: jax.Array, positions: jax.Array, start: jax.Array):
+        with scopes.scope(scopes.LM_MLA):
+            attn, kept = self.attn(x, positions, start)
+            x = x + attn
+        x, stats = self._feed_forward(x, jnp.arange(x.shape[1])[None, :] >= start[:, None])
+        return x, kept, stats
+
+    def decode(self, x: jax.Array, cache_c, cache_kr, pos, start):
+        attn, cache_c, cache_kr = self.attn.decode(x, cache_c, cache_kr, pos, start)
+        x, _ = self._feed_forward(x + attn)
+        return x, cache_c, cache_kr
+
+
+class Transformer(nn.Module):
+    """Embedding, the decoder layers, the final norm. Heads are the caller's."""
+
+    cfg: TransformerConfig
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    def setup(self) -> None:
+        c = self.cfg
+        self.embedding = self.param("embedding", _init(c), (c.vocab_size, c.hidden_size), self.param_dtype)
+        layer = nn.remat(DecoderLayer)  # a layer's internals are made again in the backward pass, its input alone is kept
+        self.layers = [
+            layer(c, i < c.first_k_dense_replace, self.dtype, self.param_dtype) for i in range(c.num_hidden_layers)
+        ]
+        self.final_norm = RMSNorm(c.rms_norm_eps, self.param_dtype)
+
+    def embed(self, tokens: jax.Array) -> jax.Array:
+        with scopes.scope(scopes.LM_EMBED):
+            return jnp.take(self.embedding, tokens, axis=0).astype(self.dtype)
+
+    def __call__(self, tokens: jax.Array, start: jax.Array):
+        """``tokens`` [B, S] left-padded: row b's context begins at index
+        ``start[b]``. Returns the hidden states before the final norm [B, S, H],
+        per layer what a cache keeps, and per expert layer its stats."""
+        positions = jnp.maximum(jnp.arange(tokens.shape[1])[None, :] - start[:, None], 0)
+        x = self.embed(tokens)
+        kept, stats = [], []
+        for layer in self.layers:
+            x, kv, layer_stats = layer(x, positions, start)
+            kept.append(kv)
+            if layer_stats is not None:
+                stats.append(layer_stats)
+        return x, kept, stats
+
+    def decode(self, tokens: jax.Array, cache: Dict[str, Any], pos: jax.Array, start: jax.Array):
+        """``tokens`` [E] at indices ``pos`` [E]; ``cache`` = {"c": L x [E, T, r], "kr": L x [E, T, dr]},
+        one array a layer so that a donated cache is updated in place."""
+        x = self.embed(tokens)
+        cs, krs = [], []
+        for i, layer in enumerate(self.layers):
+            x, c_i, kr_i = layer.decode(x, cache["c"][i], cache["kr"][i], pos, start)
+            cs.append(c_i)
+            krs.append(kr_i)
+        return x, {"c": tuple(cs), "kr": tuple(krs)}
+
+
+def merge_moe_stats(stats: list) -> Optional[Dict[str, jax.Array]]:
+    """The per-layer stats of one forward pass as arrays with a leading layer axis."""
+    if not stats:
+        return None
+    return {k: jnp.stack([s[k] for s in stats]) for k in stats[0]}

@@ -27,6 +27,19 @@ DV3_STEP = (DV3_ENCODER, DV3_RSSM, DV3_HEADS, DV3_IMAGINE, DV3_ACTOR_CRITIC, DV3
 DV3_ACT = "dv3/act"  # the player's acting step
 RING_SAMPLE = "replay/ring_sample"  # the in-jit sampler of the device replay ring
 RING_WRITE = "replay/ring_write"  # the ring's donated write program
+# The token policy's gradient step (algos/ppo_lm/ppo_lm.py:make_train_step over models/transformer.py)
+LM_EMBED = "lm/embed"  # the token embedding lookup
+LM_MLA = "lm/mla"  # pre-norm, latent attention (projections, RoPE, scores, output projection)
+LM_MOE_ROUTE = "lm/moe_route"  # pre-norm of the feed-forward half, router scores, top-k, the sort by expert
+LM_MOE_EXPERTS = "lm/moe_experts"  # dispatch, the grouped products over the held experts, combine
+LM_MOE_SHARED = "lm/moe_shared"  # the shared experts' SwiGLU
+LM_DENSE_MLP = "lm/dense_mlp"  # the leading dense layers' SwiGLU
+LM_HEAD_LOSS = "lm/head_loss"  # final norm, vocabulary head, value head, the PPO loss
+LM_OPTIM = "lm/optim"  # clipping and the optimizer update
+LM_STEP = (LM_EMBED, LM_MLA, LM_MOE_ROUTE, LM_MOE_EXPERTS, LM_MOE_SHARED, LM_DENSE_MLP, LM_HEAD_LOSS, LM_OPTIM)
+# Outside the gradient step: the player's two programs
+LM_ACT_PREFILL = "lm/act_prefill"  # whole prompts through the whole-sequence form, filling the latent cache
+LM_ACT_DECODE = "lm/act_decode"  # one token per env through the absorbed form over the cache
 
 
 def scope(name: str):
