@@ -758,6 +758,16 @@ GRU_SHAPES = {
 }
 
 
+#: (sequences, positions, with its backward) of the whole-sequence latent
+#: attention at the token policy's benchmark cell (32 heads of 128 + 64 / 128, latent 512):
+#: the gradient step's minibatch, and the player's prefill of all 16 prompts.
+MLA_SHAPES = {
+    "update": (4, 2080, True),
+    "prefill_16_prompts": (16, 2048, False),
+}
+MLA_HEADS, MLA_ROPE, MLA_LATENT = 32, 64, 512
+
+
 def described_v5e():
     """A 2x2 TPU v5e that is described, not attached: the compiler's target."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -785,6 +795,31 @@ def compile_ln_gru(batch: int, hidden: int, d: int, dtype: Any, sharding: Any):
     )
 
 
+def compile_mla_attention(batch: int, seq: int, grad: bool, dtype: Any, sharding: Any):
+    """Compile the fused latent-attention kernels (forward, or forward and
+    backward under `jax.grad`) for the device behind ``sharding``."""
+    import jax
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.models import pallas_mla_attention as kernel
+
+    def spec(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    def attend(qn, qr, latent, w_kv, kr, start):
+        return kernel.mla_attention(qn, qr, latent, w_kv, kr, start, (kernel.LANES + MLA_ROPE) ** -0.5)
+
+    def grads(*args):
+        return jax.grad(lambda *a: attend(*a, args[5]).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*args[:5])
+
+    return (
+        jax.jit(grads if grad else attend)
+        .lower(spec(batch, seq, MLA_HEADS, kernel.LANES), spec(batch, seq, MLA_HEADS, MLA_ROPE), spec(batch, seq, MLA_LATENT),
+               spec(MLA_LATENT, MLA_HEADS * 2 * kernel.LANES), spec(batch, seq, MLA_ROPE), spec(batch, dt=jnp.int32))
+        .compile()
+    )
+
+
 def aot_rehearsal() -> int:
     """From a sandbox with no chip: do the kernels and the real train step
     compile for the chip? Nothing runs; this is not a chip run."""
@@ -795,7 +830,7 @@ def aot_rehearsal() -> int:
 
     import sheeprl_tpu
     from sheeprl_tpu.config.loader import compose
-    from sheeprl_tpu.models import pallas_gru
+    from sheeprl_tpu.models import pallas_gru, pallas_mla_attention
 
     # A compile for a described chip is written to the persistent cache but
     # cannot be read back without one: keep the cache out of it.
@@ -812,6 +847,14 @@ def aot_rehearsal() -> int:
         started = time.perf_counter()
         compile_ln_gru(batch, hidden, d, jnp.float32, one_chip)
         say(f"aot: LN-GRU {name} B={batch} H={hidden} D={d}: compiles ({time.perf_counter() - started:.1f} s)")
+    for name, (batch, seq, grad) in MLA_SHAPES.items():
+        reason = pallas_mla_attention.shape_ineligible_reason(seq, pallas_mla_attention.LANES, MLA_ROPE, pallas_mla_attention.LANES, jnp.bfloat16)
+        if reason is not None:
+            say(f"aot: latent attention {name} [{batch}, {seq}]: declared ineligible ({reason})")
+            continue
+        started = time.perf_counter()
+        compile_mla_attention(batch, seq, grad, jnp.bfloat16, one_chip)
+        say(f"aot: latent attention {name} [{batch}, {seq}]{' with its backward' if grad else ''}: compiles ({time.perf_counter() - started:.1f} s)")
     sheeprl_tpu.register_all()
     for count in (1, 4):
         cfg = compose("config", dv3_overrides(OUT_DIR, "unused", "tpu", FULL, (f"fabric.devices={count}",)))

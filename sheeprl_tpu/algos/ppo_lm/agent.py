@@ -25,13 +25,14 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from sheeprl_tpu.models.transformer import Transformer, TransformerConfig, _init, merge_moe_stats
+from sheeprl_tpu.models.transformer import Transformer, TransformerConfig, _init, attention_is_fused, merge_moe_stats
 from sheeprl_tpu.telemetry import scopes
 
 HIGHEST = jax.lax.Precision.HIGHEST
 #: Matrices that compute in float32 under every precision policy: the player's copy keeps them so.
 FLOAT32_LEAVES = ("router", "value_head")
-#: Prompts that share one block of attention scores at prefill (all of them where they do not divide).
+#: Prompts that share one block of float32 attention scores where the prefill's softmax runs in plain JAX (all of
+#: them where they do not divide). The fused kernels make no such block: there every prompt goes through at once.
 PREFILL_GROUP = 4
 
 
@@ -126,17 +127,18 @@ class PPOLMAgent:
         with scopes.scope(scopes.LM_ACT_PREFILL):
             E, P = prompt.shape
             start = (P - prompt_len).astype(jnp.int32)
-            group = PREFILL_GROUP if E % PREFILL_GROUP == 0 else E
 
             def some(args):
                 return self.module.apply(params, *args, method=LMPolicy.prefill)
 
-            grouped = lambda x: x.reshape(E // group, group, *x.shape[1:])  # noqa: E731
-            logits, values, kept = jax.lax.map(some, (grouped(prompt), grouped(start)))
-            ungroup = lambda x: x.reshape(E, *x.shape[2:])  # noqa: E731
-            logits, values = ungroup(logits), ungroup(values)
+            if attention_is_fused(self.model, P, self.dtype) or E % PREFILL_GROUP:
+                logits, values, kept = some((prompt, start))
+            else:
+                grouped = lambda x: x.reshape(E // PREFILL_GROUP, PREFILL_GROUP, *x.shape[1:])  # noqa: E731
+                out = jax.lax.map(some, (grouped(prompt), grouped(start)))
+                logits, values, kept = jax.tree_util.tree_map(lambda x: x.reshape(E, *x.shape[2:]), out)
             keep = lambda new, old: jnp.where(reset.reshape((E,) + (1,) * (old.ndim - 1)), new, old)  # noqa: E731
-            fill = lambda new, old: keep(old.at[:, :P].set(ungroup(new).astype(old.dtype)), old)  # noqa: E731
+            fill = lambda new, old: keep(old.at[:, :P].set(new.astype(old.dtype)), old)  # noqa: E731
             new_state = {
                 "c": tuple(fill(c, old) for (c, _), old in zip(kept, state["c"])),
                 "kr": tuple(fill(kr, old) for (_, kr), old in zip(kept, state["kr"])),

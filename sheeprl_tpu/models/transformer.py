@@ -8,9 +8,16 @@ What `model_type: deepseek_v3` configurations are made of, as published:
   queries and keys alike, so every dot product is the published one.
 - **Latent attention (MLA)**, no query compression, in two forms over the
   same parameters. :meth:`MLA.__call__` runs whole sequences: keys and values
-  are expanded from the latent, and the causal softmax goes query block by
-  query block over the keys up to the block's end, each block under
-  ``jax.checkpoint`` so that no ``[heads, S, S]`` tensor outlives its block.
+  are expanded from the latent, and the causal softmax over left-padded keys
+  is one algorithm in two implementations chosen by what the code can see
+  (`pallas_mla_attention.ineligible_reason`, the whole rule): on a TPU, at an
+  eligible shape, the fused kernels of `models/pallas_mla_attention.py`
+  (online softmax forward, probabilities made again from the saved
+  log-sum-exp backward: no block of scores goes to HBM); elsewhere
+  :func:`blocked_attention`, plain JAX query block by query block under
+  ``jax.checkpoint``, which is what the CPU tests and the micro sizes run and
+  what the kernels are held to. Both share the projections, RoPE and the
+  output product, and both sit under the ``lm/mla`` scope.
   :meth:`MLA.decode` is the absorbed single-token form over a cache of
   ``(c, k_rope)``, ``kv_lora_rank + qk_rope_head_dim`` numbers a token:
   ``q' = q_nope W_kb^T``, scores ``q'.c + q_rope.k_rope``, ``(P c) W_vb``.
@@ -41,11 +48,12 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from sheeprl_tpu.models import pallas_mla_attention
 from sheeprl_tpu.telemetry import scopes
 
 Dtype = Any
-MASKED = -1e30  # a masked score: finite, so a row with no valid key stays finite
-ATTN_BLOCK = 512  # most queries of one block of the whole-sequence attention (a short sequence still goes in four)
+MASKED = pallas_mla_attention.MASKED  # a masked score: finite, so a row with no valid key stays finite
+ATTN_BLOCK = 512  # most queries of one block of the plain whole-sequence attention (a short sequence still goes in four)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,6 +149,44 @@ class SwiGLU(nn.Module):
 
 
 # ------------------------------------------------------------ latent attention
+def blocked_attention(q_nope: jax.Array, q_rope: jax.Array, k_nope: jax.Array, k_rope: jax.Array, v: jax.Array,
+                      start: jax.Array, scale: float) -> jax.Array:
+    """The causal softmax of left-padded whole sequences in plain JAX, query
+    block by query block over the keys up to the block's end, each block under
+    ``jax.checkpoint`` so that no ``[heads, S, S]`` tensor outlives its block:
+    ``q_nope``, ``k_nope`` [B, S, h, dn], ``q_rope`` [B, S, h, dr], ``k_rope``
+    [B, S, dr], ``v`` [B, S, h, dv]; row b's keys are valid from ``start[b]``
+    on. What runs where `pallas_mla_attention` does not, and what it is held to."""
+
+    def block(qn, qr, kn, kr, v, first, start):
+        # queries [first, first + len) against the keys [0, first + len)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qn, kn, preferred_element_type=jnp.float32)
+        scores = scores + jnp.einsum("bqhd,bkd->bhqk", qr, kr, preferred_element_type=jnp.float32)
+        key_at = jnp.arange(kn.shape[1])
+        causal = key_at[None, :] <= (first + jnp.arange(qn.shape[1]))[:, None]
+        valid = causal[None, None] & (key_at[None, :] >= start[:, None])[:, None, None, :]
+        probs = jax.nn.softmax(jnp.where(valid, scores * scale, MASKED), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+
+    block = jax.checkpoint(block, static_argnums=(5,))
+    S = q_nope.shape[1]
+    size = min(ATTN_BLOCK, -(-S // 4))
+    outs = []
+    for first in range(0, S, size):
+        end = min(first + size, S)
+        outs.append(block(q_nope[:, first:end], q_rope[:, first:end], k_nope[:, :end], k_rope[:, :end],
+                          v[:, :end], first, start))
+    return jnp.concatenate(outs, axis=1)
+
+
+def attention_is_fused(cfg: TransformerConfig, seq: int, dtype: Dtype) -> bool:
+    """Whether :meth:`MLA.__call__` hands whole sequences of ``seq`` positions
+    to the fused kernels where this is traced; else :func:`blocked_attention`
+    runs, whose float32 score block grows with the rows that share it."""
+    return pallas_mla_attention.ineligible_reason(
+        seq, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, dtype) is None
+
+
 class MLA(nn.Module):
     cfg: TransformerConfig
     dtype: Dtype = jnp.float32
@@ -181,33 +227,23 @@ class MLA(nn.Module):
     def __call__(self, x: jax.Array, positions: jax.Array, start: jax.Array):
         """Whole sequences: ``x`` [B, S, H], ``positions`` [B, S]; the keys of
         row b are valid from index ``start[b]`` on (left padding). Returns
-        the attention output and ``(c, k_rope)``, what a cache keeps."""
+        the attention output and ``(c, k_rope)``, what a cache keeps. On the
+        plain path a block of float32 scores ``[B, heads, ATTN_BLOCK, S]`` goes
+        through memory, so a caller with many rows bounds ``B`` itself where
+        :func:`attention_is_fused` says no (the player's prefill does)."""
         c = self.cfg
         B, S, _ = x.shape
         q_nope, q_rope, latent, k_rope = self._project(x, positions)
-        w_kb, w_vb = self._wkv_b()
-        k_nope = jnp.einsum("bsr,rhd->bshd", latent, w_kb)
-        value = jnp.einsum("bsr,rhd->bshd", latent, w_vb)
         scale = c.qk_head_dim ** -0.5
-
-        def block(qn, qr, kn, kr, v, first, start):
-            # queries [first, first + len) against the keys [0, first + len)
-            scores = jnp.einsum("bqhd,bkhd->bhqk", qn, kn, preferred_element_type=jnp.float32)
-            scores = scores + jnp.einsum("bqhd,bkd->bhqk", qr, kr, preferred_element_type=jnp.float32)
-            key_at = jnp.arange(kn.shape[1])
-            causal = key_at[None, :] <= (first + jnp.arange(qn.shape[1]))[:, None]
-            valid = causal[None, None] & (key_at[None, :] >= start[:, None])[:, None, None, :]
-            probs = jax.nn.softmax(jnp.where(valid, scores * scale, MASKED), axis=-1)
-            return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
-
-        block = jax.checkpoint(block, static_argnums=(5,))
-        size = min(ATTN_BLOCK, -(-S // 4))
-        outs = []
-        for first in range(0, S, size):
-            end = min(first + size, S)
-            outs.append(block(q_nope[:, first:end], q_rope[:, first:end], k_nope[:, :end], k_rope[:, :end],
-                              value[:, :end], first, start))
-        out = jnp.concatenate(outs, axis=1).reshape(B, S, c.num_attention_heads * c.v_head_dim)
+        if attention_is_fused(c, S, self.dtype):
+            # the kernels' wrapper expands keys and values itself: `wkv_b` holds each head's key columns beside its value columns
+            out = pallas_mla_attention.mla_attention(q_nope, q_rope, latent, self.wkv_b.astype(self.dtype), k_rope, start, scale)
+        else:
+            w_kb, w_vb = self._wkv_b()
+            k_nope = jnp.einsum("bsr,rhd->bshd", latent, w_kb)
+            value = jnp.einsum("bsr,rhd->bshd", latent, w_vb)
+            out = blocked_attention(q_nope, q_rope, k_nope, k_rope, value, start, scale)
+        out = out.reshape(B, S, c.num_attention_heads * c.v_head_dim)
         return out @ self.wo.astype(self.dtype), (latent, k_rope)
 
     def decode(self, x: jax.Array, cache_c: jax.Array, cache_kr: jax.Array, pos: jax.Array, start: jax.Array):

@@ -96,6 +96,31 @@ def test_decode_through_the_cache_is_the_whole_sequence_form_and_the_reference(a
                 assert np.abs(logits - plain[t]).max() < 1e-4 * np.abs(plain[t]).max(), (name, t)
 
 
+def test_prefill_takes_the_prompts_at_once_only_where_the_kernels_run(agent_and_params, monkeypatch):
+    """Eight prompts go four at a time where the plain path runs (its float32
+    score block grows with the rows that share it: a loop in what is traced)
+    and all at once where the rule says the kernels take them; logits, values
+    and cache rows are the same either way."""
+    from sheeprl_tpu.algos.ppo_lm import agent as agent_module
+
+    agent, params = agent_and_params
+    rng = np.random.default_rng(11)
+    lengths = rng.integers(2, P + 1, 8)
+    prompts = np.stack([left_padded(rng, n) for n in lengths])
+
+    def prefill():
+        return agent.prefill(params, agent.init_state(8), prompts, lengths, np.ones(8, bool), jax.random.PRNGKey(0), greedy=True)
+
+    assert "scan[" in str(jax.make_jaxpr(lambda: prefill())())  # a fresh function each time: traces are cached by it
+    (_, _, values), state, _ = prefill()
+    # the agent's side of the rule alone: the layer itself still takes the plain path on the CPU
+    monkeypatch.setattr(agent_module, "attention_is_fused", lambda *a: True)
+    assert "scan[" not in str(jax.make_jaxpr(lambda: prefill())())
+    (_, _, values_at_once), state_at_once, _ = prefill()
+    for got, want in zip(jax.tree_util.tree_leaves((values_at_once, state_at_once)), jax.tree_util.tree_leaves((values, state))):
+        assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 1e-5 * max(np.abs(np.asarray(want)).max(), 1.0)
+
+
 def test_the_eight_shares_add_up_to_the_uncut_layer(agent_and_params):
     """Section 4's share test: the routed parts that the eight shares of an
     expert layer give, with the shared experts (which every chip computes

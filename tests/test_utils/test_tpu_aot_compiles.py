@@ -2,9 +2,10 @@
 
 The compiler that ships with the installed jaxlib/libtpu compiles for a TPU
 v5e that is described, not attached (on-chip-measurement guide, section 2.3).
-Held here: the fused Pallas LN-GRU cell at the Dreamer sizes — "eligible"
-must imply "compiles". Nothing here runs on a device, and nothing here is a
-chip measurement. (The bound, the warning and the cache placement are in
+Held here: the fused Pallas LN-GRU cell at the Dreamer sizes and the fused
+latent-attention kernels at the token policy's shapes — "eligible" must imply
+"compiles". Nothing here runs on a device, and nothing here is a chip
+measurement. (The bound, the warning and the cache placement are in
 tests/test_core/test_tpu_aot.py.)
 """
 
@@ -21,7 +22,7 @@ sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
 
-from sheeprl_tpu.models import pallas_gru  # noqa: E402
+from sheeprl_tpu.models import pallas_gru, pallas_mla_attention  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -58,3 +59,53 @@ def test_ln_gru_eligible_implies_compiles(size, dtype, one_described_chip):
     assert reason is None
     compiled = chip_smoke.compile_ln_gru(batch, hidden, d, dtype, one_described_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("shape", sorted(chip_smoke.MLA_SHAPES))
+def test_mla_attention_eligible_implies_compiles(shape, dtype, one_described_chip):
+    batch, seq, grad = chip_smoke.MLA_SHAPES[shape]
+    lanes = pallas_mla_attention.LANES
+    assert pallas_mla_attention.shape_ineligible_reason(seq, lanes, chip_smoke.MLA_ROPE, lanes, dtype) is None
+    compiled = chip_smoke.compile_mla_attention(batch, seq, grad, dtype, one_described_chip)
+    assert compiled.as_text().count("tpu_custom_call") == (2 if grad else 1)  # forward, and one backward kernel
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_mla_attention_longest_eligible_compiles(dtype, one_described_chip):
+    """The longest sequences the rule admits (its VMEM bound) are what the compiler admits too."""
+    lanes, block = pallas_mla_attention.LANES, pallas_mla_attention.BLOCK
+    eligible = lambda seq: pallas_mla_attention.shape_ineligible_reason(seq, lanes, chip_smoke.MLA_ROPE, lanes, dtype) is None  # noqa: E731
+    seq = max(n * block for n in range(1, 64) if eligible(n * block))
+    assert 4096 <= seq < 63 * block and "VMEM" in pallas_mla_attention.shape_ineligible_reason(seq + block, lanes, chip_smoke.MLA_ROPE, lanes, dtype)
+    chip_smoke.compile_mla_attention(1, seq, True, dtype, one_described_chip)
+
+
+def test_mla_gradient_step_holds_the_kernels(one_described_chip, monkeypatch):
+    """The gradient of one attention layer at the cell's widths and its
+    `[4, 2080]` minibatch, compiled for the chip: the layer takes the kernels
+    (the rule is asked about the shape alone here: this process's backend is
+    the CPU) and the compiled step holds them, forward and backward."""
+    from sheeprl_tpu.models import transformer as T
+
+    monkeypatch.setattr(pallas_mla_attention, "ineligible_reason", pallas_mla_attention.shape_ineligible_reason)
+    cfg = T.TransformerConfig(vocab_size=16032, hidden_size=2048, num_hidden_layers=5, num_attention_heads=32, qk_nope_head_dim=128,
+                              qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512, intermediate_size=6144,
+                              moe_intermediate_size=768, n_routed_experts=128, n_shared_experts=2, num_experts_per_tok=6)
+    layer = T.MLA(cfg, jnp.bfloat16, jnp.float32)
+    batch, seq, _ = chip_smoke.MLA_SHAPES["update"]
+
+    def spec(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_described_chip)
+
+    x, positions, start = spec(batch, seq, cfg.hidden_size), spec(batch, seq, dt=jnp.int32), spec(batch, dt=jnp.int32)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size), jnp.bfloat16),
+                                               jnp.zeros((1, 8), jnp.int32), jnp.zeros((1,), jnp.int32)))
+    params = jax.tree_util.tree_map(lambda p: spec(*p.shape, dt=p.dtype), params)
+
+    def step(params, x, positions, start):
+        return jax.grad(lambda p: layer.apply(p, x, positions, start)[0].astype(jnp.float32).sum())(params)
+
+    text = jax.jit(step).lower(params, x, positions, start).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "mla_attention_fwd" in text and "mla_attention_bwd" in text
