@@ -116,7 +116,7 @@ def dv3_overrides(root_dir: str, run_name: str, accelerator: str, size: Dict[str
 
 
 def ppo_anakin_overrides(root_dir: str, run_name: str, accelerator: str, size: Dict[str, Any]):
-    """Phase B's recipe, as bench.py's Anakin legs compose it."""
+    """Phase B's recipe: ``exp=ppo_anakin`` with the fused rollout on."""
     return [
         "exp=ppo_anakin",
         "algo.fused_rollout=True",

@@ -31,13 +31,12 @@ fi
 echo "== graftlint (whole package, zero findings, no baseline) =="
 python -m sheeprl_tpu.analysis --no-baseline sheeprl_tpu/ || rc=1
 
-# Performance-observatory gate: the goodput accountant and the bench store
-# sit on the hot dispatch path / the CI gate path — they hold zero findings
-# by name so a future package-wide policy change can't quietly exempt them.
+# Performance-observatory gate: the goodput accountant and the mesh
+# observatory sit on the hot dispatch path — they hold zero findings by
+# name so a future package-wide policy change can't quietly exempt them.
 echo "== graftlint (performance observatory, zero findings) =="
 python -m sheeprl_tpu.analysis --no-baseline \
-    sheeprl_tpu/telemetry/perf.py sheeprl_tpu/telemetry/bench_db.py \
-    sheeprl_tpu/telemetry/mesh_obs.py || rc=1
+    sheeprl_tpu/telemetry/perf.py sheeprl_tpu/telemetry/mesh_obs.py || rc=1
 
 # Sharded-learner gate: every core/ and data/ file the mesh-parallel train
 # path flows through (mesh plan -> runtime -> fused superstep -> device

@@ -6,8 +6,8 @@ it silently **reshards** to the requested one. When a buffer is produced
 under ``NamedSharding(mesh, P("data"))`` and the train step declares
 ``in_shardings=P("model")`` (or a stale spec after a mesh refactor), each
 call inserts an all-to-all the profiler attributes to "infeed" and no
-error ever surfaces — the classic goodput sink the roofline accounting in
-``bench.py`` cannot see past. The disagreement is fully static: both
+error ever surfaces — the classic goodput sink roofline accounting cannot
+see past. The disagreement is fully static: both
 sides are written down as ``PartitionSpec`` literals in the same program.
 
 Analysis (project-wide, on the :mod:`~sheeprl_tpu.analysis.meshmodel`):

@@ -5,9 +5,8 @@ Two passes per scan:
 1. **file pass** — every ``.py`` is parsed into a LintContext and the
    per-file rules run against it. Files are independent, so this pass fans
    out over a thread pool (``jobs``); parsing and AST walking release enough
-   of the interpreter between files that the full-repo scan stays in the
-   single-digit seconds the CI gate budgets (``bench.py graftlint_repo``
-   tracks it).
+   of the interpreter between files that the full-repo scan stays in
+   single-digit seconds.
 2. **project pass** — the parsed contexts are assembled into one
    :class:`~sheeprl_tpu.analysis.project.AnalysisContext` (module graph +
    symbol table + call edges + jit closure) and each ProjectRule runs once

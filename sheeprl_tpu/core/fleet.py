@@ -466,7 +466,7 @@ class FleetSupervisor:
         # Credit-based flow control: each replica may run at most max_inflight
         # shipments ahead of the learner's ingestion (0 = unbounded). Bounds
         # pipe memory AND stops replicas stealing CPU from the learner on
-        # shared cores — the bench overhead gate depends on this.
+        # shared cores.
         self._max_inflight = int(max_inflight)
         self._mp = mp.get_context(start_method)
         self._slots: List[_ReplicaSlot] = [_ReplicaSlot(index=i) for i in range(self._replicas)]

@@ -76,13 +76,13 @@ def fused_enabled(cfg) -> bool:
     return bool(cfg.env.get("jax_native", False)) and bool(cfg.algo.get("fused_rollout", False))
 
 
-# Dispatch accounting for the bench's head-to-head legs: supersteps run,
-# jit dispatches issued, env steps covered (scripts/bench.py reads these).
+# Dispatch accounting: supersteps run, jit dispatches issued, env steps
+# covered (chip_smoke.py and the lane's tests read these).
 _RUN_STATS: Dict[str, int] = {"supersteps": 0, "jit_dispatches": 0, "env_steps": 0}
 
 
 def last_run_stats() -> Dict[str, int]:
-    """Counters from the most recent fused run (bench reporting)."""
+    """Counters from the most recent fused run."""
     return dict(_RUN_STATS)
 
 

@@ -307,16 +307,15 @@ class FetchStats:
         }
 
 
-# bench.py reads the slot from its own thread while a decoupled trainer
-# may still be publishing; swap under the lock.
+# A reader on another thread may take the slot while a decoupled trainer
+# is still publishing; swap under the lock.
 _stats_lock = threading.Lock()
 _LAST_RUN_STATS: Optional[Dict[str, float]] = None  # graftlint: guarded-by(_stats_lock)
 
 
 def last_run_stats() -> Optional[Dict[str, float]]:
     """The stats dict from the most recent :meth:`InteractionPipeline.publish`
-    in this process — how ``bench.py`` reads a leg's interaction time split
-    without parsing logs."""
+    in this process: a run's interaction time split without parsing logs."""
     with _stats_lock:
         return _LAST_RUN_STATS
 
@@ -646,7 +645,7 @@ class InteractionPipeline:
 
     def publish(self) -> Dict[str, float]:
         """End-of-run: publish the stats dict to the module-level
-        :func:`last_run_stats` slot (read in-process by ``bench.py``) and the
+        :func:`last_run_stats` slot and the
         overlap-fraction gauge to the current tracer."""
         global _LAST_RUN_STATS
         stats = self.snapshot()

@@ -8,8 +8,7 @@ ways:
   (``env.jax_native=true`` + ``algo.fused_rollout=true``);
 - adapted in: external gymnax-style envs via :class:`GymnaxAdapter`;
 - adapted out: any jax env through the host Gymnasium pipeline via
-  :class:`JaxToGymnasium` (the compatibility lane the bench legs race
-  against).
+  :class:`JaxToGymnasium` (the compatibility lane).
 
 First-party envs — one per algorithm family: :class:`CartPole` (discrete,
 ppo), :class:`Pendulum` (continuous, sac), :class:`Gridworld` (pixels,

@@ -5,8 +5,8 @@ calling convention ``reset(key, params) -> (obs, state)`` /
 ``step(key, state, action, params) -> (obs, state, reward, done, info)``.
 :class:`GymnaxAdapter` re-shuffles that into this repo's
 :class:`~sheeprl_tpu.envs.jax.base.JaxEnv` protocol without touching the
-wrapped env: drop a gymnax env in, get the fused loop, the
-``JaxToGymnasium`` compatibility lane and the bench legs for free.
+wrapped env: drop a gymnax env in, get the fused loop and the
+``JaxToGymnasium`` compatibility lane for free.
 
 The registry maps env ids to factories. Ids are normalized (lowercase,
 optional ``jax_`` prefix and ``-vN`` suffix stripped) so config ids like

@@ -4,8 +4,8 @@ The reverse adapter: a :class:`~sheeprl_tpu.envs.jax.base.JaxEnv` becomes a
 standard ``gymnasium.Env``, so every jax env ALSO runs through the existing
 pipeline unchanged — make_env's dict-ification/rescaling, SyncVectorEnv
 with SAME_STEP autoreset, `core/interact.py`, RecordEpisodeStatistics, the
-whole Gymnasium contract. This is what makes the bench legs head-to-head
-(both lanes step the *same* dynamics) and what lets a fused-lane checkpoint
+whole Gymnasium contract. This is what makes the two lanes comparable
+(both step the *same* dynamics) and what lets a fused-lane checkpoint
 resume on the host lane with nothing but ``algo.fused_rollout=false``.
 
 Instantiable straight from a wrapper config::

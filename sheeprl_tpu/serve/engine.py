@@ -160,7 +160,7 @@ class InferenceEngine:
             pass
         # bucket -> [requests_served, batches] for mean-occupancy reporting.
         # Written by the dispatcher thread, cleared by reset_stats() from
-        # HTTP/bench threads — both sides must hold the condition's lock.
+        # another thread — both sides must hold the condition's lock.
         self._occupancy: Dict[int, List[int]] = {}  # graftlint: guarded-by(self._cv)
         self._ewma_service_s: Optional[float] = None  # graftlint: guarded-by(self._cv)
         # Serve processes have no JaxEventMonitor; the module listeners still
@@ -562,7 +562,7 @@ class InferenceEngine:
 
         per_request = elapsed / len(live)
         with self._cv:
-            # reset_stats() clears the occupancy table from bench/HTTP threads
+            # reset_stats() clears the occupancy table from another thread
             # mid-run; unlocked setdefault here would resurrect a dead bucket
             # row and double-count against the post-reset window.
             prev = self._ewma_service_s
@@ -641,8 +641,9 @@ class InferenceEngine:
 
     # ----------------------------------------------------------------- stats
     def reset_stats(self) -> None:
-        """Zero the latency histogram, occupancy table, and counters (bench
-        sweeps measure per-leg windows); the service-time EWMA is kept."""
+        """Zero the latency histogram, occupancy table, and counters (for a
+        caller that measures one window at a time); the service-time EWMA is
+        kept."""
         with self._cv:
             self.latency.reset()
             self._occupancy.clear()

@@ -282,8 +282,8 @@ class PolicyServer:
 
 
 class ServeClient:
-    """In-process client mirroring the HTTP surface (bench legs and tests
-    exercise the exact engine semantics without a socket in the loop)."""
+    """In-process client mirroring the HTTP surface (tests exercise the
+    exact engine semantics without a socket in the loop)."""
 
     def __init__(self, engine: InferenceEngine) -> None:
         self.engine = engine

@@ -33,26 +33,21 @@ Parts (see each module's docstring for the design):
   (CPU fallback: calibrated micro-kernel probe), and the
   :class:`PerfAccountant` that publishes ``perf/mfu``,
   ``perf/hbm_bw_util`` and the compute/infeed/host step-time breakdown;
-- :mod:`~sheeprl_tpu.telemetry.bench_db` — the schema-versioned
-  ``BENCH_HISTORY.jsonl`` store (atomic concurrent-safe append, git +
-  hardware stamps) and the bootstrap-CI regression statistics;
 - :mod:`~sheeprl_tpu.telemetry.telemetry` — the :class:`Telemetry` facade
   the Runtime carries and the algorithms thread through their loops.
 
 ``python -m sheeprl_tpu.telemetry tail <logdir>`` renders a live run's
 current health and throughput from its ``telemetry.jsonl``;
 ``python -m sheeprl_tpu.telemetry flight <logdir>`` lists and inspects
-flight dumps (``--merge`` writes the cross-process aggregated trace);
-``python -m sheeprl_tpu.telemetry perf`` prints the bench trend table and
-(with ``--check``) gates on statistical regressions.
+flight dumps (``--merge`` writes the cross-process aggregated trace).
 """
 
-from sheeprl_tpu.telemetry import bench_db, flight, trace_context, tracer
+from sheeprl_tpu.telemetry import flight, trace_context, tracer
 from sheeprl_tpu.telemetry.flight import FlightRecorder, aggregate_traces
 from sheeprl_tpu.telemetry.health import HealthEvent, HealthMonitor, health_probe, probes_enabled
 from sheeprl_tpu.telemetry.histogram import Histogram, geometric_bounds
 from sheeprl_tpu.telemetry.jax_events import JaxEventMonitor
-from sheeprl_tpu.telemetry.perf import PerfAccountant, jit_cost, last_published, resolve_peaks
+from sheeprl_tpu.telemetry.perf import PerfAccountant, jit_cost, resolve_peaks
 from sheeprl_tpu.telemetry.profiling import ProfilerWindow
 from sheeprl_tpu.telemetry.registry import Counter, Gauge, MetricsExporter, MetricsRegistry, default_registry
 from sheeprl_tpu.telemetry.step_timer import StepTimer
@@ -75,13 +70,11 @@ __all__ = [
     "TraceContext",
     "PerfAccountant",
     "aggregate_traces",
-    "bench_db",
     "default_registry",
     "flight",
     "geometric_bounds",
     "health_probe",
     "jit_cost",
-    "last_published",
     "probes_enabled",
     "ProfilerWindow",
     "resolve_peaks",

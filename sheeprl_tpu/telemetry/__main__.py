@@ -22,14 +22,6 @@ blocks, but offline from the JSONL instead of needing live arrays), and a
 table of the latest per-shard goodput gauges (``perf/shard/*``) with the
 imbalance figure — everything the run recorded via ``Telemetry.set_mesh``
 and ``record_param_layouts``. Read-only and jax-free like ``tail``.
-
-``perf [history]`` is the regression gate over ``BENCH_HISTORY.jsonl``:
-for every leg it splits the history into HEAD (the newest git sha present)
-vs baseline (everything before it), runs the bench_db noise-aware test
-(median-of-reps vs bootstrapped CI of the baseline median), and prints a
-trend table. ``--check`` exits nonzero when any leg regressed — the CI
-tripwire; ``--warn-only`` downgrades that to a warning on noisy (CPU)
-runners. Stdlib-only like the other subcommands: no jax import anywhere.
 """
 
 from __future__ import annotations
@@ -360,86 +352,6 @@ def flight(
     return 0
 
 
-def perf(
-    history: Optional[str] = None,
-    legs: Optional[List[str]] = None,
-    check: bool = False,
-    warn_only: bool = False,
-    threshold: float = 0.10,
-    window: int = 10,
-    head_runs: int = 0,
-    out: Any = None,
-) -> int:
-    """Trend table + regression verdict over the bench history."""
-    from sheeprl_tpu.telemetry import bench_db
-
-    out = out if out is not None else sys.stdout
-    path = history or bench_db.default_history_path()
-    records = bench_db.load_history(path)
-    if not records:
-        print(f"no bench records found in {path!r} (run `python bench.py <leg>` first)", file=sys.stderr)
-        return 1 if check and not warn_only else 0
-
-    by_leg: Dict[str, List[Dict[str, Any]]] = {}
-    for rec in records:
-        by_leg.setdefault(str(rec["leg"]), []).append(rec)
-    wanted = legs or sorted(by_leg)
-
-    def split(leg_records: List[Dict[str, Any]]) -> Any:
-        # HEAD = the trailing run of the newest sha (or the last --head-runs
-        # records when forced); baseline = everything before it.
-        if head_runs > 0:
-            return leg_records[:-head_runs], leg_records[-head_runs:]
-        head_sha = (leg_records[-1].get("git") or {}).get("sha", "unknown")
-        cut = len(leg_records)
-        while cut > 0 and (leg_records[cut - 1].get("git") or {}).get("sha", "unknown") == head_sha:
-            cut -= 1
-        return leg_records[:cut], leg_records[cut:]
-
-    header = f"{'leg':<24} {'baseline':>14} {'ci':>22} {'head':>14} {'n':>5} {'change':>8}  verdict"
-    out.write(f"== {path} ({len(records)} records) ==\n{header}\n")
-    regressions: List[str] = []
-    for leg in wanted:
-        leg_records = by_leg.get(leg)
-        if not leg_records:
-            out.write(f"{leg:<24} {'-':>14} {'-':>22} {'-':>14} {'-':>5} {'-':>8}  no records\n")
-            continue
-        baseline, head = split(leg_records)
-        verdict = bench_db.compare(baseline, head, threshold=threshold, window=window)
-        if verdict is None:
-            latest = _fmt_value(leg_records[-1]["value"])
-            unit = leg_records[-1].get("unit", "")
-            out.write(
-                f"{leg:<24} {'-':>14} {'-':>22} {latest:>14} {len(leg_records):>5} {'-':>8}"
-                f"  no baseline ({unit})\n"
-            )
-            continue
-        ci_lo, ci_hi = verdict["baseline_ci"]
-        change = verdict["rel_change_worse"]
-        if verdict["regressed"]:
-            word = "REGRESSED"
-            regressions.append(leg)
-        elif verdict["improved"]:
-            word = "improved"
-        else:
-            word = "ok"
-        out.write(
-            f"{leg:<24} {_fmt_value(verdict['baseline_median']):>14} "
-            f"[{_fmt_value(ci_lo)}, {_fmt_value(ci_hi)}]".ljust(24 + 15 + 23)
-            + f"{_fmt_value(verdict['head_median']):>14} {verdict['head_n']:>5} "
-            f"{change * 100:>+7.1f}%  {word} ({verdict['unit']}, {verdict['direction']}-better)\n"
-        )
-    if regressions:
-        msg = f"perf regression in {len(regressions)} leg(s): {', '.join(regressions)}"
-        if check and not warn_only:
-            print(msg, file=sys.stderr)
-            return 1
-        out.write(f"WARNING: {msg}\n")
-    elif check:
-        out.write("perf check: no regressions\n")
-    return 0
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m sheeprl_tpu.telemetry",
@@ -459,14 +371,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     p_flight.add_argument("--show", help="specific dump to detail (default: the newest)")
     p_flight.add_argument("--merge", metavar="OUT", help="write the merged cross-process trace JSON here")
     p_flight.add_argument("--trace", dest="trace_id", help="with --merge: keep only this trace id")
-    p_perf = sub.add_parser("perf", help="bench trend table + statistical regression gate over BENCH_HISTORY.jsonl")
-    p_perf.add_argument("history", nargs="?", help="BENCH_HISTORY.jsonl path (default: $SHEEPRL_BENCH_HISTORY or repo root)")
-    p_perf.add_argument("--leg", action="append", dest="legs", help="restrict to this leg (repeatable)")
-    p_perf.add_argument("--check", action="store_true", help="exit 1 when any leg regressed")
-    p_perf.add_argument("--warn-only", action="store_true", help="with --check: report regressions but exit 0 (noisy runners)")
-    p_perf.add_argument("--threshold", type=float, default=0.10, help="relative worsening that counts as a regression (default 0.10)")
-    p_perf.add_argument("--baseline-window", type=int, default=10, dest="window", help="baseline = last N pre-HEAD records per leg (default 10)")
-    p_perf.add_argument("--head-runs", type=int, default=0, help="force HEAD = last N records instead of the newest-sha split")
     args = parser.parse_args(argv)
     if args.command == "tail":
         return tail(args.logdir, follow=args.follow, interval=args.interval, metrics_url=args.metrics_url)
@@ -474,16 +378,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         return mesh(args.logdir, max_layouts=args.max_layouts)
     if args.command == "flight":
         return flight(args.logdir, merge=args.merge, trace_id=args.trace_id, show=args.show)
-    if args.command == "perf":
-        return perf(
-            args.history,
-            legs=args.legs,
-            check=args.check,
-            warn_only=args.warn_only,
-            threshold=args.threshold,
-            window=args.window,
-            head_runs=args.head_runs,
-        )
     parser.error(f"unknown command {args.command!r}")  # pragma: no cover
     return 2
 

@@ -46,7 +46,6 @@ __all__ = [
     "jit_cost",
     "resolve_peaks",
     "peaks_for_device_kind",
-    "last_published",
     "GAUGE_PREFIX",
 ]
 
@@ -84,26 +83,6 @@ def peaks_for_device_kind(device_kind: str) -> Tuple[float, float]:
         f"device kind {device_kind!r} is not in sheeprl_tpu.telemetry.perf.PEAK_TABLE: add its "
         "datasheet peaks there (or set SHEEPRL_PERF_PEAK_FLOPS and SHEEPRL_PERF_PEAK_BW_GBPS)"
     )
-
-
-# Module-level "most recent publish" readout, mirroring
-# core/interact.last_run_stats(): bench.py embeds the goodput snapshot of a
-# finished run without threading the accountant out of the algorithm main.
-_LAST_LOCK = threading.Lock()
-_LAST_PUBLISHED: Dict[str, float] = {}  # graftlint: guarded-by(_LAST_LOCK)
-
-
-def last_published() -> Dict[str, float]:
-    """Gauges from the most recent :meth:`PerfAccountant.publish` in this
-    process (empty dict when no accountant published yet)."""
-    with _LAST_LOCK:
-        return dict(_LAST_PUBLISHED)
-
-
-def _set_last_published(gauges: Dict[str, float]) -> None:
-    with _LAST_LOCK:
-        _LAST_PUBLISHED.clear()
-        _LAST_PUBLISHED.update(gauges)
 
 
 # ------------------------------------------------------------------ ceilings
@@ -191,8 +170,8 @@ def resolve_peaks(
             source = "table"
         except LookupError as err:
             # A training run goes on without its utilization gauges, but
-            # says which device it could not account for; chip_smoke.py and
-            # bench.py call peaks_for_device_kind themselves and fail.
+            # says which device it could not account for; chip_smoke.py
+            # calls peaks_for_device_kind itself and fails.
             warnings.warn(f"{err}; perf/mfu and perf/hbm_bw_util are not published")
     elif probe:
         with _probe_lock:
@@ -454,8 +433,7 @@ class PerfAccountant:
         """Compute the interval's goodput gauges and push them to the tracer
         (telemetry.jsonl) and metrics registry (/metrics). Call once per log
         interval, AFTER the StepTimer flush trued up the interval's bound
-        time. Returns the gauge dict (also kept in :attr:`last_gauges` and
-        the module-level :func:`last_published`)."""
+        time. Returns the gauge dict (also kept in :attr:`last_gauges`)."""
         if not self.enabled:
             return {}
         self._harvest_pending()
@@ -544,12 +522,11 @@ class PerfAccountant:
             reg = default_registry()
         reg.set_gauges(gauges)
         self.last_gauges = dict(gauges)
-        _set_last_published(gauges)
         return gauges
 
     # ------------------------------------------------------------ snapshots
     def costs(self) -> Dict[str, Dict[str, float]]:
-        """Harvested per-key costs (for bench embedding / tests)."""
+        """Harvested per-key costs."""
         self._harvest_pending()
         with self._lock:
             return {k: dict(v) for k, v in self._costs.items()}

@@ -26,6 +26,9 @@ from __future__ import annotations
 
 import json
 import os
+import platform
+import socket
+import subprocess
 import time
 import warnings
 from typing import Any, Dict, Optional
@@ -41,6 +44,41 @@ from sheeprl_tpu.telemetry.tracer import Tracer
 CHROME_TRACE_FILENAME = "trace.json"
 JSONL_FILENAME = "telemetry.jsonl"
 FLIGHT_DIRNAME = "flight"
+
+
+def git_stamp(root: Optional[str] = None) -> Dict[str, Any]:
+    """``{"sha", "dirty"}`` of the checkout at ``root`` (cwd default); both
+    degrade gracefully (sha ``"unknown"``) outside a git work tree."""
+    cwd = root or os.getcwd()
+    try:
+        sha = subprocess.run(
+            ["git", "rev-parse", "HEAD"],
+            cwd=cwd, capture_output=True, text=True, timeout=10,
+        ).stdout.strip() or "unknown"
+    except Exception:
+        sha = "unknown"
+    dirty = False
+    try:
+        status = subprocess.run(
+            ["git", "status", "--porcelain"],
+            cwd=cwd, capture_output=True, text=True, timeout=10,
+        )
+        dirty = status.returncode == 0 and bool(status.stdout.strip())
+    except Exception:
+        pass
+    return {"sha": sha, "dirty": dirty}
+
+
+def host_fingerprint() -> Dict[str, Any]:
+    """Hardware/host identity coarse enough to be stable across runs on the
+    same box, fine enough to separate baselines from different machines."""
+    return {
+        "hostname": socket.gethostname(),
+        "machine": platform.machine(),
+        "system": platform.system(),
+        "cpu_count": os.cpu_count() or 0,
+        "python": platform.python_version(),
+    }
 
 
 class Telemetry:
@@ -198,8 +236,6 @@ class Telemetry:
         if self._jsonl_path() is not None:
             import jax
 
-            from sheeprl_tpu.telemetry import bench_db
-
             self._append_jsonl(
                 {
                     "type": "meta",
@@ -213,13 +249,10 @@ class Telemetry:
                     "perf_epoch_s": self._tracer.perf_epoch_s,
                     "wall_epoch_s": self._tracer.wall_epoch_s,
                     # Provenance stamps: which code on which hardware produced
-                    # this run — the same identity bench history records carry.
-                    # Stamp the PACKAGE checkout, not the run cwd: runs launch
-                    # from throwaway dirs outside the repo.
-                    "git": bench_db.git_stamp(
-                        os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-                    ),
-                    "host": bench_db.host_fingerprint(),
+                    # this run. Stamp the PACKAGE checkout, not the run cwd:
+                    # runs launch from throwaway dirs outside the repo.
+                    "git": git_stamp(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))),
+                    "host": host_fingerprint(),
                     "device": getattr(jax.devices()[0], "device_kind", ""),
                     "device_count": jax.device_count(),
                     "local_device_count": jax.local_device_count(),

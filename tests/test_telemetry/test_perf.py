@@ -20,7 +20,6 @@ from sheeprl_tpu.telemetry.perf import (
     PEAK_TABLE,
     PerfAccountant,
     jit_cost,
-    last_published,
     peaks_for_device_kind,
     resolve_peaks,
 )
@@ -73,7 +72,7 @@ class TestResolvePeaks:
         assert peaks_for_device_kind("TPU v5 lite") == peaks_for_device_kind("TPU v5e") == (197e12, 819e9)
 
     def test_unknown_accelerator_kind_is_reported_by_name(self):
-        # Strict callers (chip_smoke.py, bench.py) get an error naming the
+        # Strict callers (chip_smoke.py) get an error naming the
         # kind; a training run gets a warning naming it — never silent zeros.
         with pytest.raises(LookupError, match="mystery-9000"):
             peaks_for_device_kind("mystery-9000")
@@ -148,8 +147,6 @@ class TestPerfAccountant:
         assert "perf/mfu" in live.counters()
         # ... and the registry (/metrics path).
         assert reg.gauge("perf/mfu").value == pytest.approx(gauges["perf/mfu"])
-        # ... and the module-level snapshot bench.py embeds.
-        assert last_published()["perf/mfu"] == pytest.approx(gauges["perf/mfu"])
         assert acc.last_gauges == gauges
 
     def test_interval_is_differenced_not_cumulative(self):

@@ -11,6 +11,7 @@ import pytest
 
 from sheeprl_tpu.cli import run
 from sheeprl_tpu.telemetry import Telemetry
+from sheeprl_tpu.telemetry.telemetry import git_stamp
 from sheeprl_tpu.utils.utils import dotdict
 
 pytestmark = pytest.mark.telemetry
@@ -58,6 +59,15 @@ def _check_exports(root):
     assert lines, "telemetry.jsonl is empty"
     kinds = {rec["type"] for rec in lines}
     assert {"meta", "counters", "span"} <= kinds
+    # The meta line's fields are read outside the package (the benchmark's
+    # harness takes perf_epoch_s from it): none may go.
+    meta = lines[0]
+    assert set(meta) >= {
+        "type", "time", "backend", "process_index", "profiler_window", "trace_id", "pid",
+        "perf_epoch_s", "wall_epoch_s", "git", "host", "device", "device_count", "local_device_count",
+    }
+    assert set(meta["git"]) == {"sha", "dirty"}
+    assert set(meta["host"]) == {"hostname", "machine", "system", "cpu_count", "python"}
     final_counters = [rec for rec in lines if rec["type"] == "counters"][-1]["values"]
     assert final_counters.get("compiles", 0) >= 1
     assert final_counters.get("device_get_bytes", 0) > 0
@@ -138,6 +148,11 @@ def test_dreamer_v3_smoke_writes_telemetry(tmp_path):
     assert {"fetch/player_actions", "train/dispatch", "replay/add"} <= {e["name"] for e in inside}
     for child in inside:
         assert sum(i["ts"] <= child["ts"] and child["ts"] + child["dur"] <= i["ts"] + i["dur"] for i in iterations) == 1
+
+
+def test_git_stamp_degrades_outside_a_worktree(tmp_path):
+    stamp = git_stamp(str(tmp_path))
+    assert stamp["sha"] == "unknown"
 
 
 def test_from_config_maps_the_telemetry_group():
