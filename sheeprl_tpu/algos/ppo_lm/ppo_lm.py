@@ -53,7 +53,7 @@ from sheeprl_tpu.utils.utils import polynomial_decay, save_configs
 
 #: What a gradient step reports beside its losses; the loop adds them to the
 #: run's counters when the losses are fetched, never with a sync of their own.
-STEP_COUNTERS = ("moe/routed_slots", "moe/held_slots", "moe/max_expert_tokens", "ppo_lm/loss_tokens",
+STEP_COUNTERS = ("moe/routed_slots", "moe/held_slots", "moe/overflow_chunks", "moe/max_expert_tokens", "ppo_lm/loss_tokens",
                  "ppo_lm/padded_tokens", "ppo_lm/step_tokens")
 
 
@@ -100,6 +100,7 @@ def make_train_step(agent: PPOLMAgent, tx: optax.GradientTransformation, cfg: Di
             "entropy_loss": ent_loss,
             "moe/routed_slots": jnp.sum(stats["routed_slots"]).astype(jnp.float32) if stats else zero,
             "moe/held_slots": jnp.sum(stats["held_slots"]).astype(jnp.float32) if stats else zero,
+            "moe/overflow_chunks": jnp.sum(stats["overflow_chunks"]).astype(jnp.float32) if stats else zero,
             "moe/max_expert_tokens": jnp.max(stats["expert_tokens"]).astype(jnp.float32) if stats else zero,
             "ppo_lm/loss_tokens": jnp.sum(mask),
             "ppo_lm/padded_tokens": positions - real.astype(jnp.float32),

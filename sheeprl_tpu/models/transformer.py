@@ -28,10 +28,14 @@ What `model_type: deepseek_v3` configurations are made of, as published:
   *told which experts it holds* (``experts_held = (first, count)``): it routes
   over all of them and computes the part of the result its own experts give,
   as one rank of an expert-parallel deployment does before the exchange. The
-  slots that chose a held expert are sorted by expert and go through grouped
-  matrix products (``jax.lax.ragged_dot``); there is no capacity and no token
-  is dropped. Dispatch and combine are each other's transposes, so both
-  directions are gathers.
+  slots that chose a held expert are sorted by expert, first in the order,
+  and go through grouped matrix products (``jax.lax.ragged_dot``) a bounded
+  chunk of rows at a time (:func:`routed_experts`): a gather of the chunk's
+  tokens, the products, a scatter-add onto the tokens, so the layer costs by
+  the slots this chip holds and not by all that were routed. The chunk follows
+  from the shape and the experts held (:func:`expert_chunk_rows`); chunks past
+  the first run only while held slots remain, through the same body: there is
+  no capacity and no token is dropped.
 
 Compute dtype and parameter dtype come from the precision policy
 (``bf16-mixed``: float32 parameters, bfloat16 products, float32 softmax, norm
@@ -279,39 +283,107 @@ def route(scores: jax.Array, bias: jax.Array, k: int, normalise: bool, scaling: 
     return chosen, weights * scaling
 
 
-@jax.custom_vjp
-def _dispatch(x: jax.Array, src: jax.Array, rank: jax.Array, held: jax.Array, n_held: jax.Array) -> jax.Array:
-    """Rows of ``x`` [N, D] in sorted-slot order: row m < n_held is the token of
-    the m-th held slot, the rest are zero."""
-    live = jnp.arange(src.shape[0]) < n_held
-    return jnp.where(live[:, None], x[src], 0)
+EXPERT_ROW_TILE = 512  # a chunk of sorted slots is a whole number of these rows
 
 
-@jax.custom_vjp
-def _combine(y: jax.Array, src: jax.Array, rank: jax.Array, held: jax.Array, n_held: jax.Array) -> jax.Array:
-    """Its transpose: token n gets the sum of the rows of ``y`` [M, D] that its held slots stand at."""
-    rows = y[jnp.where(held, rank, 0)]  # [N, K, D]
-    return jnp.sum(jnp.where(held[..., None], rows, 0), axis=1)
+def expert_chunk_rows(slots: int, held: int, routed: int) -> int:
+    """Rows of one chunk of the sorted slots, from what the code can see: twice
+    what an even router sends to ``held`` of ``routed`` experts, in whole tiles;
+    all ``slots`` where that is no fewer (every expert held, a decode step, the
+    micro sizes), and then the one chunk is the whole layer."""
+    rows = -(-2 * slots * held // (routed * EXPERT_ROW_TILE)) * EXPERT_ROW_TILE
+    return min(rows, slots)
 
 
-def _dispatch_fwd(x, src, rank, held, n_held):
-    return _dispatch(x, src, rank, held, n_held), (src, rank, held, n_held)
+def chunk_trips(n_held, rows: int):
+    """Chunks of ``rows`` sorted slots that run: the first always, a later one while held slots remain."""
+    return jnp.maximum(-(-n_held // rows), 1)
 
 
-def _dispatch_bwd(res, g):
-    return (_combine(g, *res), None, None, None, None)
+def _chunks(x, weights, order, sizes, n_held, rows: int):
+    """The sorted slots ``rows`` at a time: the number of chunks, and ``take(j)``
+    -> each row's slot (token x k + choice) and token, whether it chose a held
+    expert, the chunk's part of every group, the tokens' rows (dispatched: the
+    others zero) and the slots' float32 weights. Dispatch and combine, forward
+    and transpose, all index through this."""
+    k = weights.shape[1]
+    count = -(-order.shape[0] // rows)
+    order = jnp.pad(order, (0, count * rows - order.shape[0]))  # the last chunk's tail: never live
+    ends = jnp.cumsum(sizes)
+    starts, flat = ends - sizes, weights.reshape(-1)
+
+    def take(j):
+        base = j * rows
+        slot = jax.lax.dynamic_slice(order, (base,), (rows,))
+        live = base + jnp.arange(rows) < n_held
+        part = jnp.clip(ends - base, 0, rows) - jnp.clip(starts - base, 0, rows)
+        src = slot // k
+        return slot, src, live, part, jnp.where(live[:, None], x[src], 0), jnp.where(live, flat[slot], 0.0)
+
+    return count, take
 
 
-def _combine_fwd(y, src, rank, held, n_held):
-    return _combine(y, src, rank, held, n_held), (src, rank, held, n_held)
+def _chunk_rows(xs, slot_weight, live, w_gate, w_up, w_down, sizes):
+    """The held experts over one chunk's dispatched rows, each scaled by its slot's float32 weight."""
+    grouped = partial(jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=w_gate.dtype)
+    ys = grouped(nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up), w_down)
+    # rows past the last group are not written by the grouped product
+    return jnp.where(live[:, None], ys.astype(jnp.float32) * slot_weight[:, None], 0).astype(w_gate.dtype)
 
 
-def _combine_bwd(res, g):
-    return (_dispatch(g, *res), None, None, None, None)
+def _over_chunks(body, carry, n_held, rows: int, count: int):
+    """``body(j, carry)`` for the chunks that run: the later trips are skipped, not masked."""
+    if count == 1:
+        return body(0, carry)
+    trips = chunk_trips(n_held, rows)
+    return jax.lax.while_loop(lambda c: c[0] < trips, lambda c: (c[0] + 1, body(c[0], c[1])), (jnp.zeros_like(trips), carry))[1]
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-_combine.defvjp(_combine_fwd, _combine_bwd)
+@partial(jax.custom_vjp, nondiff_argnums=(8,))
+def routed_experts(x, w_gate, w_up, w_down, weights, order, sizes, n_held, rows: int):
+    """What the held experts give every token: ``x`` [N, D], ``weights`` [N, k]
+    float32, ``order`` the slots sorted by expert with the ``n_held`` that chose
+    a held expert first, ``sizes`` the held experts' groups. The sorted slots
+    are worked on ``rows`` at a time by one body: a gather of the chunk's
+    tokens, the three grouped products, a scatter-add of the weighted rows
+    (float32 sums, rounded once, as a sum over a token's choices is). No slot
+    is dropped: held slots past the first chunk run the same body again."""
+    count, take = _chunks(x, weights, order, sizes, n_held, rows)
+
+    def chunk(j, out):
+        _, src, live, part, xs, slot_weight = take(j)
+        return out.at[src].add(_chunk_rows(xs, slot_weight, live, w_gate, w_up, w_down, part).astype(out.dtype))
+
+    return _over_chunks(chunk, jnp.zeros(x.shape, jnp.float32), n_held, rows, count).astype(x.dtype)
+
+
+def _routed_experts_fwd(x, w_gate, w_up, w_down, weights, order, sizes, n_held, rows):
+    saved = (x, w_gate, w_up, w_down, weights, order, sizes, n_held)
+    return routed_experts(*saved, rows), saved
+
+
+def _routed_experts_bwd(rows, saved, g):
+    """The transpose, chunk by chunk over the same index: the combine's is one
+    gather of the cotangent's rows, the dispatch's one scatter-add; the chunk's
+    own rows are made again from its gather (nothing a chunk computed is kept)."""
+    x, w_gate, w_up, w_down, weights, order, sizes, n_held = saved
+    count, take = _chunks(x, weights, order, sizes, n_held, rows)
+
+    def chunk(j, carry):
+        dx, d_flat, d_gate, d_up, d_down = carry
+        slot, src, live, part, xs, slot_weight = take(j)
+        _, pull = jax.vjp(lambda xs, sw, a, b, c: _chunk_rows(xs, sw, live, a, b, c, part), xs, slot_weight, w_gate, w_up, w_down)
+        d_xs, d_sw, a, b, c = pull(jnp.where(live[:, None], g[src], 0))
+        dx = dx.at[src].add(jnp.where(live[:, None], d_xs, 0).astype(dx.dtype))
+        d_flat = d_flat.at[slot].add(jnp.where(live, d_sw, 0.0))
+        return dx, d_flat, d_gate + a, d_up + b, d_down + c
+
+    zeros = (jnp.zeros(x.shape, jnp.float32), jnp.zeros(weights.size, weights.dtype), *map(jnp.zeros_like, (w_gate, w_up, w_down)))
+    dx, d_flat, d_gate, d_up, d_down = _over_chunks(chunk, zeros, n_held, rows, count)
+    return dx.astype(x.dtype), d_gate, d_up, d_down, d_flat.reshape(weights.shape), None, None, None
+
+
+routed_experts.defvjp(_routed_experts_fwd, _routed_experts_bwd)
 
 
 class MoE(nn.Module):
@@ -335,19 +407,6 @@ class MoE(nn.Module):
         self.w_down = self.param("w_down", _init(c), (count, width, c.hidden_size), self.param_dtype)
         self.shared = SwiGLU(c, c.moe_intermediate_size * c.n_shared_experts, self.dtype, self.param_dtype)
 
-    def _experts(self, x, weights, order, rank, held, sizes, n_held):
-        """The held experts' part: the sorted slots, of which the first ``n_held`` chose a held expert."""
-        src = order // self.cfg.num_experts_per_tok
-        live = jnp.arange(order.shape[0]) < n_held
-        slot_weight = jnp.where(live, weights.reshape(-1)[order], 0.0)
-        xs = _dispatch(x, src, rank, held, n_held)
-        grouped = partial(jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=self.dtype)
-        hidden = nn.silu(grouped(xs, self.w_gate.astype(self.dtype))) * grouped(xs, self.w_up.astype(self.dtype))
-        ys = grouped(hidden, self.w_down.astype(self.dtype))
-        # rows past the last group are not written by the grouped product
-        ys = jnp.where(live[:, None], ys.astype(jnp.float32) * slot_weight[:, None], 0).astype(self.dtype)
-        return _combine(ys, src, rank, held, n_held)
-
     def __call__(self, x: jax.Array, real: Optional[jax.Array] = None):
         """``real`` (the shape of ``x`` less its last axis) is false at the
         padding of a left-padded batch: a position that belongs to no context
@@ -369,17 +428,16 @@ class MoE(nn.Module):
                 routed_slots = jnp.sum(real).astype(jnp.int32) * k
             group = jnp.where(held, local, count).reshape(-1)  # the other slots sort last
             order = jnp.argsort(group, stable=True)
-            one_hot = (group[:, None] == jnp.arange(count)[None, :]).astype(jnp.int32)
-            sizes = jnp.sum(one_hot, axis=0)
-            offsets = jnp.cumsum(sizes) - sizes
-            within = jnp.cumsum(one_hot, axis=0) - one_hot  # slots of the same expert before this one
-            rank = jnp.sum(one_hot * (within + offsets[None, :]), axis=1).reshape(held.shape)
+            sizes = jnp.sum((group[:, None] == jnp.arange(count)[None, :]).astype(jnp.int32), axis=0)
             n_held = jnp.sum(sizes)
+            rows = expert_chunk_rows(group.shape[0], count, c.n_routed_experts)
         with scopes.scope(scopes.LM_MOE_EXPERTS):
-            routed = self._experts(xn, weights, order, rank, held, sizes, n_held)
+            routed = routed_experts(xn, self.w_gate.astype(self.dtype), self.w_up.astype(self.dtype),
+                                    self.w_down.astype(self.dtype), weights, order, sizes, n_held, rows)
         with scopes.scope(scopes.LM_MOE_SHARED):
             shared = self.shared(xn)
-        stats = {"expert_tokens": sizes, "held_slots": n_held, "routed_slots": routed_slots, "chosen": chosen}
+        stats = {"expert_tokens": sizes, "held_slots": n_held, "routed_slots": routed_slots, "chosen": chosen,
+                 "overflow_chunks": chunk_trips(n_held, rows) - 1}
         return (routed + shared).reshape(shape), stats
 
 

@@ -130,8 +130,8 @@ def test_the_step_counters_reach_telemetry_tail(tmp_path):
     out = io.StringIO()
     assert tail(str(tmp_path / "logs"), out=out) == 0
     text = out.getvalue()
-    for name in ("moe/routed_slots", "moe/held_slots", "moe/max_expert_tokens", "ppo_lm/loss_tokens", "ppo_lm/padded_tokens",
-                 "ppo_lm/step_tokens", "player/cache_tokens"):
+    for name in ("moe/routed_slots", "moe/held_slots", "moe/overflow_chunks", "moe/max_expert_tokens", "ppo_lm/loss_tokens",
+                 "ppo_lm/padded_tokens", "ppo_lm/step_tokens", "player/cache_tokens"):
         assert name in text, name
     values = {line.split()[0]: float(line.split()[1]) for line in text.splitlines() if line.startswith("  ") and len(line.split()) >= 2}
     # 2 epochs x 2 minibatches of 2 sequences x (8 + 3) positions, one expert layer, top 2 of 4, all held;
@@ -141,6 +141,7 @@ def test_the_step_counters_reach_telemetry_tail(tmp_path):
     # (padded_tokens also counts the 2 idle response positions of each of the 8 sequences, which are routed)
     in_context = values["ppo_lm/step_tokens"] - values["ppo_lm/padded_tokens"] + 8 * 2
     assert values["moe/routed_slots"] == values["moe/held_slots"] == in_context * 2
+    assert values["moe/overflow_chunks"] == 0  # every expert held: one chunk is the whole layer, no chunk past it ever runs
     assert values["player/cache_tokens"] == values["ppo_lm/step_tokens"] / 2 - values["ppo_lm/padded_tokens"] / 2
 
 

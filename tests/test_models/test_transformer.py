@@ -5,6 +5,7 @@ expert-parallel deployment, and the router's rules."""
 
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -252,6 +253,132 @@ def test_no_token_is_dropped_when_every_token_chooses_one_expert():
     # and the gradient reaches every token through the experts (dispatch and combine are each other's transposes)
     grad = jax.grad(lambda v: layer.apply({"params": params}, v)[0].sum())(x)
     assert np.isfinite(np.asarray(grad)).all() and (np.abs(np.asarray(grad)).sum(-1) > 0).all()
+
+
+def every_slot_moe(cfg, params, x, real=None, dtype=jnp.float32):
+    """The expert layer as it stood before the chunks, in plain JAX, kept as
+    the reference: every routed slot is dispatched in sorted order (the rows of
+    the others zero), goes through the three grouped products, and a token
+    gathers the rows its held slots stand at, one gather a choice."""
+    first, count = cfg.experts_held
+    k = cfg.num_experts_per_tok
+    xn = T.RMSNorm(cfg.rms_norm_eps).apply({"params": params["norm"]}, x).reshape(-1, x.shape[-1])
+    logits = jnp.dot(xn.astype(jnp.float32), params["router"], precision=jax.lax.Precision.HIGHEST)
+    chosen, weights = T.route(jax.nn.sigmoid(logits), params["router_bias"], k, cfg.norm_topk_prob, cfg.routed_scaling_factor)
+    local = chosen - first
+    held = (local >= 0) & (local < count)
+    if real is not None:
+        held = held & real.reshape(-1, 1)
+    group = jnp.where(held, local, count).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    rank = jnp.argsort(order).reshape(held.shape)  # where each slot stands in the sorted order
+    sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :], axis=0).astype(jnp.int32)
+    live = jnp.arange(order.shape[0]) < jnp.sum(sizes)
+    # gathers on float32 copies: their transposes then sum a token's rows in float32 and round once, as the forward sum does
+    xs = jnp.where(live[:, None], xn.astype(jnp.float32)[order // k], 0).astype(dtype)
+    grouped = partial(jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=dtype)
+    ys = grouped(jax.nn.silu(grouped(xs, params["w_gate"].astype(dtype))) * grouped(xs, params["w_up"].astype(dtype)),
+                 params["w_down"].astype(dtype))
+    slot_weight = jnp.where(live, weights.reshape(-1)[order], 0.0)
+    ys = jnp.where(live[:, None], ys.astype(jnp.float32) * slot_weight[:, None], 0).astype(dtype)
+    routed = jnp.sum(jnp.where(held[..., None], ys.astype(jnp.float32)[jnp.where(held, rank, 0)], 0), axis=1).astype(dtype)
+    shared = T.SwiGLU(cfg, cfg.moe_intermediate_size * cfg.n_shared_experts, dtype).apply({"params": params["shared"]}, xn)
+    return (routed + shared).reshape(x.shape), {"held_slots": jnp.sum(sizes), "expert_tokens": sizes}
+
+
+WIDE = dict(MICRO, n_routed_experts=128)  # 16 of 128 held, as the token cell's chip holds them
+#: name -> (sizes, experts_held, shape of x less its width, first real index of each row or None, experts the bias forces or None)
+CHUNK_CASES = {
+    "few_held": (WIDE, (32, 16), (2, 300), None, None),              # 3600 slots in chunks of 1024: one runs
+    "all_held": (MICRO, None, (2, 50), None, None),                  # one chunk is all 600 slots
+    "left_padding": (WIDE, (0, 16), (2, 300), (180, 0), None),       # `real` keeps the padding from every expert
+    "decode_sized": (WIDE, (0, 16), (16,), None, None),              # 96 slots: one chunk
+    "overflow_forced": (MICRO, (2, 4), (2, 300), None, (2, 3, 4, 5)),  # 2400 held slots against chunks of 2048: a second runs
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+def test_the_chunked_expert_layer_is_the_every_slot_layer(case, dtype):
+    """Value, the gradient of every leaf and of ``x``, and the slots counted:
+    the bounded chunks of sorted slots (one body, later chunks only while held
+    slots remain) give what dispatch and combine over every slot gave. In
+    bfloat16 both round at the same places; a token's rows are summed in
+    float32 in another order, which can move the rounded sum by one place."""
+    sizes, held, lead, starts, forced = CHUNK_CASES[case]
+    cfg = T.TransformerConfig(**sizes, experts_held=held)
+    layer = T.MoE(cfg, dtype, jnp.float32)
+    rng = np.random.default_rng(29)
+    x = jnp.asarray(rng.normal(size=(*lead, sizes["hidden_size"])), dtype)
+    params = layer.init(jax.random.PRNGKey(5), x)["params"]
+    params = jax.tree_util.tree_map(lambda p: jnp.asarray(rng.normal(size=p.shape) * 0.3, jnp.float32), params)
+    if forced is not None:  # every token's choices begin with these experts, all held here
+        params["router_bias"] = jnp.zeros(sizes["n_routed_experts"]).at[jnp.array(forced)].set(100.0)
+    real = None if starts is None else jnp.arange(lead[1])[None, :] >= jnp.asarray(starts)[:, None]
+    weight = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+
+    def through(forward):
+        def loss(p, x):
+            out, stats = forward(p, x)
+            return jnp.sum(out.astype(jnp.float32) * weight), (out, stats)
+        (_, (out, stats)), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(params, x)
+        return out, stats, grads
+
+    out, stats, grads = through(lambda p, x: layer.apply({"params": p}, x, real))
+    want, counted, want_grads = through(lambda p, x: every_slot_moe(cfg, p, x, real, dtype))
+
+    slots = int(np.prod(lead)) * cfg.num_experts_per_tok
+    rows = T.expert_chunk_rows(slots, cfg.experts_held[1], cfg.n_routed_experts)
+    assert int(stats["held_slots"]) == int(counted["held_slots"]) and np.array_equal(stats["expert_tokens"], counted["expert_tokens"])
+    assert int(stats["overflow_chunks"]) == max(-(-int(stats["held_slots"]) // rows) - 1, 0)
+    if case == "overflow_forced":  # nothing dropped: every token's four forced choices are held and computed
+        assert int(stats["held_slots"]) == 4 * 600 > rows and int(stats["overflow_chunks"]) == 1
+    else:
+        assert int(stats["overflow_chunks"]) == 0 and (rows < slots) == (case in ("few_held", "left_padding"))
+    tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+
+    def close(got, ref):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        return np.abs(got - ref).max() <= tol * max(np.abs(ref).max(), 1e-6)
+
+    assert close(out, want)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), ref in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        assert got.shape == ref.shape and got.dtype == ref.dtype and close(got, ref), jax.tree_util.keystr(path)
+    assert np.abs(np.asarray(grads[0]["w_gate"])).max() > 0 and np.abs(np.asarray(grads[1], np.float32)).max() > 0
+
+
+@pytest.mark.parametrize("slots, held, routed, rows", [
+    (4 * 2080 * 6, 16, 128, 12800),    # the token cell's gradient step: about a quarter of its 49 920 slots
+    (16 * 2048 * 6, 16, 128, 49152),   # its prefill: a quarter of 196 608
+    (16 * 6, 16, 128, 96),             # its decode step: one chunk, all 96 slots
+    (8320 * 6, 128, 128, 49920),       # every expert held: one chunk, the whole layer
+    (8320 * 6, 64, 128, 49920),        # half of them held: twice an even share is everything
+    (600 * 6, 4, 16, 2048),            # whole tiles of 512 rows
+])
+def test_the_chunk_of_sorted_slots_follows_from_the_shape_and_the_experts_held(slots, held, routed, rows):
+    assert T.expert_chunk_rows(slots, held, routed) == rows
+
+
+def test_one_expert_body_serves_every_chunk():
+    """The chunk that always runs and the chunks an overflow needs are one
+    traced body: a forward pass holds the three grouped products once (in a
+    loop over chunks whose later trips are skipped), the rematerialised
+    gradient twelve times (the forward, the chunk made again, two transposes
+    of each product), whatever the number of chunks."""
+    import re
+
+    cfg = T.TransformerConfig(**WIDE, experts_held=(0, 16))
+    layer = T.MoE(cfg)
+    x = jnp.zeros((2, 300, MICRO["hidden_size"]))
+    assert -(-x.shape[0] * x.shape[1] * 6 // T.expert_chunk_rows(3600, 16, 128)) == 4
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    products = lambda fn: len(re.findall(r"= ragged_dot\w*\[", str(jax.make_jaxpr(fn)(params, x))))  # noqa: E731
+    forward = lambda p, x: layer.apply({"params": p}, x)[0]  # noqa: E731
+    assert products(forward) == 3
+    assert products(jax.grad(lambda p, x: jax.checkpoint(forward)(p, x).sum(), argnums=(0, 1))) == 12
+    text = str(jax.make_jaxpr(forward)(params, x))
+    assert text.count("while[") == 1 and text.count("scatter-add[") == 1 and "cond[" not in text
 
 
 def test_the_expert_layer_is_told_what_it_holds():

@@ -109,3 +109,39 @@ def test_mla_gradient_step_holds_the_kernels(one_described_chip, monkeypatch):
     text = jax.jit(step).lower(params, x, positions, start).compile().as_text()
     assert text.count("tpu_custom_call") == 2
     assert "mla_attention_fwd" in text and "mla_attention_bwd" in text
+
+
+@pytest.mark.parametrize("shape", sorted(chip_smoke.MLA_SHAPES))
+def test_expert_layer_compiles_with_its_bounded_chunks(shape, one_described_chip):
+    """One expert layer at the token cell's widths (16 of 128 experts held, top
+    6) through the chip's compiler: the gradient with rematerialisation at the
+    update's `[4, 2080]`, the forward at the prefill's `[16, 2048]`. The grouped
+    products are built over a chunk of the sorted slots (about a quarter of
+    them) and nowhere over every slot, inside a loop the held slots bound."""
+    from sheeprl_tpu.models import transformer as T
+
+    batch, seq, grad = chip_smoke.MLA_SHAPES[shape]
+    cfg = T.TransformerConfig(vocab_size=16032, hidden_size=2048, num_hidden_layers=5, num_attention_heads=32, qk_nope_head_dim=128,
+                              qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512, intermediate_size=6144,
+                              moe_intermediate_size=768, n_routed_experts=128, n_shared_experts=2, num_experts_per_tok=6,
+                              routed_scaling_factor=2.448, experts_held=(0, 16))
+    layer = T.MoE(cfg, jnp.bfloat16, jnp.float32)
+    slots = batch * seq * cfg.num_experts_per_tok
+    rows = T.expert_chunk_rows(slots, 16, 128)
+    assert 4 * rows >= slots > 3 * rows and rows % T.EXPERT_ROW_TILE == 0
+
+    def spec(*dims, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_described_chip)
+
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size), jnp.bfloat16)))
+    params = jax.tree_util.tree_map(lambda p: spec(*p.shape, dt=p.dtype), params)
+
+    def forward(params, x, real):
+        return layer.apply(params, x, real)[0]
+
+    def step(params, x, real):
+        return jax.grad(lambda p, x: jax.checkpoint(forward)(p, x, real).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
+
+    text = jax.jit(step if grad else forward).lower(params, spec(batch, seq, cfg.hidden_size), spec(batch, seq, dt=jnp.bool_)).compile().as_text()
+    assert f"bf16[{rows},768]" in text and f"bf16[{slots},768]" not in text and f"bf16[{slots},2048]" not in text
+    assert " while(" in text and "ragged-dot" in text
