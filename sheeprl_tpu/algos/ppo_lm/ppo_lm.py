@@ -1,8 +1,8 @@
 """PPO over tokens: a language model as the policy (``algo=ppo_lm``).
 
 One rollout is one generation batch. Every env is reset and hands over its
-prompt; the prompts go through **prefill** (the whole-sequence attention,
-which fills the per-env latent cache and draws the first response token),
+prompt; the prompts go through **prefill** (the backbone's whole-sequence
+form, which fills the per-env cache and draws the first response token),
 then ``rollout_steps - 1`` **decode** steps follow, one token per env per
 policy step through the cache. An env whose episode has ended reports
 ``active = 0`` and idles; its further steps carry no loss. The update runs the
@@ -18,7 +18,9 @@ step, as DreamerV3's is, with the update owed at the rollout's last step and
 bounded there (one block and one fetch a rollout): a preemption is honoured
 within one decode step (the unfinished rollout is dropped), and an iteration
 lasts as long as an observation waits for its action. The env interaction goes through `InteractionPipeline.interact`; the
-player's state (where `ppo_recurrent`'s LSTM carry rides) is the latent cache.
+player's state (where `ppo_recurrent`'s LSTM carry rides) is the backbone's
+cache: the latent cache of a `deepseek_v3` decoder, or the window rings, shared
+keys and values and recurrent state of a `phi4flash` one (`agent.BACKBONES`).
 """
 
 from __future__ import annotations
@@ -36,7 +38,7 @@ import optax
 
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
 from sheeprl_tpu.algos.ppo.ppo import _current_lr, make_optimizer
-from sheeprl_tpu.algos.ppo_lm.agent import PPOLMAgent, build_agent
+from sheeprl_tpu.algos.ppo_lm.agent import REST, PPOLMAgent, build_agent
 from sheeprl_tpu.algos.ppo_lm.utils import test, token_gae
 from sheeprl_tpu.config.instantiate import instantiate
 from sheeprl_tpu.core.interact import InteractionPipeline
@@ -55,6 +57,8 @@ from sheeprl_tpu.utils.utils import polynomial_decay, save_configs
 #: run's counters when the losses are fetched, never with a sync of their own.
 STEP_COUNTERS = ("moe/routed_slots", "moe/held_slots", "moe/overflow_chunks", "moe/max_expert_tokens", "ppo_lm/loss_tokens",
                  "ppo_lm/padded_tokens", "ppo_lm/step_tokens")
+#: Counted only by a backbone that has the mechanism (chunks x state-space layers of the step's selective scans).
+SCAN_COUNTER = "ssm/scan_chunks"
 
 
 def make_train_step(agent: PPOLMAgent, tx: optax.GradientTransformation, cfg: Dict[str, Any]):
@@ -66,6 +70,7 @@ def make_train_step(agent: PPOLMAgent, tx: optax.GradientTransformation, cfg: Di
     experts every token chose in every expert layer (a diagnostic the loop drops)."""
     vf_coef = float(cfg.algo.vf_coef)
     P, R = agent.prompt_len, agent.rollout_steps
+    scan_chunks = agent.scan_chunks()
 
     def loss_fn(params, batch, clip_coef, ent_coef):
         logits, values, stats = agent.evaluate(params, batch["tokens"], batch["start"])
@@ -106,6 +111,8 @@ def make_train_step(agent: PPOLMAgent, tx: optax.GradientTransformation, cfg: Di
             "ppo_lm/padded_tokens": positions - real.astype(jnp.float32),
             "ppo_lm/step_tokens": jnp.asarray(positions, jnp.float32),
         }
+        if scan_chunks:
+            metrics[SCAN_COUNTER] = jnp.asarray(scan_chunks, jnp.float32)
         routes = stats["chosen"] if stats else jnp.zeros((0, positions, 1), jnp.int32)
         return params, opt_state, metrics, routes
 
@@ -191,9 +198,8 @@ def main(runtime, cfg: Dict[str, Any]):
     prefill_fn = jax.jit(act_prefill, donate_argnums=(1,))
     decode_fn = jax.jit(act_decode, donate_argnums=(1,))
 
-    def split(player_state):
-        cache = {k: player_state[k] for k in ("c", "kr")}
-        return cache, {k: v for k, v in player_state.items() if k not in cache}
+    def split(player_state):  # the backbone's cache leaves (donated), and the rest
+        return ({k: v for k, v in player_state.items() if k not in REST}, {k: player_state[k] for k in REST})
 
     acting_fn = jax.jit(agent.acting_params)
     train_fn = make_train_step(agent, tx, cfg)
@@ -206,7 +212,7 @@ def main(runtime, cfg: Dict[str, Any]):
     order = np.random.default_rng(int(cfg.seed) + rank)  # the minibatches' order, drawn on the host
     pipeline = InteractionPipeline.from_config(cfg)
     if pipeline.slices != 1:
-        raise ValueError("ppo_lm keeps one latent cache for all envs: env.pipeline_slices must be 1")
+        raise ValueError("ppo_lm keeps one cache for all envs: env.pipeline_slices must be 1")
     pipeline.set_key(placement.put(rollout_key))
     with placement.ctx():
         pipeline.init_state(lambda n, _range: agent.init_state(n))
@@ -222,6 +228,7 @@ def main(runtime, cfg: Dict[str, Any]):
     def to_env_actions(host_outputs, n_envs):
         return np.asarray(host_outputs[0]).reshape(n_envs)
 
+    cache_bytes = agent.cache_bytes(num_envs)
     train_timer = telemetry.step_timer("train", timer_key="Time/train_time")
     tracer = tracer_mod.current()
     keep_train_metrics = (aggregator is not None and not aggregator.disabled) or health.enabled or tracer.enabled
@@ -231,6 +238,8 @@ def main(runtime, cfg: Dict[str, Any]):
         for step_metrics in fetched:
             for name in STEP_COUNTERS:
                 tracer.count(name, float(step_metrics[name]))
+            if SCAN_COUNTER in step_metrics:
+                tracer.count(SCAN_COUNTER, float(step_metrics[SCAN_COUNTER]))
 
     shape = (rollout_steps, num_envs)
     tokens = np.zeros(shape, np.int32)
@@ -296,6 +305,8 @@ def main(runtime, cfg: Dict[str, Any]):
             book(fetched)
             health.observe(policy_step, fetched, telemetry=telemetry)
             tracer.set_gauge("player/cache_tokens", float(np.sum(prompt_lens) + np.sum(active)))
+            for kind, size in cache_bytes.items():
+                tracer.set_gauge(f"player/cache_bytes/{kind}", float(size))
             ended = dones.sum(0) > 0
             if aggregator and not aggregator.disabled:
                 for step_metrics in fetched:

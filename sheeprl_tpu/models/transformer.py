@@ -37,6 +37,9 @@ What `model_type: deepseek_v3` configurations are made of, as published:
   the first run only while held slots remain, through the same body: there is
   no capacity and no token is dropped.
 
+The player's state of this family is the latent cache (`Transformer.init_cache`,
+`prefill_cache`, `decode`); `Transformer.cache_kinds` says what kind of state each leaf is.
+
 Compute dtype and parameter dtype come from the precision policy
 (``bf16-mixed``: float32 parameters, bfloat16 products, float32 softmax, norm
 statistics and router). Device scopes (`telemetry/scopes.py`) name the phases.
@@ -58,6 +61,9 @@ from sheeprl_tpu.telemetry import scopes
 Dtype = Any
 MASKED = pallas_mla_attention.MASKED  # a masked score: finite, so a row with no valid key stays finite
 ATTN_BLOCK = 512  # most queries of one block of the plain whole-sequence attention (a short sequence still goes in four)
+#: Prompts that share one block of float32 attention scores where the prefill's softmax runs in plain JAX (all of
+#: them where they do not divide). The fused kernels make no such block: there every prompt goes through at once.
+PREFILL_GROUP = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,6 +108,10 @@ class TransformerConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def backbone(self, dtype: Dtype, param_dtype: Dtype) -> "Transformer":
+        """The decoder of this config (unbound: what it says of the player's state needs no parameters)."""
+        return Transformer(self, dtype, param_dtype)
 
 
 # --------------------------------------------------------------------- pieces
@@ -509,6 +519,36 @@ class Transformer(nn.Module):
             if layer_stats is not None:
                 stats.append(layer_stats)
         return x, kept, stats
+
+    # ------------------------------------------------------------ the player's state (no parameters, no scope of its own)
+    #: cache leaf -> the kind of player state it is (`player/cache_bytes/<kind>`): rows of the whole context
+    cache_kinds = {"c": "full", "kr": "full"}
+
+    @nn.nowrap
+    def scan_chunks(self, seq: int) -> int:
+        """Chunks of a state-space scan over ``seq`` positions: this family has no such layer."""
+        return 0
+
+    @nn.nowrap
+    def prefill_rows(self, num_envs: int, prompt_len: int) -> Optional[int]:
+        """Prompts that go through the whole-sequence form together; None = all of them at once."""
+        at_once = attention_is_fused(self.cfg, prompt_len, self.dtype) or num_envs % PREFILL_GROUP
+        return None if at_once else PREFILL_GROUP
+
+    @nn.nowrap
+    def init_cache(self, num_envs: int, context: int) -> Dict[str, Any]:
+        """The latent cache of ``num_envs`` envs over ``context`` positions, one array a layer."""
+        c = self.cfg
+        rows = lambda width: tuple(jnp.zeros((num_envs, context, width), self.dtype) for _ in range(c.num_hidden_layers))  # noqa: E731
+        return {"c": rows(c.kv_lora_rank), "kr": rows(c.qk_rope_head_dim)}
+
+    @nn.nowrap
+    def prefill_cache(self, cache: Dict[str, Any], kept: list, prompt_len: int, keep) -> Dict[str, Any]:
+        """``cache`` with the first ``prompt_len`` rows filled from what the whole-sequence form kept, for the envs
+        ``keep(new, old)`` takes the new leaf for."""
+        fill = lambda new, old: keep(old.at[:, :prompt_len].set(new.astype(old.dtype)), old)  # noqa: E731
+        return {"c": tuple(fill(c, old) for (c, _), old in zip(kept, cache["c"])),
+                "kr": tuple(fill(kr, old) for (_, kr), old in zip(kept, cache["kr"]))}
 
     def decode(self, tokens: jax.Array, cache: Dict[str, Any], pos: jax.Array, start: jax.Array):
         """``tokens`` [E] at indices ``pos`` [E]; ``cache`` = {"c": L x [E, T, r], "kr": L x [E, T, dr]},

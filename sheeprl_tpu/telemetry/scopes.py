@@ -37,9 +37,16 @@ LM_DENSE_MLP = "lm/dense_mlp"  # the leading dense layers' SwiGLU
 LM_HEAD_LOSS = "lm/head_loss"  # final norm, vocabulary head, value head, the PPO loss
 LM_OPTIM = "lm/optim"  # clipping and the optimizer update
 LM_STEP = (LM_EMBED, LM_MLA, LM_MOE_ROUTE, LM_MOE_EXPERTS, LM_MOE_SHARED, LM_DENSE_MLP, LM_HEAD_LOSS, LM_OPTIM)
+# The same step over a `phi4flash` backbone (models/hybrid_decoder.py): its mixers in place of latent attention and experts
+LM_SSM = "lm/ssm"  # a Mamba layer's mixer: pre-norm, projections, convolution, selective scan, gate
+LM_SWA = "lm/swa"  # pre-norm and differential attention over the sliding window
+LM_FULL_ATTN = "lm/full_attn"  # the same over the whole context (the layer whose keys and values the cross layers read)
+LM_CROSS_ATTN = "lm/cross_attn"  # pre-norm, query and output projections, attention over the full layer's keys and values
+LM_GMU = "lm/gmu"  # a gated memory unit over the memory layer's scan output
+LM_HYBRID_STEP = (LM_EMBED, LM_SSM, LM_SWA, LM_FULL_ATTN, LM_CROSS_ATTN, LM_GMU, LM_DENSE_MLP, LM_HEAD_LOSS, LM_OPTIM)
 # Outside the gradient step: the player's two programs
-LM_ACT_PREFILL = "lm/act_prefill"  # whole prompts through the whole-sequence form, filling the latent cache
-LM_ACT_DECODE = "lm/act_decode"  # one token per env through the absorbed form over the cache
+LM_ACT_PREFILL = "lm/act_prefill"  # whole prompts through the whole-sequence form, filling the player's cache
+LM_ACT_DECODE = "lm/act_decode"  # one token per env through the one-token form over the cache
 
 
 def scope(name: str):

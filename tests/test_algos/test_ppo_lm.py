@@ -168,3 +168,48 @@ def test_ppo_lm_learns_to_copy_the_last_token(monkeypatch):
     (token, _, _), _, _ = answer(agent.acting_params(params), agent.init_state(E), jax.random.PRNGKey(0))
     accuracy = float(np.mean(np.asarray(token) == prompts[:, -1]))
     assert accuracy >= 0.9, f"ppo_lm stopped learning: greedy accuracy {accuracy:.2f} after 8192 policy steps"
+
+
+# ------------------------------------------------------------------ the second backbone (`algo.model.model_type=phi4flash`)
+def hybrid_overrides(**extra):
+    """`exp=ppo_lm_phi4_mini_flash` with the cut's overrides at a toy size: the pipeline stage of layers 14-19 of 32."""
+    small = {"algo.model.hidden_size": 32, "algo.model.num_attention_heads": 4, "algo.model.num_key_value_heads": 2,
+             "algo.model.intermediate_size": 128, "algo.model.sliding_window": 4, "algo.model.d_state": 4, "algo.model.dt_rank": 2,
+             "algo.model.vocab_size": 48, "algo.model.layers_held": "[14,6]", "env.wrapper.max_prompt_len": 10,
+             "env.wrapper.min_prompt_len": 3, "fabric.precision": "32-true"}
+    args = [a for a in overrides() if not a.startswith("exp=")]
+    return ["exp=ppo_lm_phi4_mini_flash"] + args + [f"{k}={v}" for k, v in {**small, **extra}.items()]
+
+
+def test_hybrid_checkpoint_eval_resume_roundtrip(tmp_path):
+    args = [a for a in hybrid_overrides(**{"checkpoint.save_last": True}) if not a.startswith("checkpoint.every")]
+    run(args)
+    ckpts = find_checkpoints(tmp_path / "logs")
+    assert ckpts, "no checkpoint written"
+    evaluation([f"checkpoint_path={ckpts[-1]}", "fabric.accelerator=cpu"])
+    run(hybrid_overrides(**{"checkpoint.resume_from": ckpts[-1], "algo.run_test": False}))
+
+
+@pytest.mark.parametrize("override, error", [("algo.model.model_type=gpt2", "no backbone of ppo_lm"),
+                                             ("algo.model.layers_held=[18,2]", "without layer 17")])
+def test_hybrid_refuses_what_it_cannot_build(override, error):
+    with pytest.raises(ValueError, match=error):
+        run(hybrid_overrides() + [override])
+
+
+def test_the_hybrid_counters_and_gauges_reach_telemetry_tail(tmp_path):
+    """The scan's chunk count rides on the losses' fetch, the three kinds of player state are gauges on the rollout's."""
+    from sheeprl_tpu.telemetry.__main__ import tail
+
+    run(hybrid_overrides(**{"telemetry.enabled": True, "telemetry.flight.enabled": False, "algo.run_test": False}))
+    out = io.StringIO()
+    assert tail(str(tmp_path / "logs"), out=out) == 0
+    text = out.getvalue()
+    values = {line.split()[0]: float(line.split()[1]) for line in text.splitlines() if line.startswith("  ") and len(line.split()) >= 2}
+    # 2 minibatches of 2 sequences x (10 + 3) positions; one chunk a sequence pass, two Mamba layers (14 and 16)
+    assert values["ppo_lm/step_tokens"] == 2 * 2 * 13 and values["ssm/scan_chunks"] == 2 * 2
+    assert values["moe/routed_slots"] == 0  # no expert layer in this backbone
+    # 4 envs, float32: one ring of 4 rows, one shared cache of 13, two Mamba layers' (conv 3 + ssm 4) x 64
+    assert values["player/cache_bytes/window"] == 2 * 4 * 4 * 2 * 8 * 4
+    assert values["player/cache_bytes/full"] == 2 * 4 * 13 * 2 * 8 * 4
+    assert values["player/cache_bytes/state"] == 2 * 4 * (3 + 4) * 64 * 4

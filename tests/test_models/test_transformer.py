@@ -102,8 +102,6 @@ def test_prefill_takes_the_prompts_at_once_only_where_the_kernels_run(agent_and_
     score block grows with the rows that share it: a loop in what is traced)
     and all at once where the rule says the kernels take them; logits, values
     and cache rows are the same either way."""
-    from sheeprl_tpu.algos.ppo_lm import agent as agent_module
-
     agent, params = agent_and_params
     rng = np.random.default_rng(11)
     lengths = rng.integers(2, P + 1, 8)
@@ -114,8 +112,8 @@ def test_prefill_takes_the_prompts_at_once_only_where_the_kernels_run(agent_and_
 
     assert "scan[" in str(jax.make_jaxpr(lambda: prefill())())  # a fresh function each time: traces are cached by it
     (_, _, values), state, _ = prefill()
-    # the agent's side of the rule alone: the layer itself still takes the plain path on the CPU
-    monkeypatch.setattr(agent_module, "attention_is_fused", lambda *a: True)
+    # the prefill's side of the rule alone: the layer itself still takes the plain path on the CPU
+    monkeypatch.setattr(T.Transformer, "prefill_rows", lambda self, num_envs, prompt_len: None)
     assert "scan[" not in str(jax.make_jaxpr(lambda: prefill())())
     (_, _, values_at_once), state_at_once, _ = prefill()
     for got, want in zip(jax.tree_util.tree_leaves((values_at_once, state_at_once)), jax.tree_util.tree_leaves((values, state))):
