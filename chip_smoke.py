@@ -767,6 +767,18 @@ MLA_SHAPES = {
 }
 MLA_HEADS, MLA_ROPE, MLA_LATENT = 32, 64, 512
 
+#: (sequences, positions, window, with its backward) of the whole-sequence
+#: differential attention at the hybrid token policy's benchmark cell (40 query
+#: / 20 key-value heads of 64): the gradient step's minibatch and the player's
+#: prefill of all 8 prompts, a whole-context layer and the window layer.
+DIFF_SHAPES = {
+    "update_full": (2, 4128, None, True),
+    "update_window": (2, 4128, 512, True),
+    "prefill_8_prompts_full": (8, 4096, None, False),
+    "prefill_8_prompts_window": (8, 4096, 512, False),
+}
+DIFF_HEADS, DIFF_KV_HEADS, DIFF_HEAD_DIM = 40, 20, 64
+
 
 def described_v5e():
     """A 2x2 TPU v5e that is described, not attached: the compiler's target."""
@@ -820,6 +832,31 @@ def compile_mla_attention(batch: int, seq: int, grad: bool, dtype: Any, sharding
     )
 
 
+def compile_diff_attention(batch: int, seq: int, window: Optional[int], grad: bool, dtype: Any, sharding: Any):
+    """Compile the fused differential-attention kernels (forward, or forward
+    and backward under `jax.grad`) for the device behind ``sharding``."""
+    import jax
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.models import pallas_diff_attention as kernel
+
+    def spec(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    def attend(q, k, v, lam, start):
+        return kernel.diff_attention(q, k, v, start, lam, window)
+
+    def grads(*args):
+        return jax.grad(lambda *a: attend(*a, args[4]).sum(), argnums=(0, 1, 2, 3))(*args[:4])
+
+    keys = spec(batch, seq, DIFF_KV_HEADS, DIFF_HEAD_DIM)
+    return (
+        jax.jit(grads if grad else attend)
+        .lower(spec(batch, seq, DIFF_HEADS, DIFF_HEAD_DIM), keys, keys, spec(dt=jnp.float32), spec(batch, dt=jnp.int32))
+        .compile()
+    )
+
+
 def aot_rehearsal() -> int:
     """From a sandbox with no chip: do the kernels and the real train step
     compile for the chip? Nothing runs; this is not a chip run."""
@@ -830,7 +867,7 @@ def aot_rehearsal() -> int:
 
     import sheeprl_tpu
     from sheeprl_tpu.config.loader import compose
-    from sheeprl_tpu.models import pallas_gru, pallas_mla_attention
+    from sheeprl_tpu.models import pallas_diff_attention, pallas_gru, pallas_mla_attention
 
     # A compile for a described chip is written to the persistent cache but
     # cannot be read back without one: keep the cache out of it.
@@ -855,6 +892,14 @@ def aot_rehearsal() -> int:
         started = time.perf_counter()
         compile_mla_attention(batch, seq, grad, jnp.bfloat16, one_chip)
         say(f"aot: latent attention {name} [{batch}, {seq}]{' with its backward' if grad else ''}: compiles ({time.perf_counter() - started:.1f} s)")
+    for name, (batch, seq, window, grad) in DIFF_SHAPES.items():
+        reason = pallas_diff_attention.shape_ineligible_reason(seq, DIFF_HEAD_DIM, window, jnp.bfloat16, DIFF_HEADS // DIFF_KV_HEADS)
+        if reason is not None:
+            say(f"aot: differential attention {name} [{batch}, {seq}]: declared ineligible ({reason})")
+            continue
+        started = time.perf_counter()
+        compile_diff_attention(batch, seq, window, grad, jnp.bfloat16, one_chip)
+        say(f"aot: differential attention {name} [{batch}, {seq}]{' with its backward' if grad else ''}: compiles ({time.perf_counter() - started:.1f} s)")
     sheeprl_tpu.register_all()
     for count in (1, 4):
         cfg = compose("config", dv3_overrides(OUT_DIR, "unused", "tpu", FULL, (f"fabric.devices={count}",)))

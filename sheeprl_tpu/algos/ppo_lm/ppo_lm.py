@@ -229,6 +229,7 @@ def main(runtime, cfg: Dict[str, Any]):
         return np.asarray(host_outputs[0]).reshape(n_envs)
 
     cache_bytes = agent.cache_bytes(num_envs)
+    fused_layers = agent.fused_attention_layers()
     train_timer = telemetry.step_timer("train", timer_key="Time/train_time")
     tracer = tracer_mod.current()
     keep_train_metrics = (aggregator is not None and not aggregator.disabled) or health.enabled or tracer.enabled
@@ -307,6 +308,7 @@ def main(runtime, cfg: Dict[str, Any]):
             tracer.set_gauge("player/cache_tokens", float(np.sum(prompt_lens) + np.sum(active)))
             for kind, size in cache_bytes.items():
                 tracer.set_gauge(f"player/cache_bytes/{kind}", float(size))
+            tracer.set_gauge("lm/attention_fused", float(fused_layers))
             ended = dones.sum(0) > 0
             if aggregator and not aggregator.disabled:
                 for step_metrics in fetched:

@@ -37,13 +37,18 @@ Two forms over the same parameters. **Whole sequences** (left-padded: a pad
 position is no key, feeds neither the convolution nor the state): the scan
 works :data:`SCAN_CHUNK` positions at a time under ``jax.checkpoint``, so that
 forward and backward keep one state a chunk and nothing of size ``[B, S,
-d_inner, d_state]``; the attention goes query block by query block, a window
-block reading the keys of its band only, rows :data:`ATTN_ROWS` at a time
-where there are more (the player's prefill). **One token** per env over the
-player's state, three kinds side by side: a ring of ``sliding_window`` rows a
-window layer (slot = index mod window), the ``full`` layer's keys and values
-of the whole context (read by every ``cross`` layer), and ``(conv, ssm)`` a
-Mamba layer; the memory units hold nothing. Plain JAX throughout.
+d_inner, d_state]``; the attention runs as the fused Pallas kernels of
+`pallas_diff_attention` (online softmax, a pair's two softmaxes in one pass,
+forward and backward: no block of scores leaves VMEM) where
+`pallas_diff_attention.ineligible_reason` allows (a TPU, heads of 64, at
+least one tile of positions), and otherwise (the CPU, micro sizes) as
+:func:`blocked_differential`, query block by query block, a window block
+reading the keys of its band only, rows :data:`ATTN_ROWS` at a time where
+there are more. **One token** per env over the player's state, three kinds
+side by side: a ring of ``sliding_window`` rows a window layer (slot = index
+mod window), the ``full`` layer's keys and values of the whole context (read
+by every ``cross`` layer), and ``(conv, ssm)`` a Mamba layer; the memory
+units hold nothing. Everything but the whole-sequence attention is plain JAX.
 """
 
 from __future__ import annotations
@@ -56,12 +61,14 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from sheeprl_tpu.models import pallas_diff_attention
 from sheeprl_tpu.models.transformer import MASKED, SwiGLU
 from sheeprl_tpu.telemetry import scopes
 
 Dtype = Any
 SCAN_CHUNK = 64  # positions of one chunk of the selective scan: what its backward pass keeps states for
 SCAN_UNROLL = 8  # positions of one trip of the chunk's loop
+# the plain path's alone (`blocked_differential`; the fused kernels have their own tile, `pallas_diff_attention.BLOCK`):
 FULL_BLOCK = 384  # most queries of one block of the whole-context attention (its float32 scores are [rows, heads, block, S])
 ATTN_ROWS = 2  # rows that share one block of scores where a batch has more (and divides)
 #: kind of layer -> the cache leaves a layer of that kind holds (the other kinds hold none)
@@ -148,6 +155,10 @@ class HybridConfig:
         if index < self.memory_layer:
             return "swa"
         return "full" if index == self.kv_layer else "cross"
+
+    def window(self, index: int) -> Optional[int]:
+        """The positions an attention layer's query sees back (itself included): ``sliding_window`` of a ``swa`` layer, else None."""
+        return self.sliding_window if self.kind(index) == "swa" else None
 
     def held(self, kind: str) -> Tuple[int, ...]:
         return tuple(i for i in self.layers if self.kind(i) == kind)
@@ -319,11 +330,12 @@ def _differential(q: jax.Array, k: jax.Array, v: jax.Array, valid: jax.Array, la
 
 def blocked_differential(q: jax.Array, k: jax.Array, v: jax.Array, start: jax.Array, lam: jax.Array,
                          window: Optional[int]) -> jax.Array:
-    """The causal differential attention of left-padded whole sequences, query
-    block by query block, each under ``jax.checkpoint``: a block reads the keys
-    up to its end, from its band's beginning on where there is a ``window``
-    (position t sees ``t - window + 1 .. t``), and only the keys from
-    ``start[b]`` on are keys at all."""
+    """The causal differential attention of left-padded whole sequences in plain
+    JAX, query block by query block, each under ``jax.checkpoint``: a block
+    reads the keys up to its end, from its band's beginning on where there is a
+    ``window`` (position t sees ``t - window + 1 .. t``), and only the keys from
+    ``start[b]`` on are keys at all. What runs where `pallas_diff_attention`
+    does not, and what it is held to."""
     seq = q.shape[1]
 
     def block(q, k, v, first, begin, start):  # queries [first, first + len) against the keys [begin, begin + K)
@@ -351,6 +363,14 @@ def blocked_differential(q: jax.Array, k: jax.Array, v: jax.Array, start: jax.Ar
     grouped = lambda t: t.reshape(batch // ATTN_ROWS, ATTN_ROWS, *t.shape[1:])  # noqa: E731
     out = jax.lax.map(lambda args: rows(*args), (grouped(q), grouped(k), grouped(v), grouped(start)))
     return out.reshape(batch, *out.shape[2:])
+
+
+def attention_is_fused(cfg: HybridConfig, seq: int, window: Optional[int], dtype: Dtype) -> bool:
+    """Whether :meth:`DiffAttention.__call__` hands whole sequences of ``seq``
+    positions to the fused kernels where this is traced (`pallas_diff_attention.ineligible_reason`,
+    the whole rule: a TPU and an eligible shape); else :func:`blocked_differential` runs."""
+    group = cfg.num_attention_heads // cfg.num_key_value_heads
+    return pallas_diff_attention.ineligible_reason(seq, cfg.head_dim, window, dtype, group) is None
 
 
 class DiffAttention(nn.Module):
@@ -405,8 +425,10 @@ class DiffAttention(nn.Module):
         q, k, v = self.project(u)
         if self.kind == "cross":
             k, v = shared
-        window = self.cfg.sliding_window if self.kind == "swa" else None
-        return self._out(blocked_differential(q, k, v, start, self._lambda(), window)), (k, v)
+        window = self.cfg.window(self.index)
+        fused = attention_is_fused(self.cfg, u.shape[1], window, self.dtype)
+        attention = pallas_diff_attention.diff_attention if fused else blocked_differential
+        return self._out(attention(q, k, v, start, self._lambda(), window)), (k, v)
 
     def attend(self, q: jax.Array, k: jax.Array, v: jax.Array, valid: jax.Array) -> jax.Array:
         """One query per env, ``q`` [E, heads, d], over cached ``k``, ``v`` [E, K, kv heads, d] with ``valid`` [E, K]."""
@@ -550,9 +572,16 @@ class HybridDecoder(nn.Module):
         return self.cfg.scan_chunks(seq)
 
     @nn.nowrap
+    def fused_attention_layers(self, seq: int) -> int:
+        """Held attention layers whose whole-sequence form over ``seq`` positions runs as fused kernels where this is asked."""
+        c = self.cfg
+        return sum(attention_is_fused(c, seq, c.window(i), self.dtype) for i in c.layers if c.kind(i) in ("swa", "full", "cross"))
+
+    @nn.nowrap
     def prefill_rows(self, num_envs: int, prompt_len: int) -> Optional[int]:
         """Prompts that go through the whole-sequence form together: all of them (None). The scans are one position
-        after another whatever the rows, and the attention makes its blocks of scores :data:`ATTN_ROWS` rows at a time."""
+        after another whatever the rows; the fused attention takes a row a grid step, and the plain path makes its
+        blocks of scores :data:`ATTN_ROWS` rows at a time."""
         return None
 
     @nn.nowrap

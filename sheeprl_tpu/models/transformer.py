@@ -530,6 +530,11 @@ class Transformer(nn.Module):
         return 0
 
     @nn.nowrap
+    def fused_attention_layers(self, seq: int) -> int:
+        """Attention layers whose whole-sequence form over ``seq`` positions runs as fused kernels where this is asked."""
+        return self.cfg.num_hidden_layers * attention_is_fused(self.cfg, seq, self.dtype)
+
+    @nn.nowrap
     def prefill_rows(self, num_envs: int, prompt_len: int) -> Optional[int]:
         """Prompts that go through the whole-sequence form together; None = all of them at once."""
         at_once = attention_is_fused(self.cfg, prompt_len, self.dtype) or num_envs % PREFILL_GROUP

@@ -3,8 +3,8 @@
 The compiler that ships with the installed jaxlib/libtpu compiles for a TPU
 v5e that is described, not attached (on-chip-measurement guide, section 2.3).
 Held here: the fused Pallas LN-GRU cell at the Dreamer sizes and the fused
-latent-attention kernels at the token policy's shapes — "eligible" must imply
-"compiles". Nothing here runs on a device, and nothing here is a chip
+latent-attention and differential-attention kernels at the token policies'
+shapes — "eligible" must imply "compiles". Nothing here runs on a device, and nothing here is a chip
 measurement. (The bound, the warning and the cache placement are in
 tests/test_core/test_tpu_aot.py.)
 """
@@ -22,7 +22,7 @@ sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
 
-from sheeprl_tpu.models import pallas_gru, pallas_mla_attention  # noqa: E402
+from sheeprl_tpu.models import pallas_diff_attention, pallas_gru, pallas_mla_attention  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -109,6 +109,61 @@ def test_mla_gradient_step_holds_the_kernels(one_described_chip, monkeypatch):
     text = jax.jit(step).lower(params, x, positions, start).compile().as_text()
     assert text.count("tpu_custom_call") == 2
     assert "mla_attention_fwd" in text and "mla_attention_bwd" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("shape", sorted(chip_smoke.DIFF_SHAPES))
+def test_diff_attention_eligible_implies_compiles(shape, dtype, one_described_chip):
+    batch, seq, window, grad = chip_smoke.DIFF_SHAPES[shape]
+    group = chip_smoke.DIFF_HEADS // chip_smoke.DIFF_KV_HEADS
+    assert pallas_diff_attention.shape_ineligible_reason(seq, chip_smoke.DIFF_HEAD_DIM, window, dtype, group) is None
+    compiled = chip_smoke.compile_diff_attention(batch, seq, window, grad, dtype, one_described_chip)
+    assert compiled.as_text().count("tpu_custom_call") == (2 if grad else 1)  # forward, and one backward kernel
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_diff_attention_longest_eligible_compiles(dtype, one_described_chip):
+    """The longest sequences the rule admits (its VMEM bound) are what the compiler admits too."""
+    block, group = pallas_diff_attention.BLOCK, chip_smoke.DIFF_HEADS // chip_smoke.DIFF_KV_HEADS
+    reason = lambda seq: pallas_diff_attention.shape_ineligible_reason(seq, chip_smoke.DIFF_HEAD_DIM, None, dtype, group)  # noqa: E731
+    seq = max(n * block for n in range(1, 64) if reason(n * block) is None)
+    assert 4608 <= seq < 63 * block and "VMEM" in reason(seq + block)
+    chip_smoke.compile_diff_attention(1, seq, None, True, dtype, one_described_chip)
+
+
+def test_diff_attention_gradient_step_holds_the_kernels(one_described_chip, monkeypatch):
+    """The gradient of a `full` and a `cross` layer at the cell's widths and its
+    `[2, 4128]` minibatch, compiled for the chip: the layers take the kernels
+    (the rule is asked about the shape alone here: this process's backend is
+    the CPU) and the compiled step holds them, forward and backward, and no
+    block of float32 scores beside them."""
+    from sheeprl_tpu.models import hybrid_decoder as H
+
+    monkeypatch.setattr(pallas_diff_attention, "ineligible_reason", pallas_diff_attention.shape_ineligible_reason)
+    cfg = H.HybridConfig(vocab_size=25008, hidden_size=2560, num_hidden_layers=32, num_attention_heads=40, num_key_value_heads=20,
+                         intermediate_size=10240, sliding_window=512, layers_held=(14, 6))
+    full, cross = (H.DiffAttention(cfg, index, jnp.bfloat16, jnp.float32) for index in (17, 19))
+    batch, seq, _, _ = chip_smoke.DIFF_SHAPES["update_full"]
+
+    def spec(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_described_chip)
+
+    small, none = jnp.zeros((1, 8, cfg.hidden_size), jnp.bfloat16), jnp.zeros((1,), jnp.int32)
+    shared = (jnp.zeros((1, 8, 20, 64), jnp.bfloat16),) * 2
+    params = jax.eval_shape(lambda: (full.init(jax.random.PRNGKey(0), small, none), cross.init(jax.random.PRNGKey(1), small, none, shared)))
+    params = jax.tree_util.tree_map(lambda p: spec(*p.shape, dt=p.dtype), params)
+
+    def step(params, x, start):
+        def loss(params):
+            out, kept = full.apply(params[0], x, start)
+            return (out + cross.apply(params[1], x, start, kept)[0]).astype(jnp.float32).sum()
+
+        return jax.grad(loss)(params)
+
+    text = jax.jit(step).lower(params, spec(batch, seq, cfg.hidden_size), spec(batch, dt=jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert "diff_attention_fwd" in text and "diff_attention_bwd" in text
+    assert "f32[2,40," not in text and "f32[2,10,2,2," not in text  # the plain path's scores, as `_differential` shapes them
 
 
 @pytest.mark.parametrize("shape", sorted(chip_smoke.MLA_SHAPES))
