@@ -779,6 +779,15 @@ DIFF_SHAPES = {
 }
 DIFF_HEADS, DIFF_KV_HEADS, DIFF_HEAD_DIM = 40, 20, 64
 
+#: (sequences, positions, with its backward) of the selective scan at the
+#: hybrid token policy's benchmark cell (inner width 5120, 16 states): the
+#: gradient step's minibatch and the player's prefill of all 8 prompts.
+SCAN_SHAPES = {
+    "update": (2, 4128, True),
+    "prefill_8_prompts": (8, 4096, False),
+}
+SCAN_WIDTH, SCAN_STATE = 5120, 16
+
 
 def described_v5e():
     """A 2x2 TPU v5e that is described, not attached: the compiler's target."""
@@ -857,6 +866,28 @@ def compile_diff_attention(batch: int, seq: int, window: Optional[int], grad: bo
     )
 
 
+def compile_selective_scan(batch: int, seq: int, grad: bool, dtype: Any, sharding: Any, state: int = SCAN_STATE):
+    """Compile the selective-scan kernels (forward, or forward and backward
+    under `jax.grad`) for the device behind ``sharding``."""
+    import jax
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.models import pallas_selective_scan as kernel
+
+    def spec(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    def grads(*args):
+        return jax.grad(lambda *a: kernel.selective_scan(*a)[0].sum(), argnums=(0, 1, 2, 3, 4))(*args)
+
+    rows, narrow = spec(batch, seq, SCAN_WIDTH), spec(batch, seq, state)
+    return (
+        jax.jit(grads if grad else kernel.selective_scan)
+        .lower(rows, spec(batch, seq, SCAN_WIDTH, dt=jnp.float32), spec(state, SCAN_WIDTH, dt=jnp.float32), narrow, narrow)
+        .compile()
+    )
+
+
 def aot_rehearsal() -> int:
     """From a sandbox with no chip: do the kernels and the real train step
     compile for the chip? Nothing runs; this is not a chip run."""
@@ -867,7 +898,7 @@ def aot_rehearsal() -> int:
 
     import sheeprl_tpu
     from sheeprl_tpu.config.loader import compose
-    from sheeprl_tpu.models import pallas_diff_attention, pallas_gru, pallas_mla_attention
+    from sheeprl_tpu.models import pallas_diff_attention, pallas_gru, pallas_mla_attention, pallas_selective_scan
 
     # A compile for a described chip is written to the persistent cache but
     # cannot be read back without one: keep the cache out of it.
@@ -900,6 +931,14 @@ def aot_rehearsal() -> int:
         started = time.perf_counter()
         compile_diff_attention(batch, seq, window, grad, jnp.bfloat16, one_chip)
         say(f"aot: differential attention {name} [{batch}, {seq}]{' with its backward' if grad else ''}: compiles ({time.perf_counter() - started:.1f} s)")
+    for name, (batch, seq, grad) in SCAN_SHAPES.items():
+        reason = pallas_selective_scan.shape_ineligible_reason(batch, seq, SCAN_WIDTH, SCAN_STATE, jnp.bfloat16)
+        if reason is not None:
+            say(f"aot: selective scan {name} [{batch}, {seq}]: declared ineligible ({reason})")
+            continue
+        started = time.perf_counter()
+        compile_selective_scan(batch, seq, grad, jnp.bfloat16, one_chip)
+        say(f"aot: selective scan {name} [{batch}, {seq}]{' with its backward' if grad else ''}: compiles ({time.perf_counter() - started:.1f} s)")
     sheeprl_tpu.register_all()
     for count in (1, 4):
         cfg = compose("config", dv3_overrides(OUT_DIR, "unused", "tpu", FULL, (f"fabric.devices={count}",)))

@@ -39,7 +39,8 @@ from sheeprl_tpu.telemetry import scopes
 HIGHEST = jax.lax.Precision.HIGHEST
 #: ``algo.model.model_type`` -> the backbone's config; its ``backbone(dtype, param_dtype)`` is the decoder, which says
 #: everything of the player's state that the agent needs (``init_cache``, ``prefill_cache``, ``prefill_rows``,
-#: ``decode``, ``cache_kinds``, ``scan_chunks``, ``fused_attention_layers``): nothing below asks which family it has.
+#: ``decode``, ``cache_kinds``, ``scan_chunks``, ``fused_scan_layers``, ``fused_attention_layers``): nothing below asks
+#: which family it has.
 BACKBONES = {"deepseek_v3": TransformerConfig, "phi4flash": HybridConfig}
 #: The player's state beside the backbone's cache: small, not donated, readable after a call.
 REST = ("pos", "start", "logits")
@@ -141,6 +142,10 @@ class PPOLMAgent:
     def scan_chunks(self) -> int:
         """Chunks the state-space scans of one gradient step's sequences work through (0 for a backbone with none)."""
         return self.backbone.scan_chunks(self.context)
+
+    def fused_scan_layers(self) -> int:
+        """State-space layers of a gradient step's sequences whose scan runs as kernels (`ssm/scan_fused`; 0 = the plain path)."""
+        return self.backbone.fused_scan_layers(self.context)
 
     def fused_attention_layers(self) -> int:
         """Attention layers of a gradient step's sequences that run as fused kernels (`lm/attention_fused`; 0 = the plain path)."""

@@ -230,6 +230,7 @@ def main(runtime, cfg: Dict[str, Any]):
 
     cache_bytes = agent.cache_bytes(num_envs)
     fused_layers = agent.fused_attention_layers()
+    fused_scans = agent.fused_scan_layers()
     train_timer = telemetry.step_timer("train", timer_key="Time/train_time")
     tracer = tracer_mod.current()
     keep_train_metrics = (aggregator is not None and not aggregator.disabled) or health.enabled or tracer.enabled
@@ -309,6 +310,7 @@ def main(runtime, cfg: Dict[str, Any]):
             for kind, size in cache_bytes.items():
                 tracer.set_gauge(f"player/cache_bytes/{kind}", float(size))
             tracer.set_gauge("lm/attention_fused", float(fused_layers))
+            tracer.set_gauge("ssm/scan_fused", float(fused_scans))
             ended = dones.sum(0) > 0
             if aggregator and not aggregator.disabled:
                 for step_metrics in fetched:

@@ -35,12 +35,16 @@ and without its source is refused.
 
 Two forms over the same parameters. **Whole sequences** (left-padded: a pad
 position is no key, feeds neither the convolution nor the state): the scan
-works :data:`SCAN_CHUNK` positions at a time under ``jax.checkpoint``, so that
-forward and backward keep one state a chunk and nothing of size ``[B, S,
-d_inner, d_state]``; the attention runs as the fused Pallas kernels of
-`pallas_diff_attention` (online softmax, a pair's two softmaxes in one pass,
-forward and backward: no block of scores leaves VMEM) where
-`pallas_diff_attention.ineligible_reason` allows (a TPU, heads of 64, at
+runs as the Pallas kernels of `pallas_selective_scan` (the state in VMEM
+across a chunk, the backward pass making a chunk's states once) where
+`pallas_selective_scan.ineligible_reason` allows (a TPU, an inner width in
+whole lane blocks), and otherwise (the CPU, micro sizes) as
+:func:`selective_scan`, :data:`SCAN_CHUNK` positions at a time under
+``jax.checkpoint``; either way forward and backward keep one state a chunk
+and nothing of size ``[B, S, d_inner, d_state]``. The attention runs as the
+fused Pallas kernels of `pallas_diff_attention` (online softmax, a pair's two
+softmaxes in one pass, forward and backward: no block of scores leaves VMEM)
+where `pallas_diff_attention.ineligible_reason` allows (a TPU, heads of 64, at
 least one tile of positions), and otherwise (the CPU, micro sizes) as
 :func:`blocked_differential`, query block by query block, a window block
 reading the keys of its band only, rows :data:`ATTN_ROWS` at a time where
@@ -48,7 +52,8 @@ there are more. **One token** per env over the player's state, three kinds
 side by side: a ring of ``sliding_window`` rows a window layer (slot = index
 mod window), the ``full`` layer's keys and values of the whole context (read
 by every ``cross`` layer), and ``(conv, ssm)`` a Mamba layer; the memory
-units hold nothing. Everything but the whole-sequence attention is plain JAX.
+units hold nothing. Everything but the whole-sequence attention and scan is
+plain JAX.
 """
 
 from __future__ import annotations
@@ -61,11 +66,12 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from sheeprl_tpu.models import pallas_diff_attention
+from sheeprl_tpu.models import pallas_diff_attention, pallas_selective_scan
 from sheeprl_tpu.models.transformer import MASKED, SwiGLU
 from sheeprl_tpu.telemetry import scopes
 
 Dtype = Any
+# the plain path's alone (`selective_scan`; the kernels have their own, `pallas_selective_scan.CHUNK`):
 SCAN_CHUNK = 64  # positions of one chunk of the selective scan: what its backward pass keeps states for
 SCAN_UNROLL = 8  # positions of one trip of the chunk's loop
 # the plain path's alone (`blocked_differential`; the fused kernels have their own tile, `pallas_diff_attention.BLOCK`):
@@ -166,9 +172,10 @@ class HybridConfig:
     def lambda_init(self, index: int) -> float:
         return 0.8 - 0.6 * math.exp(-0.3 * index)
 
-    def scan_chunks(self, seq: int) -> int:
-        """Chunks the selective scans of one whole-sequence pass over ``seq`` positions work through, all held layers."""
-        return len(self.held("ssm")) * -(-seq // min(SCAN_CHUNK, seq))
+    def scan_chunks(self, seq: int, chunk: int = SCAN_CHUNK) -> int:
+        """Chunks the selective scans of one whole-sequence pass over ``seq`` positions work through, all held layers,
+        at ``chunk`` positions a chunk."""
+        return len(self.held("ssm")) * -(-seq // min(chunk, seq))
 
     def backbone(self, dtype: Dtype, param_dtype: Dtype) -> "HybridDecoder":
         """The decoder of this config (unbound: what it says of the player's state needs no parameters)."""
@@ -241,6 +248,13 @@ def selective_scan(x: jax.Array, delta: jax.Array, a: jax.Array, b: jax.Array, c
     return jnp.swapaxes(ys.reshape(count * size, batch, width)[:seq], 0, 1), state
 
 
+def scan_is_fused(cfg: HybridConfig, batch: int, seq: int, dtype: Dtype) -> bool:
+    """Whether :meth:`Mamba.__call__` hands ``batch`` whole sequences of ``seq`` positions to the kernels where this is
+    traced (`pallas_selective_scan.ineligible_reason`, the whole rule: a TPU and an eligible shape); else
+    :func:`selective_scan` runs."""
+    return pallas_selective_scan.ineligible_reason(batch, seq, cfg.d_inner, cfg.d_state, dtype) is None
+
+
 def _dt_bias_init(key, shape, dtype=jnp.float32):
     """The family's: ``Delta`` log-uniform on 1e-3..1e-1 at initialisation, through softplus's inverse."""
     dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32) * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
@@ -295,7 +309,8 @@ class Mamba(nn.Module):
         x = sum(x[:, k:k + seq] * conv_w[k] for k in range(taps)) + self.conv_b.astype(self.dtype)
         x = jnp.where(real, nn.silu(x), 0)
         delta, a, b, cc = self._inputs(x)
-        y, state = selective_scan(x, delta, a, b, cc)
+        scan = pallas_selective_scan.selective_scan if scan_is_fused(c, *u.shape[:2], self.dtype) else selective_scan
+        y, state = scan(x, delta, a, b, cc)
         out, memory = self._gate(y, x, z)
         return out, memory, (tail, state)
 
@@ -569,7 +584,14 @@ class HybridDecoder(nn.Module):
 
     @nn.nowrap
     def scan_chunks(self, seq: int) -> int:
-        return self.cfg.scan_chunks(seq)
+        """Chunks the scans of one whole-sequence pass work through, at the chunk of the path that runs where this is asked."""
+        fused = scan_is_fused(self.cfg, 1, seq, self.dtype)
+        return self.cfg.scan_chunks(seq, pallas_selective_scan.CHUNK if fused else SCAN_CHUNK)
+
+    @nn.nowrap
+    def fused_scan_layers(self, seq: int) -> int:
+        """Held Mamba layers whose whole-sequence scan over ``seq`` positions runs as kernels where this is asked."""
+        return len(self.cfg.held("ssm")) * scan_is_fused(self.cfg, 1, seq, self.dtype)
 
     @nn.nowrap
     def fused_attention_layers(self, seq: int) -> int:

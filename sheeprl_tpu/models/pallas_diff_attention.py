@@ -103,11 +103,16 @@ def _vmem_bytes(seq: int, itemsize: int, group: int) -> int:
     return 2 * 3 * whole * itemsize + whole * 4 + rows + 2 * 4 * tile * itemsize + 8 * tile * 4 + 8 * BLOCK * BLOCK * 4
 
 
+def traced_backend() -> str:
+    """Where what is traced now will run: the `jax.default_device` in force (a player acting from the host), else the
+    default backend."""
+    device = jax.config.jax_default_device
+    return getattr(device, "platform", device) or jax.default_backend()
+
+
 def ineligible_reason(seq: int, head_dim: int, window: Optional[int], dtype, group: int = 2) -> Optional[str]:
     """Why the kernels cannot take whole sequences of this shape here, or None when they can."""
-    # where what is traced now will run: the `jax.default_device` in force (a player acting from the host), else the default backend
-    device = jax.config.jax_default_device
-    backend = getattr(device, "platform", device) or jax.default_backend()
+    backend = traced_backend()
     if backend != "tpu":
         return f"the backend is {backend}, not a TPU"
     return shape_ineligible_reason(seq, head_dim, window, dtype, group)

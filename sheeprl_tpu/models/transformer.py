@@ -530,6 +530,11 @@ class Transformer(nn.Module):
         return 0
 
     @nn.nowrap
+    def fused_scan_layers(self, seq: int) -> int:
+        """State-space layers whose whole-sequence scan runs as kernels: this family has none."""
+        return 0
+
+    @nn.nowrap
     def fused_attention_layers(self, seq: int) -> int:
         """Attention layers whose whole-sequence form over ``seq`` positions runs as fused kernels where this is asked."""
         return self.cfg.num_hidden_layers * attention_is_fused(self.cfg, seq, self.dtype)

@@ -144,6 +144,7 @@ def test_the_step_counters_reach_telemetry_tail(tmp_path):
     assert values["moe/overflow_chunks"] == 0  # every expert held: one chunk is the whole layer, no chunk past it ever runs
     assert values["player/cache_tokens"] == values["ppo_lm/step_tokens"] / 2 - values["ppo_lm/padded_tokens"] / 2
     assert values["lm/attention_fused"] == 0  # off the TPU the step holds no attention kernel: the plain path
+    assert values["ssm/scan_fused"] == 0  # this family has no state-space layer
 
 
 def test_ppo_lm_learns_to_copy_the_last_token(monkeypatch):
@@ -215,3 +216,4 @@ def test_the_hybrid_counters_and_gauges_reach_telemetry_tail(tmp_path):
     assert values["player/cache_bytes/full"] == 2 * 4 * 13 * 2 * 8 * 4
     assert values["player/cache_bytes/state"] == 2 * 4 * (3 + 4) * 64 * 4
     assert values["lm/attention_fused"] == 0  # off the TPU (and at heads of 8) every attention layer takes the plain path
+    assert values["ssm/scan_fused"] == 0  # off the TPU both Mamba layers scan on the plain path

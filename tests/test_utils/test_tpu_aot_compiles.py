@@ -2,9 +2,9 @@
 
 The compiler that ships with the installed jaxlib/libtpu compiles for a TPU
 v5e that is described, not attached (on-chip-measurement guide, section 2.3).
-Held here: the fused Pallas LN-GRU cell at the Dreamer sizes and the fused
-latent-attention and differential-attention kernels at the token policies'
-shapes — "eligible" must imply "compiles". Nothing here runs on a device, and nothing here is a chip
+Held here: the fused Pallas LN-GRU cell at the Dreamer sizes, the fused
+latent-attention and differential-attention kernels and the selective-scan
+kernels at the token policies' shapes — "eligible" must imply "compiles". Nothing here runs on a device, and nothing here is a chip
 measurement. (The bound, the warning and the cache placement are in
 tests/test_core/test_tpu_aot.py.)
 """
@@ -22,7 +22,7 @@ sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
 
-from sheeprl_tpu.models import pallas_diff_attention, pallas_gru, pallas_mla_attention  # noqa: E402
+from sheeprl_tpu.models import pallas_diff_attention, pallas_gru, pallas_mla_attention, pallas_selective_scan  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -164,6 +164,62 @@ def test_diff_attention_gradient_step_holds_the_kernels(one_described_chip, monk
     assert text.count("tpu_custom_call") == 4
     assert "diff_attention_fwd" in text and "diff_attention_bwd" in text
     assert "f32[2,40," not in text and "f32[2,10,2,2," not in text  # the plain path's scores, as `_differential` shapes them
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("shape", sorted(chip_smoke.SCAN_SHAPES))
+def test_selective_scan_eligible_implies_compiles(shape, dtype, one_described_chip):
+    batch, seq, grad = chip_smoke.SCAN_SHAPES[shape]
+    assert pallas_selective_scan.shape_ineligible_reason(batch, seq, chip_smoke.SCAN_WIDTH, chip_smoke.SCAN_STATE, dtype) is None
+    compiled = chip_smoke.compile_selective_scan(batch, seq, grad, dtype, one_described_chip)
+    assert compiled.as_text().count("tpu_custom_call") == (2 if grad else 1)  # forward, and one backward kernel
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_selective_scan_most_states_eligible_compiles(dtype, one_described_chip):
+    """The most states a lane the rule admits (its VMEM bound) are what the compiler admits too."""
+    reason = lambda state: pallas_selective_scan.shape_ineligible_reason(1, 1024, chip_smoke.SCAN_WIDTH, state, dtype)  # noqa: E731
+    state = max(n for n in range(8, 1024, 8) if reason(n) is None)
+    assert chip_smoke.SCAN_STATE < state < 1016 and "VMEM" in reason(state + 8)
+    chip_smoke.compile_selective_scan(1, 1024, True, dtype, one_described_chip, state=state)
+
+
+def test_mamba_gradient_step_holds_the_scan_kernels(one_described_chip, monkeypatch):
+    """The gradient of a Mamba layer at the cell's widths and its `[2, 4128]`
+    minibatch, rematerialised as the decoder does it, compiled for the chip:
+    the layer takes the kernels (the rule is asked about the shape alone here:
+    this process's backend is the CPU), the compiled step holds them under the
+    layer's scope, forward and backward, and no loop over positions beside them."""
+    from sheeprl_tpu.models import hybrid_decoder as H
+    from sheeprl_tpu.telemetry import scopes
+
+    monkeypatch.setattr(pallas_selective_scan, "ineligible_reason", pallas_selective_scan.shape_ineligible_reason)
+    cfg = H.HybridConfig(vocab_size=25008, hidden_size=2560, num_hidden_layers=32, num_attention_heads=40, num_key_value_heads=20,
+                         intermediate_size=10240, sliding_window=512, layers_held=(14, 6))
+    assert (cfg.d_inner, cfg.d_state) == (chip_smoke.SCAN_WIDTH, chip_smoke.SCAN_STATE)
+    layer = H.Mamba(cfg, jnp.bfloat16, jnp.float32)
+    batch, seq, _ = chip_smoke.SCAN_SHAPES["update"]
+
+    def spec(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_described_chip)
+
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size), jnp.bfloat16), jnp.zeros((1,), jnp.int32)))
+    params = jax.tree_util.tree_map(lambda p: spec(*p.shape, dt=p.dtype), params)
+
+    def forward(params, x, start):
+        with scopes.scope(scopes.LM_SSM):
+            return layer.apply(params, x, start)[0]
+
+    def step(params, x, start):
+        return jax.value_and_grad(lambda p: jax.checkpoint(forward)(p, x, start).astype(jnp.float32).sum())(params)
+
+    text = jax.jit(step).lower(params, spec(batch, seq, cfg.hidden_size), spec(batch, dt=jnp.int32)).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 3  # the forward, the rematerialised forward and the backward
+    assert sum("selective_scan_fwd" in line for line in calls) == 2 and sum("selective_scan_bwd" in line for line in calls) == 1
+    assert all(scopes.LM_SSM in line for line in calls)
+    assert any("transpose(jvp(" in line and "selective_scan_bwd" in line for line in calls)
+    assert " while(" not in text  # the plain path's loops over chunks and positions
 
 
 @pytest.mark.parametrize("shape", sorted(chip_smoke.MLA_SHAPES))
