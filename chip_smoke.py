@@ -400,7 +400,7 @@ def phase_trainer(out_dir: str, accelerator: str, size: Dict[str, Any]) -> str:
     bounds = span_list(records, "train/bound")
     player = span_list(records, "interaction/dispatch/slice0")
     fetch = span_list(records, "fetch/player_actions")
-    compiles = span_list(records, "xla_compile")
+    compiles = span_list(records, "compile/backend")
     first_calls = f"phase A: train step first call {train[0][1]:.1f} s"
     if len(train) > 1:
         first_calls += f", second call {train[1][1]:.1f} s (the donated-layout recompile)"

@@ -610,7 +610,8 @@ def main(runtime, cfg: Dict[str, Any]):
     watchdog = runtime.resilience.watchdog
     health = runtime.health
 
-    envs = make_vector_env(cfg, rank, log_dir, restart_on_exception=True)
+    with telemetry.span("setup/envs", "setup"):
+        envs = make_vector_env(cfg, rank, log_dir, restart_on_exception=True)
     action_space = envs.single_action_space
     observation_space = envs.single_observation_space
 
@@ -641,41 +642,42 @@ def main(runtime, cfg: Dict[str, Any]):
     obs_keys = list(cfg.algo.cnn_keys.encoder) + list(cfg.algo.mlp_keys.encoder)
 
     # Eager flax/optax init runs host-side (each eager dispatch pays a host-device round trip); shard_params then moves the finished trees to the mesh.
-    with runtime.host_init():
-        agent, agent_state = build_agent(
-            runtime,
-            actions_dim,
-            is_continuous,
-            cfg,
-            observation_space,
-            state_ckpt["world_model"] if state_ckpt is not None else None,
-            state_ckpt["actor"] if state_ckpt is not None else None,
-            state_ckpt["critic"] if state_ckpt is not None else None,
-            state_ckpt["target_critic"] if state_ckpt is not None else None,
-        )
+    with telemetry.span("setup/agent", "setup"):
+        with runtime.host_init():
+            agent, agent_state = build_agent(
+                runtime,
+                actions_dim,
+                is_continuous,
+                cfg,
+                observation_space,
+                state_ckpt["world_model"] if state_ckpt is not None else None,
+                state_ckpt["actor"] if state_ckpt is not None else None,
+                state_ckpt["critic"] if state_ckpt is not None else None,
+                state_ckpt["target_critic"] if state_ckpt is not None else None,
+            )
 
-        txs = {
-            "world_model": _make_optimizer(cfg.algo.world_model.optimizer, cfg.algo.world_model.clip_gradients),
-            "actor": _make_optimizer(cfg.algo.actor.optimizer, cfg.algo.actor.clip_gradients),
-            "critic": _make_optimizer(cfg.algo.critic.optimizer, cfg.algo.critic.clip_gradients),
-        }
-        opt_states = {
-            "world_model": txs["world_model"].init(agent_state["world_model"]),
-            "actor": txs["actor"].init(agent_state["actor"]),
-            "critic": txs["critic"].init(agent_state["critic"]),
-        }
-        if state_ckpt is not None:
-            for name, ckpt_key in (
-                ("world_model", "world_optimizer"),
-                ("actor", "actor_optimizer"),
-                ("critic", "critic_optimizer"),
-            ):
-                opt_states[name] = restore_opt_state(opt_states[name], state_ckpt[ckpt_key])
+            txs = {
+                "world_model": _make_optimizer(cfg.algo.world_model.optimizer, cfg.algo.world_model.clip_gradients),
+                "actor": _make_optimizer(cfg.algo.actor.optimizer, cfg.algo.actor.clip_gradients),
+                "critic": _make_optimizer(cfg.algo.critic.optimizer, cfg.algo.critic.clip_gradients),
+            }
+            opt_states = {
+                "world_model": txs["world_model"].init(agent_state["world_model"]),
+                "actor": txs["actor"].init(agent_state["actor"]),
+                "critic": txs["critic"].init(agent_state["critic"]),
+            }
+            if state_ckpt is not None:
+                for name, ckpt_key in (
+                    ("world_model", "world_optimizer"),
+                    ("actor", "actor_optimizer"),
+                    ("critic", "critic_optimizer"),
+                ):
+                    opt_states[name] = restore_opt_state(opt_states[name], state_ckpt[ckpt_key])
 
-        # Explicit mesh placement: replicated, or tensor-parallel over the model
-        # axis for the wide dense stacks when fabric.model_axis > 1.
-    agent_state = runtime.shard_params(agent_state)
-    opt_states = runtime.shard_params(opt_states)
+            # Explicit mesh placement: replicated, or tensor-parallel over the model
+            # axis for the wide dense stacks when fabric.model_axis > 1.
+        agent_state = runtime.shard_params(agent_state)
+        opt_states = runtime.shard_params(opt_states)
 
     # Arm per-shard goodput accounting: the observatory needs the mesh and the
     # realised param layouts to attribute MFU/imbalance per data-shard.
@@ -692,17 +694,6 @@ def main(runtime, cfg: Dict[str, Any]):
     aggregator = None
     if not MetricAggregator.disabled:
         aggregator: MetricAggregator = instantiate(cfg.metric.aggregator)
-
-    buffer_size = cfg.buffer.size // int(cfg.env.num_envs * world_size) if not cfg.dry_run else 2
-    rb = EnvIndependentReplayBuffer(
-        buffer_size,
-        n_envs=cfg.env.num_envs,
-        memmap=cfg.buffer.memmap,
-        memmap_dir=os.path.join(log_dir, "memmap_buffer", f"rank_{rank}"),
-        buffer_cls=SequentialReplayBuffer,
-    )
-    if state_ckpt is not None and cfg.buffer.checkpoint and state_ckpt.get("rb") is not None:
-        rb = state_ckpt["rb"]
 
     train_step_count = 0
     last_train = 0
@@ -738,52 +729,64 @@ def main(runtime, cfg: Dict[str, Any]):
 
     train_fn = make_train_step(agent, txs, cfg, mesh, state=agent_state, opt_states=opt_states)
 
-    # Device-resident replay ring (data/device_buffer.py): rollout rows are
-    # mirrored into HBM and the fused train step samples them inside its own
-    # jit — zero per-gradient-step host transfers. The host buffer stays
-    # authoritative (checkpointing, fallback when the ring won't fit HBM).
-    use_device_buffer = bool(cfg.buffer.get("device", False))
-    fused_train_steps = max(int(cfg.algo.get("fused_train_steps", 1)), 1)
-    ring = None
-    fused_train_fn = None
-    if use_device_buffer:
-        ring = DeviceReplayRing(
+    with telemetry.span("setup/replay", "setup"):
+        buffer_size = cfg.buffer.size // int(cfg.env.num_envs * world_size) if not cfg.dry_run else 2
+        rb = EnvIndependentReplayBuffer(
             buffer_size,
-            cfg.env.num_envs,
-            cnn_keys=tuple(cfg.algo.cnn_keys.encoder),
-            obs_keys=tuple(obs_keys),
-            hbm_fraction=float(cfg.buffer.get("device_hbm_fraction", 0.4)),
-            device=mesh.devices.flat[0],
-            mesh=mesh,
+            n_envs=cfg.env.num_envs,
+            memmap=cfg.buffer.memmap,
+            memmap_dir=os.path.join(log_dir, "memmap_buffer", f"rank_{rank}"),
+            buffer_cls=SequentialReplayBuffer,
         )
         if state_ckpt is not None and cfg.buffer.checkpoint and state_ckpt.get("rb") is not None:
-            ring.load_host_buffer(rb)
-        ring_sample_fn = ring.make_sample_fn(
-            cfg.algo.per_rank_batch_size,
-            sequence_length=cfg.algo.per_rank_sequence_length,
-            time_major=True,
-        )
-        fused_train_fn = make_fused_train_step(
-            agent,
-            txs,
-            cfg,
-            mesh,
-            ring_sample_fn,
-            state=agent_state,
-            opt_states=opt_states,
-            ring_shardings=ring.state_shardings(),
-        )
+            rb = state_ckpt["rb"]
 
-    # Async infeed (data/infeed.py): the next train call's sampled batches
-    # are copied host->device by a worker thread while envs step, so the
-    # pixel-batch H2D never sits on the critical path.
-    infeed = ReplayInfeed(
-        rb,
-        cfg.algo.per_rank_batch_size,
-        cfg.algo.per_rank_sequence_length,
-        cfg.algo.cnn_keys.encoder,
-        enabled=cfg.buffer.get("prefetch", True),
-    )
+        # Device-resident replay ring (data/device_buffer.py): rollout rows are
+        # mirrored into HBM and the fused train step samples them inside its own
+        # jit — zero per-gradient-step host transfers. The host buffer stays
+        # authoritative (checkpointing, fallback when the ring won't fit HBM).
+        use_device_buffer = bool(cfg.buffer.get("device", False))
+        fused_train_steps = max(int(cfg.algo.get("fused_train_steps", 1)), 1)
+        ring = None
+        fused_train_fn = None
+        if use_device_buffer:
+            ring = DeviceReplayRing(
+                buffer_size,
+                cfg.env.num_envs,
+                cnn_keys=tuple(cfg.algo.cnn_keys.encoder),
+                obs_keys=tuple(obs_keys),
+                hbm_fraction=float(cfg.buffer.get("device_hbm_fraction", 0.4)),
+                device=mesh.devices.flat[0],
+                mesh=mesh,
+            )
+            if state_ckpt is not None and cfg.buffer.checkpoint and state_ckpt.get("rb") is not None:
+                ring.load_host_buffer(rb)
+            ring_sample_fn = ring.make_sample_fn(
+                cfg.algo.per_rank_batch_size,
+                sequence_length=cfg.algo.per_rank_sequence_length,
+                time_major=True,
+            )
+            fused_train_fn = make_fused_train_step(
+                agent,
+                txs,
+                cfg,
+                mesh,
+                ring_sample_fn,
+                state=agent_state,
+                opt_states=opt_states,
+                ring_shardings=ring.state_shardings(),
+            )
+
+        # Async infeed (data/infeed.py): the next train call's sampled batches
+        # are copied host->device by a worker thread while envs step, so the
+        # pixel-batch H2D never sits on the critical path.
+        infeed = ReplayInfeed(
+            rb,
+            cfg.algo.per_rank_batch_size,
+            cfg.algo.per_rank_sequence_length,
+            cfg.algo.cnn_keys.encoder,
+            enabled=cfg.buffer.get("prefetch", True),
+        )
 
     player_cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
 
@@ -804,22 +807,25 @@ def main(runtime, cfg: Dict[str, Any]):
     # posterior->actor per-step forward runs where dispatch is cheapest; the
     # mirror refreshes world-model+actor after every train call. Off-policy:
     # honors fabric.player_sync=async.
-    placement = PlayerPlacement.resolve(
-        cfg, mesh.devices.flat[0],
-        params={"world_model": agent_state["world_model"], "actor": agent_state["actor"]},
-    )
-    placement.push({"world_model": agent_state["world_model"], "actor": agent_state["actor"]})
+    with telemetry.span("setup/player", "setup"):
+        placement = PlayerPlacement.resolve(
+            cfg, mesh.devices.flat[0],
+            params={"world_model": agent_state["world_model"], "actor": agent_state["actor"]},
+        )
+        placement.push({"world_model": agent_state["world_model"], "actor": agent_state["actor"]})
 
-    rollout_key, train_key = jax.random.split(jax.random.fold_in(runtime.root_key, rank))
-    rollout_key = placement.put(rollout_key)
+        rollout_key, train_key = jax.random.split(jax.random.fold_in(runtime.root_key, rank))
+        rollout_key = placement.put(rollout_key)
 
-    # Pipelined interaction (core/interact.py): per-slice policy dispatch +
-    # async action fetch + double-buffered obs staging, with the recurrent
-    # player latents and the rollout PRNG key held per slice. slices=1/async
-    # off is bit-identical to the serial loop.
-    pipeline = InteractionPipeline.from_config(cfg)
-    pipeline.watchdog = watchdog
-    pipeline.set_key(rollout_key)
+        # Pipelined interaction (core/interact.py): per-slice policy dispatch +
+        # async action fetch + double-buffered obs staging, with the recurrent
+        # player latents and the rollout PRNG key held per slice. slices=1/async
+        # off is bit-identical to the serial loop.
+        pipeline = InteractionPipeline.from_config(cfg)
+        pipeline.watchdog = watchdog
+        pipeline.set_key(rollout_key)
+        with placement.ctx():
+            pipeline.init_state(lambda n, _rng: init_player_fn(placement.params()["world_model"], n))
     single_action_shape = envs.single_action_space.shape
     player_cnn_cfg_keys = cfg.algo.cnn_keys.encoder
 
@@ -848,8 +854,6 @@ def main(runtime, cfg: Dict[str, Any]):
     step_data["truncated"] = np.zeros((1, cfg.env.num_envs, 1), np.float32)
     step_data["terminated"] = np.zeros((1, cfg.env.num_envs, 1), np.float32)
     step_data["is_first"] = np.ones_like(step_data["terminated"])
-    with placement.ctx():
-        pipeline.init_state(lambda n, _rng: init_player_fn(placement.params()["world_model"], n))
 
     cumulative_per_rank_gradient_steps = 0
     # Bound async in-flight train dispatches (core/runtime.py: an

@@ -143,7 +143,8 @@ def main(runtime, cfg: Dict[str, Any]):
     world_size = jax.process_count()
     num_envs = int(cfg.env.num_envs)
     rollout_steps = int(cfg.algo.rollout_steps)
-    envs = make_vector_env(cfg, rank, log_dir)
+    with telemetry.span("setup/envs", "setup"):
+        envs = make_vector_env(cfg, rank, log_dir)
     observation_space = envs.single_observation_space
     wanted = {"prompt", "prompt_len", "token", "active"}
     if not isinstance(observation_space, gym.spaces.Dict) or not wanted <= set(observation_space.keys()):
@@ -154,15 +155,16 @@ def main(runtime, cfg: Dict[str, Any]):
 
     # The flax init is one jitted call; it runs host-side so that the mesh
     # device sees the finished tree once.
-    with runtime.host_init():
-        agent, params = build_agent(
-            runtime, cfg, int(envs.single_action_space.n), prompt_len, state["agent"] if state is not None else None
-        )
-    tx, base_lr = make_optimizer(cfg)
-    params = runtime.shard_params(params)
-    opt_state = jax.jit(tx.init)(params)
-    if state is not None:
-        opt_state = runtime.shard_params(restore_opt_state(opt_state, state["optimizer"]))
+    with telemetry.span("setup/agent", "setup"):
+        with runtime.host_init():
+            agent, params = build_agent(
+                runtime, cfg, int(envs.single_action_space.n), prompt_len, state["agent"] if state is not None else None
+            )
+        tx, base_lr = make_optimizer(cfg)
+        params = runtime.shard_params(params)
+        opt_state = jax.jit(tx.init)(params)
+        if state is not None:
+            opt_state = runtime.shard_params(restore_opt_state(opt_state, state["optimizer"]))
 
     if runtime.is_global_zero:
         save_configs(cfg, log_dir)
@@ -206,16 +208,17 @@ def main(runtime, cfg: Dict[str, Any]):
 
     # The player acts on a copy of the parameters in the compute dtype,
     # refreshed after every update (on-policy: the next rollout waits for it).
-    placement = PlayerPlacement.resolve(cfg, runtime.mesh.devices.flat[0], params=params, force_fresh=True)
-    placement.push(acting_fn(params))
-    rollout_key = jax.random.fold_in(runtime.root_key, rank)
-    order = np.random.default_rng(int(cfg.seed) + rank)  # the minibatches' order, drawn on the host
-    pipeline = InteractionPipeline.from_config(cfg)
-    if pipeline.slices != 1:
-        raise ValueError("ppo_lm keeps one cache for all envs: env.pipeline_slices must be 1")
-    pipeline.set_key(placement.put(rollout_key))
-    with placement.ctx():
-        pipeline.init_state(lambda n, _range: agent.init_state(n))
+    with telemetry.span("setup/player", "setup"):
+        placement = PlayerPlacement.resolve(cfg, runtime.mesh.devices.flat[0], params=params, force_fresh=True)
+        placement.push(acting_fn(params))
+        rollout_key = jax.random.fold_in(runtime.root_key, rank)
+        order = np.random.default_rng(int(cfg.seed) + rank)  # the minibatches' order, drawn on the host
+        pipeline = InteractionPipeline.from_config(cfg)
+        if pipeline.slices != 1:
+            raise ValueError("ppo_lm keeps one cache for all envs: env.pipeline_slices must be 1")
+        pipeline.set_key(placement.put(rollout_key))
+        with placement.ctx():
+            pipeline.init_state(lambda n, _range: agent.init_state(n))
 
     def prefill_policy(obs, player_state, key):  # a rollout's first policy step
         with placement.ctx(), telemetry.span("player/prefill", "player"):

@@ -14,8 +14,9 @@ import importlib
 import os
 import pathlib
 import sys
+import time
 import warnings
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from sheeprl_tpu.config.instantiate import instantiate
 from sheeprl_tpu.config.loader import compose
@@ -252,10 +253,11 @@ def _prune_model_keys(cfg: dotdict, utils_module) -> None:
         cfg.model_manager.disabled = True
 
 
-def run_algorithm(cfg: dotdict) -> None:
-    """Registry lookup + Runtime construction + entrypoint call
-    (reference: cli.py:60-199; fabric.launch collapses to a plain call —
-    JAX multi-host processes are launched externally, one per host)."""
+def _launch(cfg: dotdict, telemetry: Any) -> Tuple[Any, Any, Dict[str, Any]]:
+    """Registry lookup + Runtime construction: ``(runtime, entrypoint, the
+    entrypoint's extra kwargs)`` (reference: cli.py:60-199; fabric.launch
+    collapses to a plain call — JAX multi-host processes are launched
+    externally, one per host)."""
     entry = algorithm_registry[cfg.algo.name]
     task = importlib.import_module(entry.module)
     utils_module = importlib.import_module(entry.module.rsplit(".", 1)[0] + ".utils")
@@ -320,11 +322,7 @@ def run_algorithm(cfg: dotdict) -> None:
     runtime = instantiate(cfg.fabric)
     runtime.launch()
     runtime.seed_everything(cfg.seed)
-    # The run's observability surface: every algorithm opens it against its
-    # log dir and threads it through the train loop (howto/observability.md).
-    from sheeprl_tpu.telemetry import Telemetry
-
-    runtime.telemetry = Telemetry.from_config(cfg)
+    runtime.telemetry = telemetry
     # The run's fault-tolerance surface: preemption guard + env supervisor +
     # dispatch watchdog + chaos injectors (howto/fault_tolerance.md).
     from sheeprl_tpu.core.resilience import Resilience
@@ -335,6 +333,23 @@ def run_algorithm(cfg: dotdict) -> None:
     from sheeprl_tpu.telemetry.health import HealthMonitor
 
     runtime.health = HealthMonitor.from_config(cfg)
+    return runtime, command, kwargs
+
+
+def run_algorithm(cfg: dotdict, setup: Optional[Tuple[float, float]] = None) -> None:
+    """Runtime construction + entrypoint call.
+
+    ``setup``: the ``perf_counter`` start and end of ``setup/config`` where
+    :func:`run` composed the config; set-up's root span starts there."""
+    # The run's observability surface, built first so that set-up's compiles
+    # are recorded from here on: every algorithm opens it against its log dir
+    # and threads it through the train loop (howto/observability.md).
+    from sheeprl_tpu.telemetry import Telemetry
+
+    telemetry = Telemetry.from_config(cfg)
+    telemetry.begin_setup(setup[0] if setup else time.perf_counter(), (("setup/config", *setup),) if setup else ())
+    with telemetry.span("setup/runtime", "setup"):
+        runtime, command, kwargs = _launch(cfg, telemetry)
     import jax
 
     # Eager ops and un-sharded jits must land on the chosen accelerator: the
@@ -347,6 +362,7 @@ def run_algorithm(cfg: dotdict) -> None:
 def run(args: Optional[Sequence[str]] = None) -> None:
     """Training entry: `python -m sheeprl_tpu exp=... [overrides...]`
     (reference: cli.run, cli.py:358-366)."""
+    started = time.perf_counter()  # set-up's root span starts here
     import sheeprl_tpu
 
     sheeprl_tpu.register_all()
@@ -373,7 +389,7 @@ def run(args: Optional[Sequence[str]] = None) -> None:
     if cfg.metric.log_level > 0:
         print_config(cfg)
     check_configs(cfg)
-    run_algorithm(cfg)
+    run_algorithm(cfg, setup=(started, time.perf_counter()))
 
 
 def registration(args: Optional[Sequence[str]] = None) -> None:

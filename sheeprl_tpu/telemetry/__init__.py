@@ -9,8 +9,9 @@ Parts (see each module's docstring for the design):
   donated-chain pattern: N chained dispatches bounded by one fetch);
 - :mod:`~sheeprl_tpu.telemetry.histogram` — streaming geometric-bucket
   latency histogram (p50/p95/p99) used by StepTimer and the serving engine;
-- :mod:`~sheeprl_tpu.telemetry.jax_events` — compile/retrace/cache
-  counters via jax.monitoring, HBM gauges, recompile-after-warmup watchdog;
+- :mod:`~sheeprl_tpu.telemetry.jax_events` — compile spans and
+  compile/retrace/cache counters via jax.monitoring, HBM gauges,
+  recompile-after-warmup watchdog;
 - :mod:`~sheeprl_tpu.telemetry.profiling` — config-driven jax.profiler
   step-window traces and live profiler server;
 - :mod:`~sheeprl_tpu.telemetry.registry` — the unified counters/gauges/

@@ -1,55 +1,61 @@
-"""Compile/retrace/transfer counters wired to ``jax.monitoring``.
+"""Compile spans and counters from ``jax.monitoring``.
 
-JAX instruments its own compiler pipeline with named monitoring events;
-registering listeners is the zero-overhead way to count compiles — no
-wrapping of ``jax.jit``, no log scraping. The events this module consumes
-(names as of jax 0.4.x):
+JAX instruments its own compiler pipeline: each stage of a jitted function's
+first call is reported as a time span (wall-clock start and end, the
+function's name in ``fun_name``), and the persistent compile cache reports its
+traffic as events. Listening is the zero-overhead way to see compiles — no
+wrapping of ``jax.jit``, no log scraping. A :class:`JaxEventMonitor`
+registers its listeners on :meth:`~JaxEventMonitor.attach` and removes them on
+:meth:`~JaxEventMonitor.detach` (``Telemetry.begin_setup`` or ``open`` /
+``close``: with telemetry off none is registered). It records one span per
+stage, into the run's tracer (the current one where it was given none),
+on the tracer's ``perf_counter`` clock (JAX's wall-clock stamps are carried
+over through the tracer's ``perf_epoch_s`` / ``wall_epoch_s`` pair) and on the
+thread that compiled, which is the thread that called the jit:
 
-- ``/jax/core/compile/backend_compile_duration`` — one per real XLA
-  backend compile (the expensive thing; a retrace that hits the executable
-  cache does NOT fire it);
-- ``/jax/core/compile/jaxpr_trace_duration`` — one per trace of a jitted
-  function (fires on every retrace, cached or not);
-- ``/jax/compilation_cache/cache_hits`` / ``cache_misses`` — persistent
-  compile-cache traffic.
+- ``compile/trace`` — ``/jax/core/compile/jaxpr_trace_duration``, one per
+  trace of a function (an inner jit's trace nests inside its caller's);
+- ``compile/lower`` — ``/jax/core/compile/jaxpr_to_mlir_module_duration``;
+- ``compile/backend`` — ``/jax/core/compile/backend_compile_duration``, the
+  XLA compile, or the persistent cache's load in its place.
 
-``jax.monitoring`` has no public unregister, and test suites construct many
-telemetry stacks per process, so ONE module-level listener pair is
-registered lazily and fans out to the currently-attached monitors — attach/
-detach is list membership, not listener churn.
+Every span carries ``fun``. ``compile/backend`` also carries ``cache``
+(``hit``: loaded from the persistent cache; ``miss``: looked up there and
+compiled; ``off``: not looked up) and ``seen``: how many earlier
+``compile/backend`` spans of the same ``fun`` the monitor recorded. The second
+call of a jit that donates its inputs compiles once more for the donated
+layout; that compile reads ``seen`` 1.
 
-Retrace detection: hand-run profiling has to exclude the "hidden recompile"
-(the second call after compilation recompiles once for the donated-layout
-change). :meth:`JaxEventMonitor.advance` is called once per train
-iteration; compiles observed after ``warmup_iters`` iterations are counted
-as ``recompiles_after_warmup`` and warned about — the silent
-recompile-storm trap made loud.
+Counters: ``compiles`` and ``compile_secs`` (backend), ``traces`` and
+``trace_secs``, ``compile_cache_hits`` / ``compile_cache_misses`` (a miss is
+counted when the cache is written), ``compile/recompiles`` (backend compiles
+with ``seen`` > 0) and ``recompiles_after_warmup``:
+:meth:`JaxEventMonitor.advance` is called once per train iteration, and
+compiles observed after ``warmup_iters`` iterations are counted there and
+warned about — the silent recompile-storm trap made loud.
 """
 
 from __future__ import annotations
 
-import time
+import threading
 import warnings
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 from sheeprl_tpu.telemetry import tracer as tracer_mod
 
-_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+#: jax.monitoring time-span event -> span name
+SPAN_NAMES = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile/lower",
+    "/jax/core/compile/backend_compile_duration": "compile/backend",
+}
+_CACHE_LOOKUP = "/jax/compilation_cache/compile_requests_use_cache"
 _CACHE_COUNT_EVENTS = {
     "/jax/compilation_cache/cache_hits": "compile_cache_hits",
     "/jax/compilation_cache/cache_misses": "compile_cache_misses",
 }
-#: Substrings that mark a monitoring event as a device transfer. jax 0.4.37
-#: emits no transfer events yet (only the compile pipeline is instrumented),
-#: but the name family is reserved upstream — matching by substring means
-#: the runtime transfer ledger (core/mesh.py accounted puts) gains the
-#: runtime's own numbers the day the installed jax starts emitting them,
-#: with no code change here.
-_TRANSFER_NAME_PARTS = ("transfer", "device_put", "copy_to_host")
 
-_ACTIVE: List["JaxEventMonitor"] = []
-_LISTENERS_INSTALLED = False
+_MIRROR_INSTALLED = False
 
 
 def _registry_count(name: str, amount: float = 1.0) -> None:
@@ -58,9 +64,7 @@ def _registry_count(name: str, amount: float = 1.0) -> None:
     The ``jax/`` prefix keeps these distinct from the *gauge* mirrors that
     ``Telemetry.log_counters`` derives from monitor counters (``compiles``
     etc.) — a registry name can hold one kind only. This is the bridge that
-    puts compile/retrace/cache traffic on ``/metrics`` and the telemetry
-    tail for EVERY process with the listeners installed (serve included),
-    monitor attached or not.
+    puts compile/retrace/cache traffic on ``/metrics`` and the telemetry tail.
     """
     try:
         from sheeprl_tpu.telemetry.registry import default_registry
@@ -70,104 +74,115 @@ def _registry_count(name: str, amount: float = 1.0) -> None:
         pass
 
 
-def _transfer_key(event: str) -> Optional[str]:
-    """Counter stem for a transfer-family monitoring event, else None."""
-    lowered = event.lower()
-    if not any(part in lowered for part in _TRANSFER_NAME_PARTS):
-        return None
-    stem = lowered.rsplit("/", 1)[-1] or "transfer"
-    return f"transfer_event_{stem}"
-
-
-def _on_event(event: str, **kwargs: Any) -> None:
-    key = _CACHE_COUNT_EVENTS.get(event)
-    if key is None:
-        tkey = _transfer_key(event)
-        if tkey is None:
-            return
-        _registry_count(f"jax/{tkey}")
-        for monitor in list(_ACTIVE):
-            monitor.counters[tkey] = monitor.counters.get(tkey, 0.0) + 1.0
-        return
-    _registry_count(f"jax/{key}")
-    for monitor in list(_ACTIVE):
-        monitor.counters[key] = monitor.counters.get(key, 0.0) + 1.0
-
-
-def _on_event_duration(event: str, duration_secs: float, **kwargs: Any) -> None:
-    if event == _BACKEND_COMPILE_EVENT:
+def _mirror_span(event: str, start: float, end: float, **kwargs: Any) -> None:
+    name = SPAN_NAMES.get(event)
+    if name == "compile/backend":
         _registry_count("jax/compiles")
-        _registry_count("jax/compile_secs", float(duration_secs))
-        for monitor in list(_ACTIVE):
-            monitor._record_compile(duration_secs)
-    elif event == _TRACE_EVENT:
+        _registry_count("jax/compile_secs", end - start)
+    elif name == "compile/trace":
         _registry_count("jax/traces")
-        _registry_count("jax/trace_secs", float(duration_secs))
-        for monitor in list(_ACTIVE):
-            monitor.counters["traces"] = monitor.counters.get("traces", 0.0) + 1.0
-            monitor.counters["trace_secs"] = monitor.counters.get("trace_secs", 0.0) + float(
-                duration_secs
-            )
-    else:
-        tkey = _transfer_key(event)
-        if tkey is not None:
-            _registry_count(f"jax/{tkey}_calls")
-            _registry_count(f"jax/{tkey}_secs", float(duration_secs))
-            for monitor in list(_ACTIVE):
-                monitor.counters[f"{tkey}_secs"] = monitor.counters.get(
-                    f"{tkey}_secs", 0.0
-                ) + float(duration_secs)
+        _registry_count("jax/trace_secs", end - start)
 
 
-def _ensure_listeners() -> None:
-    global _LISTENERS_INSTALLED
-    if _LISTENERS_INSTALLED:
-        return
-    from jax import monitoring
-
-    monitoring.register_event_listener(_on_event)
-    monitoring.register_event_duration_secs_listener(_on_event_duration)
-    _LISTENERS_INSTALLED = True
+def _mirror_event(event: str, **kwargs: Any) -> None:
+    key = _CACHE_COUNT_EVENTS.get(event)
+    if key is not None:
+        _registry_count(f"jax/{key}")
 
 
 def install_listeners() -> None:
-    """Public, idempotent listener install for processes that never build a
-    :class:`JaxEventMonitor` — the serve engine calls this so inference
-    processes still expose ``jax/*`` compile counters on ``/metrics``."""
-    _ensure_listeners()
+    """Mirror compile traffic into the default registry for the rest of the
+    process, for processes that never attach a :class:`JaxEventMonitor` —
+    the serve engine calls this so inference processes still expose ``jax/*``
+    compile counters on ``/metrics``. Idempotent; attached monitors stop
+    mirroring once it has run, so nothing is counted twice."""
+    global _MIRROR_INSTALLED
+    if _MIRROR_INSTALLED:
+        return
+    from jax import monitoring
+
+    monitoring.register_event_time_span_listener(_mirror_span)
+    monitoring.register_event_listener(_mirror_event)
+    _MIRROR_INSTALLED = True
 
 
 class JaxEventMonitor:
-    """Per-run compile/transfer counter set fed by the module listeners."""
+    """Per-run compile spans and counters, fed by its own listeners while
+    attached; the spans go to ``tracer`` (the current tracer where None)."""
 
-    def __init__(self, warmup_iters: int = 3, warn_on_recompile: bool = True) -> None:
+    def __init__(
+        self, warmup_iters: int = 3, warn_on_recompile: bool = True, tracer: Optional[tracer_mod.Tracer] = None
+    ) -> None:
         self.warmup_iters = int(warmup_iters)
         self.warn_on_recompile = bool(warn_on_recompile)
+        self._tracer = tracer
         self.counters: Dict[str, float] = {}
         self.iters = 0
         self._compiles_at_warmup: Optional[float] = None
+        self._seen: Dict[str, int] = {}
+        self._lock = threading.Lock()  # jits compile on whichever thread calls them
+        self._local = threading.local()  # .cache: what the cache said of the backend compile running on this thread
+        self._attached = False
 
     # ----------------------------------------------------------- lifecycle
     def attach(self) -> None:
-        _ensure_listeners()
-        if self not in _ACTIVE:
-            _ACTIVE.append(self)
+        if self._attached:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_time_span_listener(self._on_span)
+        monitoring.register_event_listener(self._on_event)
+        self._attached = True
 
     def detach(self) -> None:
-        try:
-            _ACTIVE.remove(self)
-        except ValueError:
-            pass
+        if not self._attached:
+            return
+        from jax import monitoring
+
+        monitoring.unregister_event_time_span_listener(self._on_span)
+        monitoring.unregister_event_listener(self._on_event)
+        self._attached = False
 
     # ------------------------------------------------------------- events
-    def _record_compile(self, duration_secs: float) -> None:
-        self.counters["compiles"] = self.counters.get("compiles", 0.0) + 1.0
-        self.counters["compile_secs"] = self.counters.get("compile_secs", 0.0) + float(
-            duration_secs
-        )
-        # A compile span on the timeline: ends now, lasted duration_secs.
-        now = time.perf_counter()
-        tracer_mod.current().add_span("xla_compile", "compile", now - duration_secs, duration_secs)
+    def _count(self, name: str, amount: float = 1.0) -> None:
+        with self._lock:
+            self.counters[name] = self.counters.get(name, 0.0) + float(amount)
+
+    def _on_event(self, event: str, **kwargs: Any) -> None:
+        key = _CACHE_COUNT_EVENTS.get(event)
+        if key is not None:
+            self._count(key)
+            self._local.cache = "hit" if key == "compile_cache_hits" else "miss"
+            if not _MIRROR_INSTALLED:
+                _mirror_event(event)
+        elif event == _CACHE_LOOKUP and getattr(self._local, "cache", "off") == "off":
+            self._local.cache = "miss"  # a hit, if one follows, overrides it
+
+    def _on_span(self, event: str, start: float, end: float, **kwargs: Any) -> None:
+        name = SPAN_NAMES.get(event)
+        if name is None:
+            return
+        fun = str(kwargs.get("fun_name", ""))
+        seconds = end - start
+        args: Dict[str, Any] = {"fun": fun}
+        if name == "compile/backend":
+            with self._lock:
+                seen = self._seen.get(fun, 0)
+                self._seen[fun] = seen + 1
+            args["cache"] = getattr(self._local, "cache", "off")
+            args["seen"] = seen
+            self._local.cache = "off"
+            self._count("compiles")
+            self._count("compile_secs", seconds)
+            if seen:
+                self._count("compile/recompiles")
+        elif name == "compile/trace":
+            self._count("traces")
+            self._count("trace_secs", seconds)
+        if not _MIRROR_INSTALLED:
+            _mirror_span(event, start, end)
+        tracer = self._tracer or tracer_mod.current()
+        tracer.add_span(name, "compile", tracer.perf_epoch_s + (start - tracer.wall_epoch_s), seconds, args)
 
     # -------------------------------------------------------------- steps
     def advance(self) -> None:

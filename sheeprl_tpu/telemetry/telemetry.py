@@ -6,13 +6,23 @@ Composition of the observability subsystem's parts:
   installed as the process-wide current tracer while the run is open so
   low-level emitters (utils/timer, core/rollout, data/infeed) need no
   plumbing;
-- :class:`~sheeprl_tpu.telemetry.jax_events.JaxEventMonitor` compile/
-  retrace/cache counters plus HBM gauges;
+- :class:`~sheeprl_tpu.telemetry.jax_events.JaxEventMonitor` compile
+  spans (``compile/trace``, ``compile/lower``, ``compile/backend``) and
+  compile/retrace/cache counters plus HBM gauges;
 - a :class:`~sheeprl_tpu.telemetry.profiling.ProfilerWindow` for the
   config-driven XLA trace window and live profiler server;
 - :class:`~sheeprl_tpu.telemetry.step_timer.StepTimer` instances for the
   train loops (always functional — they carry the coalesced metric fetch —
   whether or not telemetry is enabled).
+
+Set-up is a span tree: the root ``setup`` runs from the entry point's first
+line (``cli.run``) to the loop's first :meth:`Telemetry.advance`. The entry
+point builds this object first and hands set-up over
+(:meth:`Telemetry.begin_setup`: the root's start, ``setup/config`` timed
+before; from then on set-up's compiles are recorded), times ``setup/runtime``
+with :meth:`Telemetry.span`, and the mains place ``setup/envs``,
+``setup/agent``, ``setup/replay`` and ``setup/player`` around their own
+set-up code.
 
 Exports (rank zero, on :meth:`close`): ``trace.json`` (Chrome trace-event
 JSON) and ``telemetry.jsonl`` (a meta line at open, one counters line per
@@ -119,7 +129,7 @@ class Telemetry:
         self.flight_min_dump_interval_s = float(flight_min_dump_interval_s)
         self._tracer = Tracer(capacity=buffer_capacity, enabled=self.enabled)
         self._monitor = JaxEventMonitor(
-            warmup_iters=warmup_iters, warn_on_recompile=warn_on_recompile
+            warmup_iters=warmup_iters, warn_on_recompile=warn_on_recompile, tracer=self._tracer
         )
         self._profiler = ProfilerWindow(
             trace_dir=profiler_trace_dir,
@@ -152,6 +162,7 @@ class Telemetry:
         self._trace_root: Optional[trace_context.TraceContext] = None
         self._trace_token: Any = None
         self._iteration: Optional[tuple] = None  # the open loop/iteration: (ctx, start, step, gradient steps before)
+        self._setup_start: Optional[float] = None  # the `setup` root's start, until advance() closes it
         self._carrier_prev: Optional[tuple] = None
         self._flight: Optional[flight_mod.FlightRecorder] = None
         self._flight_tracer: Optional[Tracer] = None
@@ -199,6 +210,18 @@ class Telemetry:
         return cls(enabled=False)
 
     # ---------------------------------------------------------- lifecycle
+    def begin_setup(self, started: float, phases: tuple = ()) -> None:
+        """The entry point hands set-up over: the ``setup`` root starts at
+        ``started`` and ``phases`` are ``(name, start, end)`` timed before this
+        object existed (``perf_counter`` seconds). From here on the run's
+        compiles are recorded, so those of set-up before :meth:`open` are too."""
+        if not self.enabled:
+            return
+        for name, start, end in phases:
+            self._tracer.add_span(name, "setup", start, end - start)
+        self._setup_start = started
+        self._monitor.attach()
+
     def open(self, log_dir: Optional[str], rank_zero: bool = True, device: Any = None) -> "Telemetry":
         """Bind the run's log dir and go live: install the tracer as the
         process-wide current one, attach the jax.monitoring counters, start
@@ -338,12 +361,13 @@ class Telemetry:
         self._end_iteration(time.perf_counter())
         for st in self._step_timers.values():
             st.flush()
+        self._monitor.detach()
+        self._setup_start = None
         if self._opened:
             if self._exporter is not None:
                 self._exporter.close()
                 self._exporter = None
             self._profiler.close()
-            self._monitor.detach()
             self._export()
             tracer_mod.set_current(self._previous_tracer)
             self._previous_tracer = None
@@ -392,6 +416,9 @@ class Telemetry:
         ship, env restarts — parents to one iteration span)."""
         if self._trace_root is not None:
             now = time.perf_counter()
+            if self._setup_start is not None:  # the first iteration ends set-up
+                self._tracer.add_span("setup", "setup", self._setup_start, now - self._setup_start)
+                self._setup_start = None
             self._end_iteration(now)
             ctx = self._trace_root.child()
             trace_context.set_current(ctx)

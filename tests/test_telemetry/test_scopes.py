@@ -241,3 +241,41 @@ def test_loop_iterations_tile_the_loop(tmp_path):
         holders = [i for i in iterations
                    if i["ts_us"] <= child["ts_us"] and child["ts_us"] + child["dur_us"] <= i["ts_us"] + i["dur_us"] + 0.01]
         assert len(holders) == 1 and child["parent_id"] == holders[0]["span_id"]
+
+
+def test_setup_is_a_span_tree_the_first_iteration_closes(tmp_path):
+    """Phases the entry point timed before the run's Telemetry existed become
+    spans with their own starts; compiles from the hand-over on are recorded,
+    before `open` too; the root `setup` runs from its start to the first
+    iteration, and no later iteration adds to it."""
+    import time
+
+    def compiled_in_setup(x):
+        return x - 1
+
+    x = jnp.ones((3,))
+    tele = Telemetry(enabled=True, flight_enabled=False)
+    started = time.perf_counter()
+    tele.begin_setup(started, (("setup/config", started, started + 1e-3),))
+    with tele.span("setup/runtime", "setup"):
+        jax.jit(compiled_in_setup)(x)
+    tele.open(str(tmp_path))
+    with tele.span("setup/agent", "setup"):
+        pass
+    for step in (1, 2):
+        tele.advance(step)
+    tele.close()
+    records = [json.loads(line) for line in open(tmp_path / "telemetry.jsonl")]
+    epoch = records[0]["perf_epoch_s"]
+    spans = {r["name"]: r for r in records if r["type"] == "span" and r["name"] != "loop/iteration"}
+    assert set(spans) == {"setup", "setup/config", "setup/runtime", "setup/agent", "compile/trace", "compile/lower", "compile/backend"}
+    assert spans["setup/config"]["ts_us"] == pytest.approx((started - epoch) * 1e6, abs=0.01)
+    assert spans["setup/config"]["dur_us"] == pytest.approx(1e3, abs=0.01)
+    runtime, backend = spans["setup/runtime"], spans["compile/backend"]
+    assert backend["args"]["fun"] == "jit(compiled_in_setup)"
+    assert runtime["ts_us"] <= backend["ts_us"] <= backend["ts_us"] + backend["dur_us"] <= runtime["ts_us"] + runtime["dur_us"] + 100
+    first = min((r for r in records if r.get("name") == "loop/iteration"), key=lambda r: r["ts_us"])
+    root = spans["setup"]
+    assert root["ts_us"] == spans["setup/config"]["ts_us"]
+    assert root["ts_us"] + root["dur_us"] == pytest.approx(first["ts_us"], abs=0.01)
+    assert all(spans[name]["cat"] == "setup" for name in spans if not name.startswith("compile/"))

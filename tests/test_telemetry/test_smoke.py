@@ -52,7 +52,7 @@ def _check_exports(root):
     assert "Time/train_time" in names
     assert "train/dispatch" in names
     # ...and at least one compile event from the jax.monitoring listeners.
-    assert "xla_compile" in names
+    assert "compile/backend" in names
     assert "compile" in cats
 
     lines = [json.loads(line) for line in open(jsonl_path)]
