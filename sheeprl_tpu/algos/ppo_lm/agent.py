@@ -39,7 +39,8 @@ from sheeprl_tpu.telemetry import scopes
 HIGHEST = jax.lax.Precision.HIGHEST
 #: ``algo.model.model_type`` -> the backbone's config; its ``backbone(dtype, param_dtype)`` is the decoder, which says
 #: everything of the player's state that the agent needs (``init_cache``, ``prefill_cache``, ``prefill_rows``,
-#: ``decode``, ``cache_kinds``, ``scan_chunks``, ``fused_scan_layers``, ``fused_attention_layers``): nothing below asks
+#: ``decode``, ``cache_kinds``, ``scan_chunks``, ``fused_scan_layers``, ``fused_attention_layers``,
+#: ``attention_tile_visits``): nothing below asks
 #: which family it has.
 BACKBONES = {"deepseek_v3": TransformerConfig, "phi4flash": HybridConfig}
 #: The player's state beside the backbone's cache: small, not donated, readable after a call.
@@ -150,6 +151,11 @@ class PPOLMAgent:
     def fused_attention_layers(self) -> int:
         """Attention layers of a gradient step's sequences that run as fused kernels (`lm/attention_fused`; 0 = the plain path)."""
         return self.backbone.fused_attention_layers(self.context)
+
+    def attention_tile_visits(self, start: jax.Array) -> Optional[Tuple[jax.Array, jax.Array]]:
+        """The fused attention kernels' tile visits in a gradient step over rows whose keys begin at ``start`` [B], and
+        those skipped as left padding (`mla/tile_visits`, `mla/tile_visits_skipped`; None = no such count here)."""
+        return self.backbone.attention_tile_visits(start, self.context)
 
     def prefill(self, params: Any, state: Dict[str, Any], prompt: jax.Array, prompt_len: jax.Array, reset: jax.Array,
                 key: jax.Array, greedy: bool = False):

@@ -59,6 +59,9 @@ STEP_COUNTERS = ("moe/routed_slots", "moe/held_slots", "moe/overflow_chunks", "m
                  "ppo_lm/padded_tokens", "ppo_lm/step_tokens")
 #: Counted only by a backbone that has the mechanism (chunks x state-space layers of the step's selective scans).
 SCAN_COUNTER = "ssm/scan_chunks"
+#: Counted only where the step runs the latent-attention kernels: the (query tile, key tile) pairs they visit, and those
+#: they skip as wholly left padding, over layers, passes and heads.
+TILE_COUNTERS = ("mla/tile_visits", "mla/tile_visits_skipped")
 
 
 def make_train_step(agent: PPOLMAgent, tx: optax.GradientTransformation, cfg: Dict[str, Any]):
@@ -113,6 +116,9 @@ def make_train_step(agent: PPOLMAgent, tx: optax.GradientTransformation, cfg: Di
         }
         if scan_chunks:
             metrics[SCAN_COUNTER] = jnp.asarray(scan_chunks, jnp.float32)
+        tiles = agent.attention_tile_visits(start)
+        if tiles is not None:
+            metrics.update({name: count.astype(jnp.float32) for name, count in zip(TILE_COUNTERS, tiles)})
         routes = stats["chosen"] if stats else jnp.zeros((0, positions, 1), jnp.int32)
         return params, opt_state, metrics, routes
 
@@ -243,8 +249,9 @@ def main(runtime, cfg: Dict[str, Any]):
         for step_metrics in fetched:
             for name in STEP_COUNTERS:
                 tracer.count(name, float(step_metrics[name]))
-            if SCAN_COUNTER in step_metrics:
-                tracer.count(SCAN_COUNTER, float(step_metrics[SCAN_COUNTER]))
+            for name in (SCAN_COUNTER, *TILE_COUNTERS):
+                if name in step_metrics:
+                    tracer.count(name, float(step_metrics[name]))
 
     shape = (rollout_steps, num_envs)
     tokens = np.zeros(shape, np.int32)

@@ -600,6 +600,11 @@ class HybridDecoder(nn.Module):
         return sum(attention_is_fused(c, seq, c.window(i), self.dtype) for i in c.layers if c.kind(i) in ("swa", "full", "cross"))
 
     @nn.nowrap
+    def attention_tile_visits(self, start: jax.Array, seq: int) -> None:
+        """No tile count is kept for the differential kernels (None): they walk from a row's first tile, padding or not."""
+        return None
+
+    @nn.nowrap
     def prefill_rows(self, num_envs: int, prompt_len: int) -> Optional[int]:
         """Prompts that go through the whole-sequence form together: all of them (None). The scans are one position
         after another whatever the rows; the fused attention takes a row a grid step, and the plain path makes its

@@ -28,8 +28,16 @@ scale, mask, maximum, sums, log-sum-exp and the accumulators in float32; the
 probabilities are cast only as the operand of their products.
 
 Masks: causal, and keys valid from ``start[b]`` on. Key tiles wholly above
-the diagonal are never visited. A query row with no valid key (a position
-inside the left padding) gives a zero output and sends no gradient anywhere.
+the diagonal are never visited, nor is a tile wholly inside a row's left
+padding: a row's walk starts at :func:`first_tile`, the first tile that holds
+a valid key. A query tile before it writes the zero output and the
+no-key log-sum-exp at once; a key tile before it writes zero gradients at
+once. What is skipped adds exactly nothing (a score there is ``MASKED``: its
+probability is exactly 0 once a row has met a valid key, and the row's sums
+restart from it exactly), so every result is the one a walk from tile 0
+gives, to the bit. :func:`tile_visits` counts the walk by the same rule. A
+query row with no valid key (a position inside the left padding) gives a
+zero output and sends no gradient anywhere.
 
 The sequence is padded to the tile on the right inside the wrapper (causality
 keeps real queries off the padded keys) and ``qk_rope_head_dim`` to the
@@ -122,6 +130,26 @@ def shape_ineligible_reason(seq: int, nope_dim: int, rope_dim: int, v_dim: int, 
     return None
 
 
+def first_tile(start):
+    """The first tile of a row whose keys begin at ``start`` that holds a valid
+    key: the tiles before it lie wholly in the left padding, and the kernels'
+    walks start here."""
+    return start // BLOCK
+
+
+def tile_visits(start: jax.Array, seq: int):
+    """The (query tile, key tile) pairs one kernel call over ``seq`` positions
+    visits for each row of ``start`` [B] and each head, and those it skips as
+    wholly left padding: ``(visits [B], skipped [B])``. The forward's query
+    tile ``i`` visits the key tiles ``first .. i``, the backward's key tile
+    ``j`` the query tiles ``j ..`` to the end: both walk the pairs on or below
+    the diagonal from :func:`first_tile` on."""
+    tiles = _padded_len(seq) // BLOCK
+    kept = tiles - jnp.minimum(first_tile(start), tiles)
+    visits = kept * (kept + 1) // 2
+    return visits, tiles * (tiles + 1) // 2 - visits
+
+
 def _tile_iota(axis: int) -> jax.Array:
     return jax.lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), axis)
 
@@ -130,37 +158,46 @@ def _tile_iota(axis: int) -> jax.Array:
 def _fwd_kernel(start_ref, qn_ref, qr_ref, kv_ref, kr_ref, out_ref, lse_ref, *, scale: float):
     b, i = pl.program_id(0), pl.program_id(2)
     start = start_ref[b]
-    qn, qr = qn_ref[0], qr_ref[0]  # [BLOCK, 128]
-    key_at = jax.lax.broadcasted_iota(jnp.int32, (1, BLOCK), 1)
+    first = first_tile(start)
 
-    def tile(j, carry, diagonal: bool):
-        m, l, acc = carry
-        at = pl.multiple_of(j * BLOCK, BLOCK)
-        kn, v, kr = kv_ref[0, pl.ds(at, BLOCK), :LANES], kv_ref[0, pl.ds(at, BLOCK), LANES:], kr_ref[0, pl.ds(at, BLOCK), :]
-        s = jax.lax.dot_general(qn, kn, _NT, preferred_element_type=jnp.float32)
-        s = s + jax.lax.dot_general(qr, kr, _NT, preferred_element_type=jnp.float32)
-        s = s * scale + jnp.where(at + key_at >= start, 0.0, MASKED)  # a key inside the left padding
-        if diagonal:  # tiles are square: on the diagonal tile key k is visible to query q where k <= q, tile-locally
-            s = jnp.where(_tile_iota(1) <= _tile_iota(0), s, MASKED)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-        acc = alpha * acc + jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        return m_new, l, acc
+    @pl.when(i < first)
+    def _all_padding():  # no row of the tile has a valid key: what such a row gets, with no key tile visited
+        out_ref[0] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
+        lse_ref[0, 0] = jnp.full(lse_ref.shape[2:], NO_KEY_LSE, jnp.float32)
 
-    init = (
-        jnp.full((BLOCK, 1), MASKED, jnp.float32),
-        jnp.zeros((BLOCK, 1), jnp.float32),
-        jnp.zeros((BLOCK, LANES), jnp.float32),
-    )
-    # the key tiles below the diagonal need no causal mask; tiles above it are never visited
-    m, l, acc = tile(i, jax.lax.fori_loop(0, i, functools.partial(tile, diagonal=False), init), diagonal=True)
-    seen = m > 0.5 * MASKED  # the row met a valid key
-    out_ref[0] = jnp.where(seen, acc / l, 0.0).astype(out_ref.dtype)
-    lse = jnp.where(seen, m + jnp.log(l), NO_KEY_LSE)
-    # the rows' log-sum-exp leaves as a row (queries on lanes), the way the backward kernel reads it
-    lse_ref[0, 0] = jnp.broadcast_to(lse, (BLOCK, LANES)).T[:1]
+    @pl.when(i >= first)
+    def _walk():
+        qn, qr = qn_ref[0], qr_ref[0]  # [BLOCK, 128]
+        key_at = jax.lax.broadcasted_iota(jnp.int32, (1, BLOCK), 1)
+
+        def tile(j, carry, diagonal: bool):
+            m, l, acc = carry
+            at = pl.multiple_of(j * BLOCK, BLOCK)
+            kn, v, kr = kv_ref[0, pl.ds(at, BLOCK), :LANES], kv_ref[0, pl.ds(at, BLOCK), LANES:], kr_ref[0, pl.ds(at, BLOCK), :]
+            s = jax.lax.dot_general(qn, kn, _NT, preferred_element_type=jnp.float32)
+            s = s + jax.lax.dot_general(qr, kr, _NT, preferred_element_type=jnp.float32)
+            s = s * scale + jnp.where(at + key_at >= start, 0.0, MASKED)  # a key inside the left padding
+            if diagonal:  # tiles are square: on the diagonal tile key k is visible to query q where k <= q, tile-locally
+                s = jnp.where(_tile_iota(1) <= _tile_iota(0), s, MASKED)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        init = (
+            jnp.full((BLOCK, 1), MASKED, jnp.float32),
+            jnp.zeros((BLOCK, 1), jnp.float32),
+            jnp.zeros((BLOCK, LANES), jnp.float32),
+        )
+        # the key tiles below the diagonal need no causal mask; tiles above it, and before the first, are never visited
+        m, l, acc = tile(i, jax.lax.fori_loop(first, i, functools.partial(tile, diagonal=False), init), diagonal=True)
+        seen = m > 0.5 * MASKED  # the row met a valid key
+        out_ref[0] = jnp.where(seen, acc / l, 0.0).astype(out_ref.dtype)
+        lse = jnp.where(seen, m + jnp.log(l), NO_KEY_LSE)
+        # the rows' log-sum-exp leaves as a row (queries on lanes), the way the backward kernel reads it
+        lse_ref[0, 0] = jnp.broadcast_to(lse, (BLOCK, LANES)).T[:1]
 
 
 def _forward(qn, qr, kv, kr, start, scale: float, interpret: bool, out_dtype=None):
@@ -199,6 +236,7 @@ def _bwd_kernel(start_ref, qn_ref, qr_ref, kv_ref, kr_ref, out_ref, do_ref, lse_
     b, j = pl.program_id(0), pl.program_id(2)
     tiles = pl.num_programs(2)
     start = start_ref[b]
+    first = first_tile(start)
 
     @pl.when(j == 0)
     def _first_key_tile_of_the_head():
@@ -213,38 +251,45 @@ def _bwd_kernel(start_ref, qn_ref, qr_ref, kv_ref, kr_ref, out_ref, do_ref, lse_
 
         jax.lax.fori_loop(0, tiles, delta, 0)
 
-    kn, v, kr = kv_ref[0, :, :LANES], kv_ref[0, :, LANES:], kr_ref[0]  # [BLOCK, 128]
-    # scores transposed: keys on sublanes, queries on lanes
-    key_at = j * BLOCK + jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)
-    padding = jnp.where(key_at >= start, 0.0, MASKED)  # [BLOCK, 1]: a key inside the left padding
+    @pl.when(j < first)
+    def _all_padding():  # no key of the tile is valid: no gradient reaches it, and no query tile is visited
+        dkv_ref[...] = jnp.zeros(dkv_ref.shape, dkv_ref.dtype)
+        dkr_ref[...] = jnp.zeros(dkr_ref.shape, dkr_ref.dtype)
 
-    def tile(i, carry, diagonal: bool):
-        dkn, dkr, dv = carry
-        at = pl.multiple_of(i * BLOCK, BLOCK)
-        qn, qr, do = qn_ref[0, pl.ds(at, BLOCK), :], qr_ref[0, pl.ds(at, BLOCK), :], do_ref[0, pl.ds(at, BLOCK), :]
-        lse, delta = lse_ref[0, 0, pl.ds(i, 1), :], delta_ref[pl.ds(i, 1), :]  # [1, BLOCK]
-        s = jax.lax.dot_general(kn, qn, _NT, preferred_element_type=jnp.float32)
-        s = s + jax.lax.dot_general(kr, qr, _NT, preferred_element_type=jnp.float32)
-        s = s * scale + padding
-        if diagonal:
-            s = jnp.where(_tile_iota(0) <= _tile_iota(1), s, MASKED)
-        p = jnp.exp(s - lse)
-        dv = dv + jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(qn.dtype)
-        dkn = dkn + jnp.dot(ds, qn, preferred_element_type=jnp.float32)
-        dkr = dkr + jnp.dot(ds, qr, preferred_element_type=jnp.float32)
-        dqn_acc[pl.ds(at, BLOCK), :] += jax.lax.dot_general(ds, kn, _TN, preferred_element_type=jnp.float32)
-        dqr_acc[pl.ds(at, BLOCK), :] += jax.lax.dot_general(ds, kr, _TN, preferred_element_type=jnp.float32)
-        return dkn, dkr, dv
+    @pl.when(j >= first)
+    def _walk():
+        kn, v, kr = kv_ref[0, :, :LANES], kv_ref[0, :, LANES:], kr_ref[0]  # [BLOCK, 128]
+        # scores transposed: keys on sublanes, queries on lanes
+        key_at = j * BLOCK + jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)
+        padding = jnp.where(key_at >= start, 0.0, MASKED)  # [BLOCK, 1]: a key inside the left padding
 
-    zero = jnp.zeros((BLOCK, LANES), jnp.float32)
-    # the diagonal tile, then the query tiles below it (no causal mask there)
-    first = tile(j, (zero, zero, zero), diagonal=True)
-    dkn, dkr, dv = jax.lax.fori_loop(j + 1, tiles, functools.partial(tile, diagonal=False), first)
-    dkv_ref[0, :, :LANES] = dkn.astype(dkv_ref.dtype)
-    dkv_ref[0, :, LANES:] = dv.astype(dkv_ref.dtype)
-    dkr_ref[0, 0] = dkr
+        def tile(i, carry, diagonal: bool):
+            dkn, dkr, dv = carry
+            at = pl.multiple_of(i * BLOCK, BLOCK)
+            qn, qr, do = qn_ref[0, pl.ds(at, BLOCK), :], qr_ref[0, pl.ds(at, BLOCK), :], do_ref[0, pl.ds(at, BLOCK), :]
+            lse, delta = lse_ref[0, 0, pl.ds(i, 1), :], delta_ref[pl.ds(i, 1), :]  # [1, BLOCK]
+            s = jax.lax.dot_general(kn, qn, _NT, preferred_element_type=jnp.float32)
+            s = s + jax.lax.dot_general(kr, qr, _NT, preferred_element_type=jnp.float32)
+            s = s * scale + padding
+            if diagonal:
+                s = jnp.where(_tile_iota(0) <= _tile_iota(1), s, MASKED)
+            p = jnp.exp(s - lse)
+            dv = dv + jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta) * scale).astype(qn.dtype)
+            dkn = dkn + jnp.dot(ds, qn, preferred_element_type=jnp.float32)
+            dkr = dkr + jnp.dot(ds, qr, preferred_element_type=jnp.float32)
+            dqn_acc[pl.ds(at, BLOCK), :] += jax.lax.dot_general(ds, kn, _TN, preferred_element_type=jnp.float32)
+            dqr_acc[pl.ds(at, BLOCK), :] += jax.lax.dot_general(ds, kr, _TN, preferred_element_type=jnp.float32)
+            return dkn, dkr, dv
+
+        zero = jnp.zeros((BLOCK, LANES), jnp.float32)
+        # the diagonal tile, then the query tiles below it (no causal mask there)
+        diagonal = tile(j, (zero, zero, zero), diagonal=True)
+        dkn, dkr, dv = jax.lax.fori_loop(j + 1, tiles, functools.partial(tile, diagonal=False), diagonal)
+        dkv_ref[0, :, :LANES] = dkn.astype(dkv_ref.dtype)
+        dkv_ref[0, :, LANES:] = dv.astype(dkv_ref.dtype)
+        dkr_ref[0, 0] = dkr
 
     @pl.when(j == tiles - 1)
     def _write():

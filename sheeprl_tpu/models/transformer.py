@@ -540,6 +540,18 @@ class Transformer(nn.Module):
         return self.cfg.num_hidden_layers * attention_is_fused(self.cfg, seq, self.dtype)
 
     @nn.nowrap
+    def attention_tile_visits(self, start: jax.Array, seq: int) -> Optional[Tuple[jax.Array, jax.Array]]:
+        """Tile visits of the fused attention kernels in one gradient step over rows of ``seq`` positions whose keys
+        begin at ``start`` [B], and the visits skipped as wholly left padding
+        (`pallas_mla_attention.tile_visits`), over every layer's forward, the forward `nn.remat` makes again and the
+        backward, and every head; None where the plain path runs."""
+        if not attention_is_fused(self.cfg, seq, self.dtype):
+            return None
+        visits, skipped = pallas_mla_attention.tile_visits(start, seq)
+        calls = self.cfg.num_hidden_layers * 3 * self.cfg.num_attention_heads
+        return calls * jnp.sum(visits), calls * jnp.sum(skipped)
+
+    @nn.nowrap
     def prefill_rows(self, num_envs: int, prompt_len: int) -> Optional[int]:
         """Prompts that go through the whole-sequence form together; None = all of them at once."""
         at_once = attention_is_fused(self.cfg, prompt_len, self.dtype) or num_envs % PREFILL_GROUP

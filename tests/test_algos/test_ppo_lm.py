@@ -217,3 +217,51 @@ def test_the_hybrid_counters_and_gauges_reach_telemetry_tail(tmp_path):
     assert values["player/cache_bytes/state"] == 2 * 4 * (3 + 4) * 64 * 4
     assert values["lm/attention_fused"] == 0  # off the TPU (and at heads of 8) every attention layer takes the plain path
     assert values["ssm/scan_fused"] == 0  # off the TPU both Mamba layers scan on the plain path
+
+
+# ------------------------------------------------------------------ the latent-attention kernels' tile counters
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "plain"])
+def test_the_tile_counters_ride_on_the_step_where_the_kernels_run(fused, monkeypatch):
+    """At 2080 positions (five tiles of 512) a gradient step's kernels make
+    forward, rematerialised forward and backward a layer: with the pairs they
+    skip, every (layer, pass, row, head) counts the 15 pairs on or below the
+    diagonal. The plain path skips nothing and counts nothing."""
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from sheeprl_tpu.algos.ppo_lm.agent import PPOLMAgent
+    from sheeprl_tpu.algos.ppo_lm.ppo_lm import TILE_COUNTERS, make_train_step
+    from sheeprl_tpu.models import pallas_mla_attention as kernel
+    from sheeprl_tpu.models.transformer import TransformerConfig
+
+    if fused:  # the rule asked about the shape alone, the kernels in the interpreter
+        monkeypatch.setattr(kernel, "ineligible_reason", kernel.shape_ineligible_reason)
+        monkeypatch.setattr(kernel, "mla_attention", lambda *a, run=kernel.mla_attention: run(*a, interpret=True))
+    layers, heads, P, R = 2, 2, 2048, 32
+    model = TransformerConfig(vocab_size=16, hidden_size=32, num_hidden_layers=layers, num_attention_heads=heads,
+                              qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=16,
+                              intermediate_size=32, moe_intermediate_size=16, n_routed_experts=4, n_shared_experts=1,
+                              num_experts_per_tok=2)
+    agent = PPOLMAgent(model, P, R, jnp.float32, jnp.float32)
+    params = agent.init_params(jax.random.PRNGKey(0))
+    tx = optax.sgd(1e-3)
+    step = make_train_step(agent, tx, SimpleNamespace(algo=SimpleNamespace(vf_coef=0.5)))
+    start = jnp.asarray([0, 1024], jnp.int32)  # the second row's first two tiles are padding
+    rows = start.shape[0]
+    tokens = jnp.where(jnp.arange(P + R)[None, :] >= start[:, None], 3, 0).astype(jnp.int32)
+    zeros = jnp.zeros((rows, R), jnp.float32)
+    batch = {"tokens": tokens, "start": start, "logprobs": zeros, "values": zeros, "advantages": zeros,
+             "returns": zeros, "mask": jnp.ones((rows, R), jnp.float32)}
+    text = str(jax.make_jaxpr(step)(params, tx.init(params), batch, 0.2, 0.0))
+    calls = text.count("name=mla_attention_fwd") + text.count("name=mla_attention_bwd")
+    assert calls == (3 * layers if fused else 0)
+    metrics = step(params, tx.init(params), batch, 0.2, 0.0)[2]
+    if not fused:
+        assert not set(TILE_COUNTERS) & set(metrics)
+        return
+    visits, skipped = (float(metrics[name]) for name in TILE_COUNTERS)
+    assert visits + skipped == layers * 3 * rows * heads * 15
+    assert visits == layers * 3 * heads * (15 + 6) and skipped == layers * 3 * heads * 9

@@ -15,14 +15,21 @@ SCALE = (kernel.LANES + ROPE) ** -0.5
 SHIPPED_BLOCK = kernel.BLOCK
 #: name -> (sequence length, start of each row, tile, heads). Tiles of 128 keep
 #: the interpreter quick: 300 positions are 2.3 of them, as 2080 are 4.06 of
-#: 512. One case runs the tile as shipped: three of them on the diagonal, one
-#: row's keys beginning inside the second (its first tile is all padding).
+#: 512. Two cases run the tile as shipped: three of them on the diagonal, one
+#: row's keys beginning inside the second (its first tile is all padding); and
+#: the cell's 2080 positions with the first two of five tiles all padding. The
+#: left padding covers whole tiles (1, 2 and 3 of them, the walks start past
+#: them), ends one position short of a tile's edge, or is the whole row.
 CASES = {
     "batch1_start0": (300, [0], 128, 2),
     "batch4_start_0_midtile_edge_allpadding": (300, [0, 70, 128, 300], 128, 2),
     "whole_tiles_start_in_the_last": (256, [5, 200], 128, 2),
     "shorter_than_a_tile": (72, [0, 9], 128, 2),
     "shipped_tile_start_0_and_midtile": (1100, [0, 600], SHIPPED_BLOCK, 1),
+    "padding_covers_1_2_3_whole_tiles": (500, [128, 256, 384, 0], 128, 2),
+    "padding_one_short_of_a_tile_edge": (500, [127, 255, 383], 128, 2),
+    "padding_throughout_the_row": (512, [512, 0], 128, 2),
+    "shipped_tile_start_1024_of_2080": (2080, [1024, 1535], SHIPPED_BLOCK, 1),
 }
 
 
@@ -76,8 +83,24 @@ def test_kernel_is_the_blocked_path(case, dtype, tol, monkeypatch):
     for name, got, want_grad in zip(("q_nope", "q_rope", "k_nope", "k_rope", "v"), grads, want_grads):
         assert got.shape == want_grad.shape and got.dtype == want_grad.dtype, name
         close(got, want_grad, tol, f"gradient to {name}")
-    # the queries inside the padding get none either
-    assert not np.asarray(jnp.where(real, 0, grads[0]), np.float32).any()
+    # the queries inside the padding get none either, nor do the keys and values there
+    for name, got in zip(("q_nope", "q_rope", "k_nope", "k_rope", "v"), grads):
+        assert not np.asarray(jnp.where(real.reshape(real.shape[:2] + (1,) * (got.ndim - 2)), 0, got), np.float32).any(), name
+
+
+@pytest.mark.parametrize("seq,visits", [(2560, [15, 15, 10, 6, 3]), (2048, [10, 10, 6, 3, 1]), (2080, [15, 15, 10, 6, 3])],
+                         ids=["update_padded", "prefill", "update"])
+def test_tile_visits_by_hand(seq, visits, monkeypatch):
+    """Visits a (row, head) for rows whose keys begin at 0, 511, 512, 1024 and
+    1536 of the shipped tile; with those skipped they make every pair on or
+    below the diagonal."""
+    monkeypatch.setattr(kernel, "BLOCK", SHIPPED_BLOCK)
+    got, skipped = kernel.tile_visits(jnp.asarray([0, 511, 512, 1024, 1536], jnp.int32), seq)
+    tiles = -(-seq // SHIPPED_BLOCK)
+    assert np.asarray(got).tolist() == visits
+    assert (np.asarray(got) + np.asarray(skipped)).tolist() == [tiles * (tiles + 1) // 2] * 5
+    # a row that is padding throughout is skipped whole
+    assert np.asarray(kernel.tile_visits(jnp.asarray([seq], jnp.int32), seq)[0]).tolist() == [0 if seq % SHIPPED_BLOCK == 0 else 1]
 
 
 def test_the_rule_takes_the_plain_path_off_the_tpu_and_says_why(monkeypatch):
